@@ -518,9 +518,9 @@ class ParallelOptions:
         "key-range owners (the keyBy shuffle over ICI instead of a host "
         "dataplane hop). Results are byte-identical to the single-chip "
         "fused path; snapshots stay canonical [K, S], so checkpoints "
-        "restore across any mesh size. Requires >= 2 visible devices and a "
-        "jax build with shard_map; otherwise execution silently stays "
-        "single-chip."
+        "restore across any mesh size. Requires >= 2 visible devices; a "
+        "request that ends on one chip is warned about and reported as "
+        "meshDevices 1."
     )
     MESH_DEVICES = (
         ConfigOptions.key("parallel.mesh.devices").int_type().default_value(0)
@@ -880,17 +880,17 @@ class ObservabilityOptions:
         .float_type().default_value(0.0)
     ).with_description(
         "HBM bandwidth (GB/s) used as the denominator of the "
-        "hbmUtilizationPct roofline gauge. 0 picks a per-platform default "
-        "(tpu/gpu/cpu); set it to the bench-measured hbm_gbps of the "
-        "actual part for calibrated utilization."
+        "hbmUtilizationPct roofline gauge. 0 takes the published peak of "
+        "the running device_kind (metrics/device_stats.DEVICE_PEAKS); a "
+        "kind that is not listed gets no roofline gauges."
     )
     DEVICE_PEAK_TFLOPS = (
         ConfigOptions.key("observability.device.peak-tflops")
         .float_type().default_value(0.0)
     ).with_description(
         "Peak compute (TFLOP/s) used as the denominator of the "
-        "flopsUtilizationPct roofline gauge. 0 picks a per-platform "
-        "default."
+        "flopsUtilizationPct roofline gauge. 0 takes the published bf16 "
+        "peak of the running device_kind, like hbm-gbps."
     )
     EMISSION_LATENCY_ENABLED = (
         ConfigOptions.key("observability.emission-latency.enabled")

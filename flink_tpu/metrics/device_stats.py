@@ -41,36 +41,36 @@ import time
 from collections import deque
 from typing import Any, Callable, Dict, List, Optional
 
-#: roofline denominators by jax backend platform when the
-#: observability.device.hbm-gbps / .peak-tflops options are left at 0
-#: (auto). Deliberately conservative datasheet-order numbers — utilization
-#: gauges are for RELATIVE attribution across operators and PRs; calibrate
-#: with the bench-measured hbm_gbps for absolute numbers.
-PLATFORM_PEAKS: Dict[str, "tuple[float, float]"] = {
-    # platform: (HBM GB/s, peak TFLOP/s)
-    "tpu": (1200.0, 275.0),
-    "gpu": (2000.0, 300.0),
-    "cpu": (50.0, 0.2),
+#: roofline denominators by `jax.devices()[0].device_kind`, used when the
+#: observability.device.hbm-gbps / .peak-tflops options are left at 0.
+#: Only parts with a published datasheet are listed; a kind that is not
+#: here gets NO roofline gauge — never another device's row.
+DEVICE_PEAKS: Dict[str, Dict[str, float]] = {
+    # Google Cloud documentation, "TPU v5e" system architecture: 16 GB HBM2e
+    # at 819 GB/s, 197 bf16 TFLOP/s, 393 int8 TOP/s per chip
+    "TPU v5 lite": {"hbm_gbps": 819.0, "bf16_tflops": 197.0,
+                    "int8_tops": 393.0},
 }
 
 
-def platform_peaks(hbm_gbps: float = 0.0,
-                   peak_tflops: float = 0.0) -> "tuple[float, float]":
-    """Resolve the roofline denominators: configured values win, 0 falls
-    back to the PLATFORM_PEAKS entry for the default jax backend (and to
-    the cpu row when jax is unavailable entirely)."""
+def platform_peaks(hbm_gbps: float = 0.0, peak_tflops: float = 0.0,
+                   device_kind: Optional[str] = None,
+                   ) -> "Optional[tuple[float, float]]":
+    """Resolve the roofline denominators (HBM GB/s, peak TFLOP/s):
+    configured values win; a value left at 0 comes from the DEVICE_PEAKS
+    row of `device_kind` (default: the kind of `jax.devices()[0]`). None
+    when a value is needed from the table and the kind is not in it."""
     if hbm_gbps > 0 and peak_tflops > 0:
         return hbm_gbps, peak_tflops
-    platform = "cpu"
-    try:
+    if device_kind is None:
         import jax
 
-        platform = jax.default_backend()
-    except Exception:  # noqa: BLE001 — no jax, no device: cpu numbers
-        pass
-    dflt = PLATFORM_PEAKS.get(platform, PLATFORM_PEAKS["cpu"])
-    return (hbm_gbps if hbm_gbps > 0 else dflt[0],
-            peak_tflops if peak_tflops > 0 else dflt[1])
+        device_kind = jax.devices()[0].device_kind
+    row = DEVICE_PEAKS.get(device_kind)
+    if row is None:
+        return None
+    return (hbm_gbps if hbm_gbps > 0 else row["hbm_gbps"],
+            peak_tflops if peak_tflops > 0 else row["bf16_tflops"])
 
 
 def _signature_str(signature: Dict[str, Any]) -> str:

@@ -23,8 +23,8 @@ from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from flink_tpu.utils.jax_compat import shard_map
 
 from flink_tpu.ops.segment_ops import INVALID_INDEX
 

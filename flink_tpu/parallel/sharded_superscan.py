@@ -62,9 +62,8 @@ from typing import Any, Dict, Optional, Sequence
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from flink_tpu.utils.jax_compat import shard_map
 
 from flink_tpu.ops.aggregators import VALUE, combine_reduce, decomposable
 from flink_tpu.ops.superscan import (
@@ -308,12 +307,12 @@ class ShardedFusedPipeline:
 
     def _init_state(self) -> None:
         n, Kl, S = self.n, self.K_local, self.S
-        self._count = jax.device_put(
-            jnp.zeros((n, Kl, S), jnp.int32), self._shard_spec(None, None))
+        # created sharded: no whole-array stop on device 0
+        spec = self._shard_spec(None, None)
+        self._count = jnp.zeros((n, Kl, S), jnp.int32, device=spec)
         self._state = {
-            f.name: jax.device_put(
-                jnp.full((n, Kl, S), f.identity, jnp.dtype(f.dtype)),
-                self._shard_spec(None, None))
+            f.name: jnp.full((n, Kl, S), f.identity, jnp.dtype(f.dtype),
+                             device=spec)
             for f in self._value_fields
         }
 
@@ -582,8 +581,9 @@ class ShardedFusedPipeline:
             idx_h = np.concatenate(
                 [idx_h, np.full((T, pad), -1, np.int32)], axis=1)
         idx_sh = idx_h.reshape(T, self.n, Bs).transpose(1, 0, 2)
-        idx_d = jax.device_put(
-            jnp.asarray(idx_sh), self._shard_spec(None, None))
+        # host arrays go to device_put as they are: each device receives
+        # its own lanes, nothing is first committed whole to device 0
+        idx_d = jax.device_put(idx_sh, self._shard_spec(None, None))
         if self._needs_vals:
             vals_h = np.asarray(plan_vals)
             if Bs * self.n != B:
@@ -591,7 +591,7 @@ class ShardedFusedPipeline:
                     [vals_h, np.zeros((T, Bs * self.n - B), np.float32)],
                     axis=1)
             vals_d = jax.device_put(
-                jnp.asarray(vals_h.reshape(T, self.n, Bs).transpose(1, 0, 2)),
+                vals_h.reshape(T, self.n, Bs).transpose(1, 0, 2),
                 self._shard_spec(None, None))
         else:
             vals_d = jnp.zeros((T, 1), jnp.float32)
@@ -887,17 +887,16 @@ class ShardedFusedPipeline:
                     [ts_h, np.zeros((T, pad), ts_h.dtype)], axis=1)
         trail = raw_h.shape[2:]
         raw_d = jax.device_put(
-            jnp.asarray(
-                raw_h.reshape((T, n, Bs) + trail)
-                .transpose((1, 0, 2) + tuple(range(3, 3 + len(trail))))),
+            raw_h.reshape((T, n, Bs) + trail)
+            .transpose((1, 0, 2) + tuple(range(3, 3 + len(trail)))),
             self._shard_spec(*([None] * (2 + len(trail)))))
         srel_d = jax.device_put(
-            jnp.asarray(srel_h.reshape(T, n, Bs).transpose(1, 0, 2)),
+            srel_h.reshape(T, n, Bs).transpose(1, 0, 2),
             self._shard_spec(None, None))
         ts_d = None
         if ts_h is not None:
             ts_d = jax.device_put(
-                jnp.asarray(ts_h.reshape(T, n, Bs).transpose(1, 0, 2)),
+                ts_h.reshape(T, n, Bs).transpose(1, 0, 2),
                 self._shard_spec(None, None))
         plan = tuple(jax.device_put(a) for a in plan_np) + (fires,)
         return raw_d, srel_d, ts_d, plan
@@ -981,11 +980,11 @@ class ShardedFusedPipeline:
             state = {k: self.routing.to_device_layout(np.asarray(v))
                      for k, v in state.items()}
         self._count = jax.device_put(
-            jnp.asarray(count.reshape(n, Kl, S)),
+            np.asarray(count).reshape(n, Kl, S),
             self._shard_spec(None, None))
         self._state = {
             name: jax.device_put(
-                jnp.asarray(v.reshape(n, Kl, S)),
+                np.asarray(v).reshape(n, Kl, S),
                 self._shard_spec(None, None))
             for name, v in state.items()
         }

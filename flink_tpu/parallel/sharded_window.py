@@ -28,8 +28,8 @@ from typing import List
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from flink_tpu.utils.jax_compat import shard_map
 
 from flink_tpu.ops import segment_ops
 from flink_tpu.ops.aggregators import DeviceAggregator, ONE

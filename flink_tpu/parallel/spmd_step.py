@@ -21,7 +21,7 @@ from typing import Dict
 
 import jax
 import jax.numpy as jnp
-from flink_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from flink_tpu.ops.aggregators import DeviceAggregator, ONE

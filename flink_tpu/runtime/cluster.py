@@ -2789,6 +2789,11 @@ def main(argv: Optional[List[str]] = None) -> None:
         )
         print(f"jobmanager listening on {svc.address}", flush=True)
     else:
+        from flink_tpu.utils.compile_cache import configure_compile_cache
+
+        # a TaskManager runs device programs (keyed shard tasks build their
+        # operators directly, not through a JobRuntime)
+        configure_compile_cache()
         svc = RpcService(security=security)
         ship_ms = 500
         conf = None

@@ -232,28 +232,29 @@ def _mesh_for_config(config: Configuration, key_capacity: int):
     backend exposes, then rounded DOWN to the largest divisor of the
     operator's key capacity so the contiguous key-group ranges divide
     evenly — a capacity/mesh mismatch degrades the mesh, never the
-    key-range semantics. Under 2 usable devices (or a jax build without
-    shard_map) the job silently stays single-chip."""
+    key-range semantics. A request for several devices that ends on one
+    chip is warned about here and shows in the job's report
+    (`meshDevices` gauge, JobExecutionResult.metrics["mesh_devices"])."""
     if not config.get(ParallelOptions.MESH_ENABLED):
-        return None
-    from flink_tpu.utils.jax_compat import HAS_SHARD_MAP
-
-    if not HAS_SHARD_MAP:
-        import warnings
-
-        warnings.warn(
-            "parallel.mesh.enabled is set but this jax build lacks "
-            "shard_map; running single-chip",
-            RuntimeWarning,
-        )
         return None
     import jax
 
     from flink_tpu.parallel.mesh import build_mesh, usable_mesh_size
 
-    n = usable_mesh_size(config.get(ParallelOptions.MESH_DEVICES),
-                         len(jax.devices()), key_capacity)
+    want = config.get(ParallelOptions.MESH_DEVICES)
+    visible = len(jax.devices())
+    n = usable_mesh_size(want, visible, key_capacity)
     if n <= 1:
+        if want != 1:
+            import warnings
+
+            warnings.warn(
+                f"parallel.mesh.enabled asked for "
+                f"{want or 'all visible'} devices but the job runs on ONE "
+                f"chip: {visible} device(s) visible, key capacity "
+                f"{key_capacity}",
+                RuntimeWarning,
+            )
         return None
     return build_mesh(n)
 
@@ -783,11 +784,15 @@ class WindowStepRunner(StepRunner):
 
     def device_roofline(self) -> Dict[str, float]:
         """hbmUtilizationPct / flopsUtilizationPct over the DeviceTimer's
-        measured device wall time (0.0 when either side is ungated)."""
+        measured device wall time (0.0 when device timing is ungated).
+        Empty when there are no peaks to divide by: the device kind has no
+        DEVICE_PEAKS row and none are configured."""
         from flink_tpu.metrics.device_stats import roofline_pct
 
+        if self._roofline_peaks is None:
+            return {}
         tracker, timer = self.device_stats, self.device_timer
-        if tracker is None or timer is None or self._roofline_peaks is None:
+        if tracker is None or timer is None:
             return {"hbmUtilizationPct": 0.0, "flopsUtilizationPct": 0.0}
         hbm, tflops = self._roofline_peaks
         return roofline_pct(tracker.bytes_accessed_total(),
@@ -973,13 +978,16 @@ class WindowStepRunner(StepRunner):
         # attributable per step
         if self.device_stats is not None:
             self.device_stats.register(group)
-            # roofline fractions are each shard's own chip's view -> MEAN
-            group.gauge("hbmUtilizationPct",
-                        lambda: self.device_roofline()["hbmUtilizationPct"],
-                        fold="mean")
-            group.gauge("flopsUtilizationPct",
-                        lambda: self.device_roofline()["flopsUtilizationPct"],
-                        fold="mean")
+            if self._roofline_peaks is not None:
+                # roofline fractions are each shard's own chip's view -> MEAN
+                group.gauge(
+                    "hbmUtilizationPct",
+                    lambda: self.device_roofline()["hbmUtilizationPct"],
+                    fold="mean")
+                group.gauge(
+                    "flopsUtilizationPct",
+                    lambda: self.device_roofline()["flopsUtilizationPct"],
+                    fold="mean")
             phases = getattr(self.op, "phase_totals", None)
             if callable(phases):
                 group.gauge("phaseIngestRecords",
@@ -1992,6 +2000,11 @@ class JobRuntime:
     def __init__(self, graph: StepGraph, config: Configuration,
                  registry: Optional[MetricRegistry] = None,
                  traces=None):
+        from flink_tpu.utils.compile_cache import configure_compile_cache
+
+        # every way a job starts in a process (local executor, MiniCluster,
+        # a TaskExecutor's graph task) builds its device programs below
+        configure_compile_cache()
         self.graph = graph
         self.config = config
         self.traces = traces    # optional TraceRegistry for device spans
@@ -2095,16 +2108,15 @@ class JobRuntime:
             dg.gauge("recompileStorm",
                      lambda: max(t.recompile_storm() for t in trackers),
                      fold="max")
-            dg.gauge("hbmUtilizationPct", lambda: max(
-                (r.device_roofline()["hbmUtilizationPct"]
-                 for r in self.runners
-                 if getattr(r, "device_stats", None) is not None),
-                default=0.0), fold="mean")
-            dg.gauge("flopsUtilizationPct", lambda: max(
-                (r.device_roofline()["flopsUtilizationPct"]
-                 for r in self.runners
-                 if getattr(r, "device_stats", None) is not None),
-                default=0.0), fold="mean")
+            roofed = [r for r in self.runners
+                      if getattr(r, "_roofline_peaks", None) is not None]
+            if roofed:
+                dg.gauge("hbmUtilizationPct", lambda: max(
+                    r.device_roofline()["hbmUtilizationPct"]
+                    for r in roofed), fold="mean")
+                dg.gauge("flopsUtilizationPct", lambda: max(
+                    r.device_roofline()["flopsUtilizationPct"]
+                    for r in roofed), fold="mean")
         if collectors:
             def _job_skew(cs=collectors):
                 skews = [s for s in (c.skew() for c in cs) if s is not None]
@@ -2550,5 +2562,10 @@ class LocalPipelineExecutor:
             job_name=job_name,
             runtime_ms=runtime_ms,
             records_in=runtime.records_in,
-            metrics={"records_in": runtime.records_in},
+            # the job's own account of where it ran: the mesh size it got
+            # (not the one asked for) and, per operator, which device
+            # programs were compiled and dispatched (/jobs/:id/device shape)
+            metrics={"records_in": runtime.records_in,
+                     "mesh_devices": runtime.mesh_devices(),
+                     "device": runtime.device_snapshot()},
         )

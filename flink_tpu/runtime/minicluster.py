@@ -39,17 +39,13 @@ from flink_tpu.runtime.executor import (
 
 def _effective_mesh_target(runtime: JobRuntime, target: int) -> Optional[int]:
     """Clamp a mesh-rescale target EXACTLY like runner construction will:
-    shard_map availability, visible devices, and the largest divisor of
-    the operators' construction-time key capacity (NOT the grown pipe.K —
-    the rebuilt operator starts from the construction capacity again, so
-    clamping against grown state would accept targets the rebuild cannot
-    reach and tear the job down for a no-op). None = the job has no
-    mesh-capable operator / no mesh backend; otherwise the device count
-    the rebuild will actually produce."""
-    from flink_tpu.utils.jax_compat import HAS_SHARD_MAP
-
-    if not HAS_SHARD_MAP:
-        return None
+    visible devices and the largest divisor of the operators'
+    construction-time key capacity (NOT the grown pipe.K — the rebuilt
+    operator starts from the construction capacity again, so clamping
+    against grown state would accept targets the rebuild cannot reach and
+    tear the job down for a no-op). None = the job has no mesh-capable
+    operator; otherwise the device count the rebuild will actually
+    produce."""
     caps = [
         op.mesh_capacity()
         for op in (getattr(r, "op", None) for r in runtime.runners)

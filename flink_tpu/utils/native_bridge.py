@@ -2,8 +2,10 @@
 
 Builds native/libflink_tpu_native.so on demand with g++ (cached by source
 mtime) and exposes typed wrappers. Every caller has a pure-Python/numpy
-fallback, so a missing compiler degrades performance, not capability —
-the same posture as the reference shipping prebuilt JNI jars.
+form, so a missing compiler costs speed, not capability — but never
+quietly: a build or load that fails warns once with the reason, and
+`load_error()` keeps it for anyone who must refuse to run without the
+library (chip_smoke.py does).
 """
 
 from __future__ import annotations
@@ -12,6 +14,7 @@ import ctypes
 import os
 import subprocess
 import threading
+import warnings
 from typing import Optional, Tuple
 
 import numpy as np
@@ -26,48 +29,75 @@ _LIB = os.path.join(_REPO_ROOT, "native", "libflink_tpu_native.so")
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
-_load_failed = False
+_load_error: Optional[str] = None
 
 
-def _build() -> bool:
+def _build() -> Optional[str]:
+    """Compile native/*.cpp into _LIB. Returns why it failed, else None.
+    The compiler writes beside the target and the result is renamed into
+    place, so a process starting next to this one never loads half a
+    file."""
+    tmp = f"{_LIB}.{os.getpid()}.tmp"
     try:
         subprocess.run(
-            ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-o", _LIB, *_SRCS],
+            ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-o", tmp, *_SRCS],
             check=True,
             capture_output=True,
             timeout=120,
         )
-        return True
-    except (subprocess.SubprocessError, FileNotFoundError):
-        return False
+        os.replace(tmp, _LIB)
+    except FileNotFoundError:
+        return "g++ not found"
+    except subprocess.TimeoutExpired:
+        return "g++ did not finish within 120 s"
+    except subprocess.CalledProcessError as e:
+        return "g++ failed: " + e.stderr.decode(errors="replace")[-2000:]
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
+    return None
+
+
+def _load() -> ctypes.CDLL:
+    missing = [src for src in _SRCS if not os.path.exists(src)]
+    if missing:
+        raise OSError(f"native sources missing: {missing}")
+    if (
+        not os.path.exists(_LIB)
+        or os.path.getmtime(_LIB) < max(os.path.getmtime(s) for s in _SRCS)
+    ):
+        why = _build()
+        if why is not None:
+            raise OSError(why)
+    lib = ctypes.CDLL(_LIB)
+    _declare(lib)
+    return lib
 
 
 def get_lib() -> Optional[ctypes.CDLL]:
     """Loads (building if stale/missing) the native library; None if
-    unavailable."""
-    global _lib, _load_failed
-    if _lib is not None or _load_failed:
+    unavailable, in which case `load_error()` says why."""
+    global _lib, _load_error
+    if _lib is not None or _load_error is not None:
         return _lib
     with _lock:
-        if _lib is not None or _load_failed:
-            return _lib
-        try:
-            if not all(os.path.exists(src) for src in _SRCS):
-                _load_failed = True
-                return None
-            if (
-                not os.path.exists(_LIB)
-                or os.path.getmtime(_LIB) < max(os.path.getmtime(s) for s in _SRCS)
-            ):
-                if not _build():
-                    _load_failed = True
-                    return None
-            lib = ctypes.CDLL(_LIB)
-            _declare(lib)
-            _lib = lib
-        except OSError:
-            _load_failed = True
+        if _lib is None and _load_error is None:
+            try:
+                _lib = _load()
+            except OSError as e:
+                _load_error = str(e)
+                warnings.warn(
+                    f"native library unavailable ({_load_error}); the host "
+                    "key dictionary, CSV codec, segment ring and spill "
+                    "store run their numpy/Python forms",
+                    RuntimeWarning,
+                )
     return _lib
+
+
+def load_error() -> Optional[str]:
+    """Why the last `get_lib()` could not build or load the library."""
+    return _load_error
 
 
 def _declare(lib: ctypes.CDLL) -> None:

@@ -30,6 +30,7 @@ import jax.numpy as jnp
 
 from flink_tpu.core.time import MAX_WATERMARK
 from flink_tpu.metrics.device_stats import (
+    DEVICE_PEAKS,
     CompileTracker,
     attribute_cause,
     compile_event_span,
@@ -189,10 +190,15 @@ def test_roofline_pct_math_and_platform_peaks():
     assert r["flopsUtilizationPct"] == pytest.approx(50.0)
     assert roofline_pct(1e9, 1e9, 0.0, 100.0, 1.0) == {
         "hbmUtilizationPct": 0.0, "flopsUtilizationPct": 0.0}
-    # configured values win; zeros fall back to the platform table
+    # configured values win; zeros take the device kind's published row
     assert platform_peaks(123.0, 4.5) == (123.0, 4.5)
-    hbm, tf = platform_peaks(0.0, 0.0)
-    assert hbm > 0 and tf > 0
+    assert platform_peaks(device_kind="TPU v5 lite") == (819.0, 197.0)
+    assert platform_peaks(0.0, 4.5, device_kind="TPU v5 lite") == (819.0, 4.5)
+    assert DEVICE_PEAKS["TPU v5 lite"]["int8_tops"] == 393.0
+    # an unlisted kind (this CPU backend too) never borrows a row
+    assert platform_peaks(device_kind="TPU v9 imaginary") is None
+    assert platform_peaks(0.0, 4.5, device_kind="TPU v9 imaginary") is None
+    assert platform_peaks() is None
 
 
 def test_compile_event_span_attribute_mapping():
@@ -431,6 +437,10 @@ def device_job():
     cfg.set(ExecutionOptions.KEY_CAPACITY, 23)
     cfg.set(ExecutionOptions.SUPERBATCH_STEPS, 4)
     cfg.set(ObservabilityOptions.DEVICE_KEY_STATS_INTERVAL_MS, 0)
+    # the CPU backend has no DEVICE_PEAKS row: state the peaks, as a user
+    # of an unlisted part would, so the roofline gauges exist here
+    cfg.set(ObservabilityOptions.DEVICE_HBM_GBPS, 50.0)
+    cfg.set(ObservabilityOptions.DEVICE_PEAK_TFLOPS, 0.2)
     env = StreamExecutionEnvironment(cfg)
     ds = env.from_source(
         DataGeneratorSource(gen, count=3264),

@@ -26,10 +26,6 @@ from flink_tpu.ops.aggregators import (
 )
 from flink_tpu.parallel.sharded_superscan import ShardedFusedPipeline
 from flink_tpu.runtime.oracle_window_operator import OracleWindowOperator
-from flink_tpu.utils.jax_compat import HAS_SHARD_MAP
-
-pytestmark = pytest.mark.skipif(
-    not HAS_SHARD_MAP, reason="this jax build lacks shard_map")
 
 NUM_KEYS = 192
 WINDOW_MS, SLIDE_MS = 2_000, 500

@@ -37,10 +37,6 @@ from flink_tpu.config import (
 from flink_tpu.connectors.sink import CollectSink
 from flink_tpu.connectors.source import Batch, DataGeneratorSource
 from flink_tpu.core.watermarks import WatermarkStrategy
-from flink_tpu.utils.jax_compat import HAS_SHARD_MAP
-
-pytestmark = pytest.mark.skipif(
-    not HAS_SHARD_MAP, reason="this jax build lacks shard_map")
 
 N_KEYS = 192          # divides the 8-device mesh; distinctive geometry
 SPAN_MS = 40_000

@@ -11,10 +11,6 @@ from flink_tpu.ops import segment_ops
 from flink_tpu.parallel.mesh import build_mesh, shard_ranges
 from flink_tpu.parallel.sharded_window import ShardedTpuWindowOperator
 from flink_tpu.runtime.tpu_window_operator import TpuWindowOperator
-from flink_tpu.utils.jax_compat import HAS_SHARD_MAP
-
-pytestmark = pytest.mark.skipif(
-    not HAS_SHARD_MAP, reason="this jax build lacks shard_map")
 
 MAX_PAR = 128
 

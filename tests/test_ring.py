@@ -4,15 +4,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from flink_tpu.parallel.mesh import build_mesh
 from flink_tpu.parallel.ring import ring_all_gather, ring_all_reduce, ring_global_topk
-from flink_tpu.utils.jax_compat import HAS_SHARD_MAP
-
-pytestmark = pytest.mark.skipif(
-    not HAS_SHARD_MAP, reason="this jax build lacks shard_map")
-from flink_tpu.utils.jax_compat import shard_map
 
 
 @pytest.fixture(scope="module")

@@ -9,10 +9,6 @@ from jax.sharding import Mesh
 from flink_tpu.api.windowing.assigners import SlidingEventTimeWindows
 from flink_tpu.parallel.sharded_superscan import ShardedFusedPipeline
 from flink_tpu.runtime.fused_window_pipeline import FusedWindowPipeline
-from flink_tpu.utils.jax_compat import HAS_SHARD_MAP
-
-pytestmark = pytest.mark.skipif(
-    not HAS_SHARD_MAP, reason="this jax build lacks shard_map")
 
 
 def _mesh(n=8):

@@ -18,10 +18,6 @@ from flink_tpu.parallel.routing import (
     predicted_skew,
 )
 from flink_tpu.scheduler.rebalancer import SkewRebalancer
-from flink_tpu.utils.jax_compat import HAS_SHARD_MAP
-
-pytestmark = pytest.mark.skipif(
-    not HAS_SHARD_MAP, reason="this jax build lacks shard_map")
 
 
 def _mesh(n=8):

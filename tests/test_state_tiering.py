@@ -461,9 +461,8 @@ def test_tiered_mesh_parity_and_cross_mesh_restore():
     import jax
 
     from flink_tpu.parallel.mesh import build_mesh
-    from flink_tpu.utils.jax_compat import HAS_SHARD_MAP
 
-    if len(jax.devices()) < 2 or not HAS_SHARD_MAP:
+    if len(jax.devices()) < 2:
         pytest.skip("no multi-device mesh on this backend")
     mesh = build_mesh(min(len(jax.devices()), 8))
     ref = _run_stream(FusedWindowOperator(

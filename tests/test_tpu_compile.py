@@ -1,0 +1,282 @@
+"""Compile-only checks against a chip-less TPU v5e topology.
+
+libtpu can describe a v5e without one attached, so Mosaic and XLA:TPU run
+for real here under JAX_PLATFORMS=cpu: a kernel the installed compiler
+refuses fails this file instead of a user's first dispatch on the chip.
+Every Pallas geometry the executor defaults and bench.py select is
+compiled, and the support gates are held to the compiler both ways:
+admitted => compiles, refused => never selected.
+
+Nothing here executes on a device; results are checked elsewhere
+(interpret-mode parity tests here on the CPU, chip_smoke.py on the chip).
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from flink_tpu.api.windowing.assigners import SlidingEventTimeWindows
+from flink_tpu.ops import pallas_superscan as ps
+from flink_tpu.ops.aggregators import (
+    VALUE,
+    count_agg,
+    max_agg,
+    mean_agg,
+    sum_agg,
+)
+from flink_tpu.runtime.fused_window_pipeline import (
+    FusedGlobalWindowPipeline,
+    FusedWindowPipeline,
+    TracedPrologue,
+)
+
+AGGS = {
+    "count": count_agg(),
+    "sum": sum_agg(),
+    "mean": mean_agg(),
+    "max8": max_agg(domain_bits=8),
+    "max": max_agg(),
+}
+
+# executor defaults (runtime/executor.py WindowStepRunner -> FusedWindowOperator):
+# 10 s / 1 s sliding windows give S=32 SPW=10; nsb 4, fires_per_step 4,
+# out_rows 256, chunk _fused_chunk(65536) = 4096, 32 steps of 65536 records;
+# key capacity starts at 1024 and doubles with the key dictionary
+EXEC = dict(S=32, NSB=4, F=4, SPW=10, R=256, T=32, B=1 << 16, CH=4096)
+# bench.py run_tpu_stream: K=8192, out_rows 64, chunk 32768 count-only /
+# 8192 weighted / 1024 max8
+BENCH = dict(K=8192, S=32, NSB=4, F=4, SPW=10, R=64)
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no libtpu, or it is locked
+        pytest.skip(f"no chip-less TPU topology available: {e!r}")
+    assert topo.devices[0].device_kind == "TPU v5 lite"
+    return topo
+
+
+def _on(dev, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype,
+                                sharding=SingleDeviceSharding(dev))
+
+
+def _plan_specs(dev, T, F, S):
+    i32 = jnp.int32
+    return (_on(dev, (T,), i32), _on(dev, (T, F), i32), _on(dev, (T, F), i32),
+            _on(dev, (T, F), i32), _on(dev, (T, S), i32))
+
+
+def _compile_keyed(dev, agg, *, K, S, NSB, F, SPW, R, T, B, CH,
+                   fire_spws=None):
+    run = ps.build_superscan(agg, K, S, NSB, F, SPW, R, T, B, CH, True,
+                             False, fire_spws)
+    KB = K // ps.LANE
+    vf = [f for f in agg.fields if f.source == VALUE]
+    args = _plan_specs(dev, T, F, S) + (
+        _on(dev, (S * KB, ps.LANE), jnp.int32),
+        tuple(_on(dev, (S * KB, ps.LANE), jnp.dtype(f.dtype)) for f in vf),
+        _on(dev, (T * B,), jnp.int32),
+        _on(dev, (T * B,), jnp.float32) if vf else None,
+    )
+    return run.trace(*args).lower().compile()
+
+
+def _ladder(agg, geom):
+    """Key capacities the executor's doubling reaches while the gate
+    admits them, and the first one it refuses."""
+    admitted, K = [], 1024
+    while ps.supports(agg, K, geom["R"], geom["S"], geom["NSB"], geom["CH"]):
+        admitted.append(K)
+        K *= 2
+    return admitted, K
+
+
+def _largest(name):
+    # max8's largest admitted executor-default geometry alone takes Mosaic
+    # ~15 s, a third of this file: it runs with the slow tests
+    return pytest.param(name, -1, id=f"{name}-largest",
+                        marks=[pytest.mark.slow] if name == "max8" else [])
+
+
+@pytest.mark.parametrize("name,end", [
+    ("count", 0), ("sum", 0), ("max8", 0),
+    _largest("count"), _largest("sum"), _largest("mean"), _largest("max8"),
+])
+def test_executor_default_ladder_ends_compile(v5e, name, end):
+    """Mosaic's need grows with K, so the ends of each doubling ladder
+    stand for the capacities between them (mean is sum-shaped)."""
+    admitted, _refused = _ladder(AGGS[name], EXEC)
+    assert admitted[0] == 1024
+    _compile_keyed(v5e.devices[0], AGGS[name], K=admitted[end], **EXEC)
+
+
+def test_gate_refuses_what_the_compiler_refuses(v5e):
+    """The harness really reaches Mosaic's VMEM check, and the gate is on
+    the right side of it: sum's first refused capacity does not compile
+    under the stated limit."""
+    _admitted, refused = _ladder(AGGS["sum"], EXEC)
+    with pytest.raises(Exception, match="vmem"):
+        _compile_keyed(v5e.devices[0], AGGS["sum"], K=refused, **EXEC)
+
+
+@pytest.mark.parametrize("name,CH,T,B,extra", [
+    ("count", 32768, 48, 1 << 20, {}),          # headline, q5, wordcount
+    ("sum", 8192, 48, 1 << 20, {}),             # weighted
+    ("max8", 1024, 24, 1 << 18,                 # q7 keyed leg, tumbling
+     {"S": 8, "NSB": 2, "SPW": 1, "R": 16}),
+])
+def test_bench_geometries_compile(v5e, name, CH, T, B, extra):
+    geom = {**BENCH, **extra}
+    agg = AGGS[name]
+    assert ps.supports(agg, geom["K"], geom["R"], geom["S"], geom["NSB"], CH)
+    _compile_keyed(v5e.devices[0], agg, T=T, B=B, CH=CH, **geom)
+
+
+def test_shared_partials_kernel_compiles(v5e):
+    """fire_spws: two window shapes over one ring, four fire slots each."""
+    geom = {**EXEC, "F": 8}
+    assert ps.supports(AGGS["sum"], 1024, geom["R"], geom["S"], geom["NSB"],
+                       geom["CH"])
+    _compile_keyed(v5e.devices[0], AGGS["sum"], K=1024,
+                   fire_spws=(10,) * 4 + (5,) * 4, **geom)
+
+
+@pytest.mark.parametrize("name,CH", [
+    ("count", 8192), ("sum", 8192), ("max", 8192), ("max8", 8192),
+    ("max", ps.MAX_GLOBAL_CHUNK)])
+def test_global_kernel_compiles(v5e, name, CH):
+    """bench.py's q7 geometry (S=8 NSB=2 R=16, 96 x 2^18, chunk 8192), and
+    the largest chunk the gate admits."""
+    agg = AGGS[name]
+    S, NSB, F, SPW, R, T, B = 8, 2, 4, 1, 16, 96, 1 << 18
+    assert not ps.supports_global(agg, S, R, NSB, 2 * ps.MAX_GLOBAL_CHUNK)
+    assert ps.supports_global(agg, S, R, NSB, CH)
+    run = ps.build_global_superscan(agg, S, NSB, F, SPW, R, T, B, CH, False)
+    dev = v5e.devices[0]
+    vf = [f for f in agg.fields if f.source == VALUE]
+    args = _plan_specs(dev, T, F, S) + (
+        _on(dev, (1, ps.LANE), jnp.int32),
+        tuple(_on(dev, (1, ps.LANE), jnp.dtype(f.dtype)) for f in vf),
+        _on(dev, (T * B,), jnp.int32),
+        _on(dev, (T * B,), jnp.float32) if vf else None,
+    )
+    run.trace(*args).lower().compile()
+
+
+def test_refused_geometry_is_never_selected(monkeypatch):
+    """`auto` on a TPU backend follows the gate exactly; nothing catches a
+    compile error afterwards, so the gate is the only guard."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def pipe(agg, K, backend="auto"):
+        return FusedWindowPipeline(
+            SlidingEventTimeWindows.of(10_000, 1_000), agg, key_capacity=K,
+            fires_per_step=EXEC["F"], out_rows=EXEC["R"], chunk=EXEC["CH"],
+            backend=backend, plan_only=True)
+
+    for name in ("count", "sum", "mean", "max8"):
+        admitted, refused = _ladder(AGGS[name], EXEC)
+        assert pipe(AGGS[name], admitted[-1])._use_pallas() is True
+        assert pipe(AGGS[name], refused)._use_pallas() is False
+        with pytest.raises(ValueError, match="does not support"):
+            pipe(AGGS[name], refused, backend="pallas")._use_pallas()
+    # unbounded max has no matmul form at any size
+    assert pipe(AGGS["max"], 1024)._use_pallas() is False
+    g = FusedGlobalWindowPipeline(
+        SlidingEventTimeWindows.of(10_000, 10_000), "max", num_slices=64,
+        nsb=2, out_rows=16)
+    assert not ps.supports_global(g.agg, g.S, g.R, g.NSB, g.chunk)
+    assert g._use_pallas() is False
+
+
+def test_chained_superscan_compiles_at_served_defaults(v5e, monkeypatch):
+    """The program a default-config `env.execute()` job dispatches
+    (chip_smoke.py leg 1): traced filter + key + sliding count, key
+    capacity 65536, 32 steps of 65536 two-column f32 records."""
+    # ops/superscan.default_ingest picks the matmul-histogram ingest by
+    # backend: build the program the chip would build
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    K, T, B = 1 << 16, 32, 1 << 16
+    pipe = FusedWindowPipeline(
+        SlidingEventTimeWindows.of(10_000, 1_000), "count", key_capacity=K,
+        fires_per_step=EXEC["F"], out_rows=EXEC["R"], chunk=EXEC["CH"],
+        plan_only=True,
+        prologue=TracedPrologue(
+            transforms=(("filter", lambda col: col[:, 1] < 0.5),),
+            key_fn=lambda col: col[:, 0].astype(jnp.int32)),
+    )
+    run = pipe._build_chained_superscan(T, B)
+    dev = v5e.devices[0]
+    i32 = jnp.int32
+    args = ({}, _on(dev, (K, pipe.S), i32), {}, _on(dev, (pipe.R, K), i32),
+            _on(dev, (T, B, 2), jnp.float32), _on(dev, (T, B), i32),
+            ) + _plan_specs(dev, T, pipe.F, pipe.S)
+    mem = run.trace(*args).lower().compile().memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 8 << 30
+
+
+def test_sharded_chained_superscan_compiles_on_the_2x2_mesh(v5e, monkeypatch):
+    """chip_smoke.py leg 3's program: the same job sharded over the four
+    chips of one host, the keyBy exchange as an in-scan all-to-all."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from flink_tpu.parallel.sharded_superscan import ShardedFusedPipeline
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    # the topology's devices cannot hold arrays: plan and compile only
+    monkeypatch.setattr(ShardedFusedPipeline, "_init_state",
+                        lambda self: None)
+    mesh = Mesh(np.array(v5e.devices), ("shards",))
+    n, K, T, B = 4, 1 << 16, 32, 1 << 16
+    pipe = ShardedFusedPipeline(
+        mesh, SlidingEventTimeWindows.of(10_000, 1_000), "count",
+        key_capacity=K, fires_per_step=EXEC["F"], out_rows=EXEC["R"],
+        chunk=EXEC["CH"],
+        prologue=TracedPrologue(
+            transforms=(("filter", lambda col: col[:, 1] < 0.5),),
+            key_fn=lambda col: col[:, 0].astype(jnp.int32)))
+    pipe.planner._raw_shape = (2,)
+    run = pipe._build_raw(T, B // n)
+
+    def on_mesh(shape, dtype, *spec):
+        return jax.ShapeDtypeStruct(
+            shape, dtype, sharding=NamedSharding(mesh, P(*spec)))
+
+    i32, F, S = jnp.int32, pipe.F, pipe.S
+    run.trace(
+        on_mesh((n, K // n, S), i32, "shards"), (),
+        on_mesh((n, T, B // n, 2), jnp.float32, "shards"),
+        on_mesh((n, T, B // n), i32, "shards"),
+        on_mesh((T,), i32), on_mesh((T, F), i32), on_mesh((T, F), i32),
+        on_mesh((T, F), i32), on_mesh((T, S), i32),
+    ).lower().compile()
+
+
+def test_compile_cache_placement(monkeypatch, tmp_path):
+    """Placed from outside => nothing is set in code; otherwise the fixed
+    in-checkout path (never a temp name, pid or time)."""
+    from flink_tpu.utils import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert compile_cache.configure_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        expect = os.path.join(checkout, ".jax_cache")
+        assert compile_cache.configure_compile_cache() == expect
+        assert jax.config.jax_compilation_cache_dir == expect
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
