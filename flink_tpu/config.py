@@ -750,11 +750,15 @@ class ObservabilityOptions:
         ConfigOptions.key("observability.device-timing.enabled")
         .bool_type().default_value(True)
     ).with_description(
-        "Time the host-side device sections of each window step (kernel "
-        "dispatch + any blocking readback) into per-operator "
-        "deviceDispatchMs histograms and deviceTimeMsTotal gauges. Timing "
-        "is host-clock around already-synchronous sections — it never "
-        "inserts extra block_until_ready syncs into deferred pipelines."
+        "Run the job's thread under the stage clock: each named stage "
+        "(source.poll .. sink.write, docs/observability.md) is a "
+        "flink_tpu.<stage> span in any profiler capture and a count + self "
+        "time in the per-operator stages table, with the link counters "
+        "(h2dBytes, d2hBytes, eventsStaged, rowsEmitted, dispatches). "
+        "deviceDispatchMs / deviceTimeMsTotal / deviceDispatches are derived "
+        "from its outer sections: HOST time in the dispatch and resolve "
+        "sections, not device time. Host clock round already-synchronous "
+        "sections — it never inserts block_until_ready syncs."
     )
     PROFILER_ENABLED = (
         ConfigOptions.key("observability.profiler.enabled")

@@ -147,7 +147,7 @@ class HostSyncInJitRule(Rule):
         "compiled body."
     )
     hint = ("keep the jitted body pure jnp; do host conversion on the "
-            "result at the step boundary (where DeviceTimer attributes it)")
+            "result at the step boundary (the stage clock's resolve stage)")
 
     def check(self, index: ModuleIndex) -> Iterator[Violation]:
         for mod in index.modules:

@@ -2,7 +2,7 @@
 per-kernel cost/roofline attribution.
 
 PR 2 instrumented the data path (latency markers, busy/idle ratios,
-DeviceTimer wall times) and PR 4 the control plane (checkpoint/failure
+stage-clock wall times) and PR 4 the control plane (checkpoint/failure
 stats); the device itself stayed a black box — the runtime could not say
 whether a job is recompile-thrashing, where a laggard kernel's device time
 goes, or how far a kernel sits from the HBM/FLOPs roofline. This module is
@@ -23,7 +23,7 @@ the third observability plane's core:
   trace, no compile) and optionally the AOT executable's
   ``memory_analysis()`` (temp/output HBM — costs an extra compile, off by
   default). Per-dispatch costs accumulate into lifetime bytes/FLOPs
-  totals, which combined with the PR-2 DeviceTimer wall time give the
+  totals, which combined with the stage clock's outer-section wall time give the
   ``hbmUtilizationPct``/``flopsUtilizationPct`` roofline gauges.
 
 Layering: metrics sits below the runtime — this module never imports it.
@@ -40,6 +40,8 @@ import threading
 import time
 from collections import deque
 from typing import Any, Callable, Dict, List, Optional
+
+from flink_tpu.metrics.task_io import tag_dispatch
 
 #: roofline denominators by `jax.devices()[0].device_kind`, used when the
 #: observability.device.hbm-gbps / .peak-tflops options are left at 0.
@@ -152,7 +154,10 @@ class CompileTracker:
              signature: Dict[str, Any]):
         """Invoke ``fn(*args)``, recording a compile event if this call
         compiled. Non-compiling dispatches cost one cache-size probe and a
-        dict increment — O(1) host work on the hot path."""
+        dict increment — O(1) host work on the hot path. The single seam
+        every window program is dispatched through: it tags the operator's
+        open `flink_tpu.dispatch` span with ``program=<program>``."""
+        tag_dispatch(program)
         probe = getattr(fn, "_cache_size", None)
         pre = None
         if probe is not None:
@@ -344,10 +349,12 @@ def roofline_pct(bytes_accessed: float, flops: float, device_time_s: float,
                  hbm_gbps: float, peak_tflops: float) -> Dict[str, float]:
     """Utilization of the memory/compute rooflines over a measured device
     wall-time window: achieved GB/s (or FLOP/s) as a percentage of the
-    part's peak. The denominator is the PR-2 DeviceTimer's host-clock wall
-    time around the already-synchronous dispatch/readback sections, so the
-    figure slightly UNDER-reports (host overhead in the window) — right
-    for cross-operator and cross-PR comparison, not for marketing."""
+    part's peak. NOTE what callers pass today: `device_roofline()` hands
+    in the stage clock's outer sections (`deviceTimeMsTotal`), which is
+    HOST time in the dispatch and resolve sections — an asynchronous
+    enqueue under-counts the device and a blocking resolve over-counts it.
+    The figure still feeds scheduler/signals.py; a later issue re-sources
+    it from a device trace or removes it (ROADMAP.md)."""
     if device_time_s <= 0:
         return {"hbmUtilizationPct": 0.0, "flopsUtilizationPct": 0.0}
     hbm = bytes_accessed / (device_time_s * max(hbm_gbps, 1e-9) * 1e9)
@@ -422,6 +429,7 @@ def empty_device_payload() -> Dict[str, Any]:
         "enabled": False,
         "compile": merge_compile_payloads([]),
         "operators": {},
+        "stages": {},
         "profiler": {"enabled": False, "captures": 0,
                      "last_capture_dir": None},
     }

@@ -131,9 +131,10 @@ class KeyStatsCollector:
         self._mesh_load_skew: Optional[float] = None
 
     # -- collection --------------------------------------------------------
-    def maybe_collect(self, now: Optional[float] = None) -> bool:
-        """Run the fold when state is resident and the interval elapsed;
-        O(1) host work otherwise (one readiness bool + one clock read)."""
+    def due(self, now: Optional[float] = None) -> bool:
+        """True once per interval while state is resident (and stamps the
+        interval): the caller then runs `collect()`. O(1) host work: one
+        readiness bool + one clock read."""
         if self._ready_fn is not None:
             try:
                 if not self._ready_fn():
@@ -144,7 +145,11 @@ class KeyStatsCollector:
         if self._last_t is not None and now - self._last_t < self.interval_s:
             return False
         self._last_t = now
-        return self.collect()
+        return True
+
+    def maybe_collect(self, now: Optional[float] = None) -> bool:
+        """Run the fold when state is resident and the interval elapsed."""
+        return self.due(now) and self.collect()
 
     def collect(self) -> bool:
         """One device fold + tiny host readback; safe anytime (reads the

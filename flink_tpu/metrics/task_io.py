@@ -1,4 +1,4 @@
-"""Per-task busy/idle/backpressure accounting and device-time attribution.
+"""Per-task busy/idle/backpressure accounting and the job thread's stage clock.
 
 The reference tracks these in TaskIOMetricGroup (busyTimeMsPerSecond,
 idleTimeMsPerSecond, backPressuredTimeMsPerSecond; TaskIOMetricGroup.java:48)
@@ -23,8 +23,10 @@ state of the task, not its lifetime average.
 
 from __future__ import annotations
 
+import contextlib
+import threading
 import time
-from typing import Callable, Dict, List
+from typing import Callable, Dict, List, Optional
 
 
 def backpressure_level(ratio: float) -> str:
@@ -120,21 +122,124 @@ class TaskIOMetrics:
                     lambda: self.ms_per_second("backPressured"), fold="mean")
 
 
-class DeviceTimer:
-    """Host-clock attribution of one operator's device sections (dispatch +
-    blocking readback). Wrap already-synchronous sections only — this is an
-    observer, it must never add block_until_ready syncs of its own."""
+#: the stages of the job's thread, in the order a batch meets them. Each is a
+#: `flink_tpu.<stage>` span in any profiler capture and a row of the
+#: per-operator `stages` table (docs/observability.md tabulates this tuple).
+STAGES = (
+    "source.poll", "source.watermark", "chain.host", "keys.lookup",
+    "normalize", "stage.fill", "stage.put", "dispatch", "resolve", "emit",
+    "drain", "sink.write", "keys.stats",
+)
+SPAN_PREFIX = "flink_tpu."
+
+_open = threading.local()       # .top: this thread's innermost open stage
+_OFF = contextlib.nullcontext()
+
+
+def stage(clock: "Optional[StageClock]", name: str, seq: Optional[int] = None):
+    """A stage of `clock`, or the shared no-op when the clock is off
+    (`observability.device-timing.enabled` false): a site then costs this
+    `is None` test."""
+    return _OFF if clock is None else _Stage(clock, name, seq)
+
+
+def dispatch_stage(clock: "Optional[StageClock]", name: str):
+    """A stage of the dispatch being staged: its span carries `clock.seq`,
+    so stage.fill .. emit of one dispatch can be followed in a capture."""
+    return _OFF if clock is None else _Stage(clock, name, clock.seq)
+
+
+def section(clock: "Optional[StageClock]"):
+    """An outer section of `clock` (see StageClock), or the no-op."""
+    return _OFF if clock is None else clock.section()
+
+
+def tag_dispatch(program: str) -> None:
+    """Name the program on this thread's open `dispatch` span (called by
+    `CompileTracker.call`, the seam every window program goes through)."""
+    top = getattr(_open, "top", None)
+    if top is not None and top.name == "dispatch" and top.span is not None:
+        top.span.set_metadata(program=program)
+
+
+class _Stage:
+    __slots__ = ("clock", "name", "seq", "t0", "child_ns", "parent", "span")
+
+    def __init__(self, clock, name, seq):
+        self.clock = clock
+        self.name = name
+        self.seq = seq
+
+    def __enter__(self):
+        self.span = None
+        annotation = self.clock.annotation
+        if annotation.is_enabled():      # a profiler capture is running
+            if self.seq is None:
+                self.span = annotation(SPAN_PREFIX + self.name)
+            else:
+                self.span = annotation(SPAN_PREFIX + self.name, seq=self.seq)
+            self.span.__enter__()
+        self.parent = getattr(_open, "top", None)
+        _open.top = self
+        self.child_ns = 0
+        self.t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc):
+        dt = time.perf_counter_ns() - self.t0
+        _open.top = self.parent
+        if self.parent is not None:
+            self.parent.child_ns += dt
+        rec = self.clock.stages[self.name]
+        rec[0] += 1
+        rec[1] += dt - self.child_ns      # self time: nested stages taken out
+        if self.span is not None:
+            self.span.__exit__(*exc)
+        return False
+
+
+def merge_stage_tables(clocks) -> Dict[str, Dict[str, float]]:
+    """One `stages` table summed over several clocks (the job's view)."""
+    out: Dict[str, Dict[str, float]] = {}
+    for n in STAGES:
+        count = sum(c.stages[n][0] for c in clocks)
+        if count:
+            out[n] = {"count": count, "ms": round(
+                sum(c.stages[n][1] for c in clocks) / 1e6, 3)}
+    return out
+
+
+class StageClock:
+    """Where one operator's share of the job's thread goes. A named stage
+    (`stage(clock, name)`, one of STAGES) is a `flink_tpu.<name>` span on the
+    profiler's clock while a capture runs, and count + self time in
+    `stages`; the link counters are added at the stages that move the bytes.
+    `section()` is the outer section round a whole `process_batch` /
+    `process_watermark` / resolving drain: host time in the dispatch and
+    resolve sections, nested stages included (`deviceTimeMsTotal`,
+    `deviceDispatches`, `deviceDispatchMs`). An observer: wrap
+    already-synchronous sections only, never add a block_until_ready."""
 
     def __init__(self, histogram=None):
+        # imported here: control-plane processes load this module without jax
+        from jax.profiler import TraceAnnotation
+
+        self.annotation = TraceAnnotation
+        self.stages: Dict[str, List[int]] = {n: [0, 0] for n in STAGES}
+        self.h2d_bytes = 0
+        self.d2h_bytes = 0
+        self.events_staged = 0
+        self.rows_emitted = 0
+        self.seq = 0            # the dispatch being staged (`dispatches` so far)
         self.total_s = 0.0
-        self.dispatches = 0
+        self.dispatches = 0     # outer sections entered
         self._hist = histogram
 
     class _Section:
-        __slots__ = ("timer", "t0")
+        __slots__ = ("clock", "t0")
 
-        def __init__(self, timer: "DeviceTimer"):
-            self.timer = timer
+        def __init__(self, clock: "StageClock"):
+            self.clock = clock
 
         def __enter__(self):
             self.t0 = time.perf_counter()
@@ -142,14 +247,28 @@ class DeviceTimer:
 
         def __exit__(self, *exc):
             dt = time.perf_counter() - self.t0
-            self.timer.total_s += dt
-            self.timer.dispatches += 1
-            if self.timer._hist is not None:
-                self.timer._hist.update(dt * 1000.0)
+            self.clock.total_s += dt
+            self.clock.dispatches += 1
+            if self.clock._hist is not None:
+                self.clock._hist.update(dt * 1000.0)
             return False
 
     def section(self) -> "_Section":
-        return DeviceTimer._Section(self)
+        return StageClock._Section(self)
+
+    def staged(self, arrays, events: int = 0) -> None:
+        """Host arrays handed to `jax.device_put` in a stage.put, and the
+        events they carry."""
+        self.h2d_bytes += sum(a.nbytes for a in arrays if a is not None)
+        self.events_staged += events
+
+    def stage_table(self) -> Dict[str, Dict[str, float]]:
+        return merge_stage_tables((self,))
+
+    def link(self) -> Dict[str, int]:
+        return {"h2dBytes": self.h2d_bytes, "d2hBytes": self.d2h_bytes,
+                "eventsStaged": self.events_staged,
+                "rowsEmitted": self.rows_emitted, "dispatches": self.seq}
 
     def register(self, group) -> None:
         group.gauge("deviceTimeMsTotal", lambda: self.total_s * 1000.0,

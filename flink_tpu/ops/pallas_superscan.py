@@ -370,7 +370,8 @@ def build_superscan(
     )
 
     @jax.jit
-    def run(smin, fpos, fvalid, frow, purge, count_in, states, idx, vals):
+    def run_pallas_superscan(smin, fpos, fvalid, frow, purge, count_in,
+                             states, idx, vals):
         args = [count_in, *states, idx]
         if nf:
             args.append(vals)
@@ -381,7 +382,7 @@ def build_superscan(
         field_outs = tuple(res[2 + nf:])
         return count_state, field_states, count_out, field_outs
 
-    return run
+    return run_pallas_superscan
 
 
 # ------------------------------------------------------------------
@@ -586,7 +587,8 @@ def build_global_superscan(
     )
 
     @jax.jit
-    def run(smin, fpos, fvalid, frow, purge, count_in, states, idx, vals):
+    def run_pallas_global_superscan(smin, fpos, fvalid, frow, purge,
+                                    count_in, states, idx, vals):
         args = [count_in, *states, idx]
         if nf:
             args.append(vals)
@@ -597,4 +599,4 @@ def build_global_superscan(
         field_outs = tuple(res[2 + nf:])
         return count_state, field_states, count_out, field_outs
 
-    return run
+    return run_pallas_global_superscan

@@ -97,66 +97,68 @@ def make_superscan_step(agg, K, S, NSB, F, R, SPW, chunk, exact,
             # idx is the step's [K, NSB] count partial, vals the tuple of
             # per-VALUE-field [K, NSB] partials — one dense column combine
             # per field, same add/min/max semantics as the lane scatter
-            cpart = idx
-            count = count.at[:, cols].add(cpart)
-            new_state = {}
-            for (name, dt, scatter, _ident), part in zip(vfields, vals):
-                upd = getattr(state[name].at[:, cols], scatter)
-                new_state[name] = upd(part.astype(dt))
-            state = new_state if vfields else state
+            with jax.named_scope("ingest"):
+                cpart = idx
+                count = count.at[:, cols].add(cpart)
+                new_state = {}
+                for (name, dt, scatter, _ident), part in zip(vfields, vals):
+                    upd = getattr(state[name].at[:, cols], scatter)
+                    new_state[name] = upd(part.astype(dt))
+                state = new_state if vfields else state
             return _fire_purge(
                 state, count, outs, count_out, phase_c if phase_counters
                 else None, cpart.sum(),
                 (fire_pos, fire_valid, fire_row, purge_mask))
 
-        # ingest: MXU histograms over (key, rel-slice) segments for
-        # add-combining fields (or direct scatter-adds on CPU backends);
-        # min/max fields always scatter-combine (no matmul form exists for
-        # order statistics — the scatter unit is the cost of supporting
-        # them on the fused path at all)
-        kid = idx // NSB
-        srel = idx % NSB
-        col = (smin_pos + srel) % S
-        safe_kid = jnp.where(idx >= 0, kid, K)  # OOB rows drop
-        # CPU add-ingest form: XLA lowers a FLAT 1-D index scatter ~2x
-        # faster than the 2-D (kid, col) scatter, so adds go through a
-        # [K*NSB] staging histogram folded densely into the ring columns —
-        # gated on the dense fold (nseg per step) staying small next to
-        # the batch, so huge-K geometries keep the direct scatter
-        flat_adds = ingest != "matmul" and nseg <= 16 * idx.shape[0]
-        if ingest == "matmul":
-            pc = matmul_hist.count_hist(idx, nseg, chunk=chunk).reshape(K, NSB)
-            count = count.at[:, cols].add(pc)
-        elif flat_adds:
-            # dead rows carry idx -1, which jax would WRAP to the last
-            # segment (numpy negative indexing; mode="drop" only drops
-            # past-the-end) — remap them to nseg so the drop is real
-            safe_idx = jnp.where(idx >= 0, idx, nseg)
-            pc = jnp.zeros((nseg,), jnp.int32).at[safe_idx].add(
-                jnp.int32(1), mode="drop").reshape(K, NSB)
-            count = count.at[:, cols].add(pc)
-        else:
-            count = count.at[safe_kid, col].add(jnp.int32(1), mode="drop")
-        new_state = {}
-        for name, dt, scatter, ident in vfields:
-            if scatter == "add":
-                if ingest == "matmul":
-                    ph = matmul_hist.weighted_hist(
-                        idx, vals, nseg, chunk=chunk, exact=exact
-                    ).reshape(K, NSB)
-                    new_state[name] = state[name].at[:, cols].add(ph.astype(dt))
-                elif flat_adds:
-                    ph = jnp.zeros((nseg,), dt).at[
-                        jnp.where(idx >= 0, idx, nseg)].add(
-                        vals.astype(dt), mode="drop").reshape(K, NSB)
-                    new_state[name] = state[name].at[:, cols].add(ph)
-                else:
-                    new_state[name] = state[name].at[safe_kid, col].add(
-                        vals.astype(dt), mode="drop")
+        with jax.named_scope("ingest"):
+            # ingest: MXU histograms over (key, rel-slice) segments for
+            # add-combining fields (or direct scatter-adds on CPU backends);
+            # min/max fields always scatter-combine (no matmul form exists for
+            # order statistics — the scatter unit is the cost of supporting
+            # them on the fused path at all)
+            kid = idx // NSB
+            srel = idx % NSB
+            col = (smin_pos + srel) % S
+            safe_kid = jnp.where(idx >= 0, kid, K)  # OOB rows drop
+            # CPU add-ingest form: XLA lowers a FLAT 1-D index scatter ~2x
+            # faster than the 2-D (kid, col) scatter, so adds go through a
+            # [K*NSB] staging histogram folded densely into the ring columns —
+            # gated on the dense fold (nseg per step) staying small next to
+            # the batch, so huge-K geometries keep the direct scatter
+            flat_adds = ingest != "matmul" and nseg <= 16 * idx.shape[0]
+            if ingest == "matmul":
+                pc = matmul_hist.count_hist(idx, nseg, chunk=chunk).reshape(K, NSB)
+                count = count.at[:, cols].add(pc)
+            elif flat_adds:
+                # dead rows carry idx -1, which jax would WRAP to the last
+                # segment (numpy negative indexing; mode="drop" only drops
+                # past-the-end) — remap them to nseg so the drop is real
+                safe_idx = jnp.where(idx >= 0, idx, nseg)
+                pc = jnp.zeros((nseg,), jnp.int32).at[safe_idx].add(
+                    jnp.int32(1), mode="drop").reshape(K, NSB)
+                count = count.at[:, cols].add(pc)
             else:
-                upd = getattr(state[name].at[safe_kid, col], scatter)
-                new_state[name] = upd(vals.astype(dt), mode="drop")
-        state = new_state if vfields else state
+                count = count.at[safe_kid, col].add(jnp.int32(1), mode="drop")
+            new_state = {}
+            for name, dt, scatter, ident in vfields:
+                if scatter == "add":
+                    if ingest == "matmul":
+                        ph = matmul_hist.weighted_hist(
+                            idx, vals, nseg, chunk=chunk, exact=exact
+                        ).reshape(K, NSB)
+                        new_state[name] = state[name].at[:, cols].add(ph.astype(dt))
+                    elif flat_adds:
+                        ph = jnp.zeros((nseg,), dt).at[
+                            jnp.where(idx >= 0, idx, nseg)].add(
+                            vals.astype(dt), mode="drop").reshape(K, NSB)
+                        new_state[name] = state[name].at[:, cols].add(ph)
+                    else:
+                        new_state[name] = state[name].at[safe_kid, col].add(
+                            vals.astype(dt), mode="drop")
+                else:
+                    upd = getattr(state[name].at[safe_kid, col], scatter)
+                    new_state[name] = upd(vals.astype(dt), mode="drop")
+            state = new_state if vfields else state
         return _fire_purge(
             state, count, outs, count_out,
             phase_c if phase_counters else None,
@@ -169,56 +171,58 @@ def make_superscan_step(agg, K, S, NSB, F, R, SPW, chunk, exact,
         different INGEST, never a different fire/purge."""
         fire_pos, fire_valid, fire_row, purge_mask = plan
 
-        # fire: combine the window's slice columns, write compact rows.
-        # The WHOLE fire body sits under the cond, gathers included: most
-        # steps fire nothing, and the K*SPW column gather+combine per fire
-        # slot is the dominant per-step fixed cost when computed eagerly
-        # (at K=8192, SPW=10, F=2 that is 20x the ingest work of an 8k
-        # batch) — identical results, the eager crow was discarded unless
-        # fire_valid was set anyway
-        def write_fire(f, bufs):
-            pos = (fire_pos[f] + jnp.arange(spws[f], dtype=jnp.int32)) % S
-            row = jnp.clip(fire_row[f], 0, R - 1)
+        with jax.named_scope("fire"):
+            # fire: combine the window's slice columns, write compact rows.
+            # The WHOLE fire body sits under the cond, gathers included: most
+            # steps fire nothing, and the K*SPW column gather+combine per fire
+            # slot is the dominant per-step fixed cost when computed eagerly
+            # (at K=8192, SPW=10, F=2 that is 20x the ingest work of an 8k
+            # batch) — identical results, the eager crow was discarded unless
+            # fire_valid was set anyway
+            def write_fire(f, bufs):
+                pos = (fire_pos[f] + jnp.arange(spws[f], dtype=jnp.int32)) % S
+                row = jnp.clip(fire_row[f], 0, R - 1)
 
-            def do_fire(b):
-                outs, count_out = b
-                crow = count[:, pos].sum(axis=1)
-                count_out = jax.lax.dynamic_update_index_in_dim(
-                    count_out, crow, row, 0)
-                new_outs = {}
-                for name, _dt, scatter, _ident in vfields:
-                    vrow = combine_reduce(scatter)(state[name][:, pos], 1)
-                    new_outs[name] = jax.lax.dynamic_update_index_in_dim(
-                        outs[name], vrow, row, 0)
-                return (new_outs if vfields else outs), count_out
+                def do_fire(b):
+                    outs, count_out = b
+                    crow = count[:, pos].sum(axis=1)
+                    count_out = jax.lax.dynamic_update_index_in_dim(
+                        count_out, crow, row, 0)
+                    new_outs = {}
+                    for name, _dt, scatter, _ident in vfields:
+                        vrow = combine_reduce(scatter)(state[name][:, pos], 1)
+                        new_outs[name] = jax.lax.dynamic_update_index_in_dim(
+                            outs[name], vrow, row, 0)
+                    return (new_outs if vfields else outs), count_out
 
-            return jax.lax.cond(fire_valid[f] > 0, do_fire, lambda b: b, bufs)
+                return jax.lax.cond(fire_valid[f] > 0, do_fire, lambda b: b, bufs)
 
-        bufs = (outs, count_out)
-        for f in range(F):
-            bufs = write_fire(f, bufs)
-        outs, count_out = bufs
+            bufs = (outs, count_out)
+            for f in range(F):
+                bufs = write_fire(f, bufs)
+            outs, count_out = bufs
 
-        # purge expired ring columns (reset to the field's identity); under
-        # a cond for the same reason — the S*K multiply/where is pure
-        # identity on the all-ones masks most steps carry
-        def do_purge(sc):
-            state, count = sc
-            count = count * purge_mask[None, :]
-            if vfields:
-                state = {
-                    name: jnp.where(
-                        purge_mask[None, :] > 0,
-                        state[name],
-                        jnp.asarray(ident, dt),
-                    )
-                    for name, dt, _scatter, ident in vfields
-                }
-            return state, count
+        with jax.named_scope("purge"):
+            # purge expired ring columns (reset to the field's identity); under
+            # a cond for the same reason — the S*K multiply/where is pure
+            # identity on the all-ones masks most steps carry
+            def do_purge(sc):
+                state, count = sc
+                count = count * purge_mask[None, :]
+                if vfields:
+                    state = {
+                        name: jnp.where(
+                            purge_mask[None, :] > 0,
+                            state[name],
+                            jnp.asarray(ident, dt),
+                        )
+                        for name, dt, _scatter, ident in vfields
+                    }
+                return state, count
 
-        purged = jnp.any(purge_mask == 0)
-        state, count = jax.lax.cond(
-            purged, do_purge, lambda sc: sc, (state, count))
+            purged = jnp.any(purge_mask == 0)
+            state, count = jax.lax.cond(
+                purged, do_purge, lambda sc: sc, (state, count))
         if phase_counters:
             phase_c = phase_c + jnp.stack([
                 ingested.astype(jnp.int32),
@@ -409,9 +413,12 @@ def build_global_superscan(agg, S, NSB, F, R, SPW, T, B,
     step = make_global_scan_step(agg, S, NSB, F, R, SPW,
                                  fire_spws=fire_spws, phase_counters=phases)
 
+    # the function's name is the program's name on the device: the trace's
+    # module reads jit_run_<CompileTracker program>
     @jax.jit
-    def run(state, count, outs, count_out, idx, vals, smin_pos, fire_pos,
-            fire_valid, fire_row, purge_mask):
+    def run_global_superscan(state, count, outs, count_out, idx, vals,
+                             smin_pos, fire_pos, fire_valid, fire_row,
+                             purge_mask):
         carry0 = (state, count, outs, count_out)
         if phases:
             carry0 = carry0 + (jnp.zeros((3,), jnp.int32),)
@@ -422,7 +429,7 @@ def build_global_superscan(agg, S, NSB, F, R, SPW, T, B,
         )
         return carry
 
-    return run
+    return run_global_superscan
 
 
 # ---------------------------------------------------------------------------

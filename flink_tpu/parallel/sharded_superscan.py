@@ -65,6 +65,7 @@ import jax.numpy as jnp
 from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from flink_tpu.metrics.task_io import dispatch_stage
 from flink_tpu.ops.aggregators import VALUE, combine_reduce, decomposable
 from flink_tpu.ops.superscan import (
     default_ingest,
@@ -394,18 +395,19 @@ class ShardedFusedPipeline:
         n, Kl, NSB, axis = self.n, self.K_local, self.NSB, self.axis
 
         def fn(carry, pidx, vals, plan_row):
-            cpart, parts = partials_fn(pidx, vals)
-            rc = jax.lax.all_to_all(
-                cpart.reshape(n, Kl * NSB), axis, split_axis=0,
-                concat_axis=0, tiled=False)
-            cpart_l = rc.sum(axis=0).reshape(Kl, NSB)
-            parts_l = []
-            for p, sc in zip(parts, scatters):
-                rp = jax.lax.all_to_all(
-                    p.reshape(n, Kl * NSB), axis, split_axis=0,
+            with jax.named_scope("exchange"):
+                cpart, parts = partials_fn(pidx, vals)
+                rc = jax.lax.all_to_all(
+                    cpart.reshape(n, Kl * NSB), axis, split_axis=0,
                     concat_axis=0, tiled=False)
-                parts_l.append(
-                    combine_reduce(sc)(rp, 0).reshape(Kl, NSB))
+                cpart_l = rc.sum(axis=0).reshape(Kl, NSB)
+                parts_l = []
+                for p, sc in zip(parts, scatters):
+                    rp = jax.lax.all_to_all(
+                        p.reshape(n, Kl * NSB), axis, split_axis=0,
+                        concat_axis=0, tiled=False)
+                    parts_l.append(
+                        combine_reduce(sc)(rp, 0).reshape(Kl, NSB))
             return step(carry, (cpart_l, tuple(parts_l)) + tuple(plan_row))
         return fn
 
@@ -476,33 +478,34 @@ class ShardedFusedPipeline:
                     dst, lidx = owner(valid, kid, idx_row % NSB)
                     pidx = jnp.where(valid, dst * (Kl * NSB) + lidx, -1)
                     return exchange(carry, pidx, vals_row, plan_row)
-                if routed:
-                    # route-raw under a table: the sender localizes (the
-                    # receiver cannot invert an arbitrary table from a
-                    # global idx without a second lookup)
-                    dst, lidx = owner(valid, kid, idx_row % NSB)
-                    send_payload, localize = lidx, (lambda r: r)
-                else:
-                    # destination = owner of the record's key range
-                    dst = jnp.where(valid, kid // Kl, -1)
-                    # localize: idx - base*NSB keeps srel intact
-                    send_payload = idx_row
-                    localize = lambda r: jnp.where(      # noqa: E731
-                        r >= 0, r - base * NSB, -1)
-                rows = jnp.arange(n, dtype=jnp.int32)[:, None]
-                route = rows == dst[None, :]                       # [n, B]
-                send_idx = jnp.where(route, send_payload[None, :], -1)
-                recv_idx = jax.lax.all_to_all(
-                    send_idx, axis, split_axis=0, concat_axis=0, tiled=False
-                ).reshape(-1)                                      # [n*B]
-                local_idx = localize(recv_idx)
-                if nf:
-                    send_v = jnp.where(route, vals_row[None, :], 0.0)
-                    recv_v = jax.lax.all_to_all(
-                        send_v, axis, split_axis=0, concat_axis=0, tiled=False
-                    ).reshape(-1)
-                else:
-                    recv_v = vals_row  # [1] placeholder
+                with jax.named_scope("exchange"):
+                    if routed:
+                        # route-raw under a table: the sender localizes (the
+                        # receiver cannot invert an arbitrary table from a
+                        # global idx without a second lookup)
+                        dst, lidx = owner(valid, kid, idx_row % NSB)
+                        send_payload, localize = lidx, (lambda r: r)
+                    else:
+                        # destination = owner of the record's key range
+                        dst = jnp.where(valid, kid // Kl, -1)
+                        # localize: idx - base*NSB keeps srel intact
+                        send_payload = idx_row
+                        localize = lambda r: jnp.where(      # noqa: E731
+                            r >= 0, r - base * NSB, -1)
+                    rows = jnp.arange(n, dtype=jnp.int32)[:, None]
+                    route = rows == dst[None, :]                       # [n, B]
+                    send_idx = jnp.where(route, send_payload[None, :], -1)
+                    recv_idx = jax.lax.all_to_all(
+                        send_idx, axis, split_axis=0, concat_axis=0, tiled=False
+                    ).reshape(-1)                                      # [n*B]
+                    local_idx = localize(recv_idx)
+                    if nf:
+                        send_v = jnp.where(route, vals_row[None, :], 0.0)
+                        recv_v = jax.lax.all_to_all(
+                            send_v, axis, split_axis=0, concat_axis=0, tiled=False
+                        ).reshape(-1)
+                    else:
+                        recv_v = vals_row  # [1] placeholder
                 return step(carry, (local_idx, recv_v, *plan_row))
 
             outs0 = {
@@ -559,8 +562,11 @@ class ShardedFusedPipeline:
         )
         # latency mode donates the carry (args 0/1: count + field states);
         # dispatch rebinds to the outputs, so the inputs die at enqueue
-        fn = (jax.jit(sharded, donate_argnums=(0, 1)) if self.donate_carry
-              else jax.jit(sharded))
+        def run_sharded_superscan(*args):   # the program's name on the device
+            return sharded(*args)
+
+        fn = (jax.jit(run_sharded_superscan, donate_argnums=(0, 1))
+              if self.donate_carry else jax.jit(run_sharded_superscan))
         self._fn_cache[key] = fn
         return fn
 
@@ -570,31 +576,38 @@ class ShardedFusedPipeline:
         the step's GLOBAL record set; lanes are dealt round-robin across the
         n source shards (any split works — the in-scan all-to-all re-routes
         by key ownership)."""
-        plan_idx, plan_vals, plan = self._planner.stage_superbatch(
-            batches, watermarks)
-        idx_h = np.asarray(plan_idx)          # [T, B_padded] int32
-        T, B = idx_h.shape
-        # pad B so every shard gets an equal lane count
-        Bs = -(-B // self.n)
-        if Bs * self.n != B:
-            pad = Bs * self.n - B
-            idx_h = np.concatenate(
-                [idx_h, np.full((T, pad), -1, np.int32)], axis=1)
-        idx_sh = idx_h.reshape(T, self.n, Bs).transpose(1, 0, 2)
-        # host arrays go to device_put as they are: each device receives
-        # its own lanes, nothing is first committed whole to device 0
-        idx_d = jax.device_put(idx_sh, self._shard_spec(None, None))
-        if self._needs_vals:
-            vals_h = np.asarray(plan_vals)
+        clock = self.stage_clock
+        # the planner's own stage.fill / stage.put nest inside this fill
+        with dispatch_stage(clock, "stage.fill"):
+            plan_idx, plan_vals, plan = self._planner.stage_superbatch(
+                batches, watermarks)
+            idx_h = np.asarray(plan_idx)          # [T, B_padded] int32
+            T, B = idx_h.shape
+            # pad B so every shard gets an equal lane count
+            Bs = -(-B // self.n)
             if Bs * self.n != B:
-                vals_h = np.concatenate(
-                    [vals_h, np.zeros((T, Bs * self.n - B), np.float32)],
-                    axis=1)
-            vals_d = jax.device_put(
-                vals_h.reshape(T, self.n, Bs).transpose(1, 0, 2),
-                self._shard_spec(None, None))
-        else:
-            vals_d = jnp.zeros((T, 1), jnp.float32)
+                pad = Bs * self.n - B
+                idx_h = np.concatenate(
+                    [idx_h, np.full((T, pad), -1, np.int32)], axis=1)
+            idx_sh = idx_h.reshape(T, self.n, Bs).transpose(1, 0, 2)
+            vals_sh = None
+            if self._needs_vals:
+                vals_h = np.asarray(plan_vals)
+                if Bs * self.n != B:
+                    vals_h = np.concatenate(
+                        [vals_h,
+                         np.zeros((T, Bs * self.n - B), np.float32)], axis=1)
+                vals_sh = vals_h.reshape(T, self.n, Bs).transpose(1, 0, 2)
+        with dispatch_stage(clock, "stage.put"):
+            # host arrays go to device_put as they are: each device receives
+            # its own lanes, nothing is first committed whole to device 0
+            idx_d = jax.device_put(idx_sh, self._shard_spec(None, None))
+            if vals_sh is not None:
+                vals_d = jax.device_put(vals_sh, self._shard_spec(None, None))
+            else:
+                vals_d = jnp.zeros((T, 1), jnp.float32)
+            if clock is not None:
+                clock.staged((idx_sh, vals_sh))
         return idx_d, vals_d, plan
 
     def process_superbatch(self, batches, watermarks, *, staged=None,
@@ -716,39 +729,40 @@ class ShardedFusedPipeline:
                 # the traced chain runs on THIS shard's raw lanes, before
                 # any routing: filter/projection/keying happen where the
                 # data landed, only survivors cross the interconnect
-                col = raw_row
-                mask = srel_row >= 0
-                for kind, fn in transforms:
-                    if kind == "map":
-                        col = fn(col)
-                    elif kind == "map_ts":
-                        col = fn(col, ts_row)
-                    else:  # filter
-                        mask = mask & jnp.asarray(fn(col)).astype(bool)
-                keys = jnp.asarray(key_fn(col)).astype(jnp.int32)
-                live = mask & (keys >= 0) & (keys < K)
-                idx = jnp.where(live, keys * NSB + srel_row,
-                                jnp.int32(-1)).astype(jnp.int32)
-                # key range observed over every SURVIVING record (pre range
-                # clamp), exactly like the single-chip chained program: an
-                # out-of-range key is a hard error at resolve, never a
-                # silent drop or a silent alias of another shard's row
-                key_bounds = jnp.stack([
-                    jnp.maximum(key_bounds[0],
-                                jnp.max(jnp.where(mask, keys, jnp.int32(-1)))),
-                    jnp.minimum(key_bounds[1],
-                                jnp.min(jnp.where(mask, keys, jnp.int32(0)))),
-                ])
-                if nf:
-                    vcol = value_fn(col) if value_fn is not None else col
-                    # dead/pad rows hold uninitialized staging bytes; zero
-                    # them BEFORE the shuffle so 0 * NaN can never poison
-                    # an owner shard's sums (combine path: a NaN times a
-                    # zero one-hot in the partial histogram, same hazard)
-                    vals = jnp.where(
-                        live, jnp.asarray(vcol).astype(jnp.float32), 0.0)
-                else:
-                    vals = jnp.zeros((1,), jnp.float32)
+                with jax.named_scope("prologue"):
+                    col = raw_row
+                    mask = srel_row >= 0
+                    for kind, fn in transforms:
+                        if kind == "map":
+                            col = fn(col)
+                        elif kind == "map_ts":
+                            col = fn(col, ts_row)
+                        else:  # filter
+                            mask = mask & jnp.asarray(fn(col)).astype(bool)
+                    keys = jnp.asarray(key_fn(col)).astype(jnp.int32)
+                    live = mask & (keys >= 0) & (keys < K)
+                    idx = jnp.where(live, keys * NSB + srel_row,
+                                    jnp.int32(-1)).astype(jnp.int32)
+                    # key range observed over every SURVIVING record (pre range
+                    # clamp), exactly like the single-chip chained program: an
+                    # out-of-range key is a hard error at resolve, never a
+                    # silent drop or a silent alias of another shard's row
+                    key_bounds = jnp.stack([
+                        jnp.maximum(key_bounds[0],
+                                    jnp.max(jnp.where(mask, keys, jnp.int32(-1)))),
+                        jnp.minimum(key_bounds[1],
+                                    jnp.min(jnp.where(mask, keys, jnp.int32(0)))),
+                    ])
+                    if nf:
+                        vcol = value_fn(col) if value_fn is not None else col
+                        # dead/pad rows hold uninitialized staging bytes; zero
+                        # them BEFORE the shuffle so 0 * NaN can never poison
+                        # an owner shard's sums (combine path: a NaN times a
+                        # zero one-hot in the partial histogram, same hazard)
+                        vals = jnp.where(
+                            live, jnp.asarray(vcol).astype(jnp.float32), 0.0)
+                    else:
+                        vals = jnp.zeros((1,), jnp.float32)
                 if combine:
                     # the map-side combiner: this shard's survivors
                     # segment-reduce by (owner, key, rel-slice) and ONLY
@@ -760,30 +774,31 @@ class ShardedFusedPipeline:
                     return (inner, key_bounds), None
                 # the keyBy exchange: bin by owning key range, one
                 # all-to-all over the mesh interconnect per step
-                if routed:
-                    # route-raw under a table: sender-side localization
-                    dst, send_payload = owner(live, keys, srel_row)
-                    localize = lambda r: r                 # noqa: E731
-                else:
-                    dst = jnp.where(live, keys // Kl, -1)
-                    send_payload = idx
-                    localize = lambda r: jnp.where(        # noqa: E731
-                        r >= 0, r - base * NSB, -1)
-                rows = jnp.arange(n, dtype=jnp.int32)[:, None]
-                route = rows == dst[None, :]                     # [n, B]
-                send_idx = jnp.where(route, send_payload[None, :], -1)
-                recv_idx = jax.lax.all_to_all(
-                    send_idx, axis, split_axis=0, concat_axis=0, tiled=False
-                ).reshape(-1)                                    # [n*B]
-                local_idx = localize(recv_idx)
-                if nf:
-                    send_v = jnp.where(route, vals[None, :], 0.0)
-                    recv_v = jax.lax.all_to_all(
-                        send_v, axis, split_axis=0, concat_axis=0,
-                        tiled=False,
-                    ).reshape(-1)
-                else:
-                    recv_v = jnp.zeros((1,), jnp.float32)
+                with jax.named_scope("exchange"):
+                    if routed:
+                        # route-raw under a table: sender-side localization
+                        dst, send_payload = owner(live, keys, srel_row)
+                        localize = lambda r: r                 # noqa: E731
+                    else:
+                        dst = jnp.where(live, keys // Kl, -1)
+                        send_payload = idx
+                        localize = lambda r: jnp.where(        # noqa: E731
+                            r >= 0, r - base * NSB, -1)
+                    rows = jnp.arange(n, dtype=jnp.int32)[:, None]
+                    route = rows == dst[None, :]                     # [n, B]
+                    send_idx = jnp.where(route, send_payload[None, :], -1)
+                    recv_idx = jax.lax.all_to_all(
+                        send_idx, axis, split_axis=0, concat_axis=0, tiled=False
+                    ).reshape(-1)                                    # [n*B]
+                    local_idx = localize(recv_idx)
+                    if nf:
+                        send_v = jnp.where(route, vals[None, :], 0.0)
+                        recv_v = jax.lax.all_to_all(
+                            send_v, axis, split_axis=0, concat_axis=0,
+                            tiled=False,
+                        ).reshape(-1)
+                    else:
+                        recv_v = jnp.zeros((1,), jnp.float32)
                 inner, _ = step(inner, (local_idx, recv_v) + plan_row)
                 return (inner, key_bounds), None
 
@@ -845,7 +860,7 @@ class ShardedFusedPipeline:
             in_specs=in_specs, out_specs=out_specs, check_vma=False,
         )
 
-        def run(*args):
+        def run_sharded_chained_superscan(*args):
             out = sharded(*args)
             if phases:
                 count, states, count_out, outs, kb, pc = out
@@ -859,8 +874,9 @@ class ShardedFusedPipeline:
                 return count, states, count_out, outs, kb_g, pc
             return count, states, count_out, outs, kb_g
 
-        fn = (jax.jit(run, donate_argnums=(0, 1)) if self.donate_carry
-              else jax.jit(run))
+        fn = (jax.jit(run_sharded_chained_superscan, donate_argnums=(0, 1))
+              if self.donate_carry
+              else jax.jit(run_sharded_chained_superscan))
         self._fn_cache[key] = fn
         return fn
 
@@ -870,35 +886,41 @@ class ShardedFusedPipeline:
         single-chip path uses, then lanes are dealt contiguously across
         the n source shards (any split works — the in-scan all-to-all
         re-routes every record to its key owner)."""
-        raw_h, srel_h, ts_h, plan_np, fires = self._planner._stage_raw_host(
-            steps, watermarks)
-        T, B = srel_h.shape
-        n = self.n
-        Bs = -(-B // n)
-        if Bs * n != B:
-            pad = Bs * n - B
-            srel_h = np.concatenate(
-                [srel_h, np.full((T, pad), -1, np.int32)], axis=1)
-            raw_h = np.concatenate(
-                [raw_h, np.zeros((T, pad) + raw_h.shape[2:], raw_h.dtype)],
-                axis=1)
-            if ts_h is not None:
-                ts_h = np.concatenate(
-                    [ts_h, np.zeros((T, pad), ts_h.dtype)], axis=1)
-        trail = raw_h.shape[2:]
-        raw_d = jax.device_put(
-            raw_h.reshape((T, n, Bs) + trail)
-            .transpose((1, 0, 2) + tuple(range(3, 3 + len(trail)))),
-            self._shard_spec(*([None] * (2 + len(trail)))))
-        srel_d = jax.device_put(
-            srel_h.reshape(T, n, Bs).transpose(1, 0, 2),
-            self._shard_spec(None, None))
-        ts_d = None
-        if ts_h is not None:
-            ts_d = jax.device_put(
-                ts_h.reshape(T, n, Bs).transpose(1, 0, 2),
-                self._shard_spec(None, None))
-        plan = tuple(jax.device_put(a) for a in plan_np) + (fires,)
+        clock = self.stage_clock
+        with dispatch_stage(clock, "stage.fill"):
+            raw_h, srel_h, ts_h, plan_np, fires = \
+                self._planner._stage_raw_host(steps, watermarks)
+            T, B = srel_h.shape
+            n = self.n
+            Bs = -(-B // n)
+            if Bs * n != B:
+                pad = Bs * n - B
+                srel_h = np.concatenate(
+                    [srel_h, np.full((T, pad), -1, np.int32)], axis=1)
+                raw_h = np.concatenate(
+                    [raw_h,
+                     np.zeros((T, pad) + raw_h.shape[2:], raw_h.dtype)],
+                    axis=1)
+                if ts_h is not None:
+                    ts_h = np.concatenate(
+                        [ts_h, np.zeros((T, pad), ts_h.dtype)], axis=1)
+            trail = raw_h.shape[2:]
+            raw_sh = raw_h.reshape((T, n, Bs) + trail).transpose(
+                (1, 0, 2) + tuple(range(3, 3 + len(trail))))
+            srel_sh = srel_h.reshape(T, n, Bs).transpose(1, 0, 2)
+            ts_sh = (None if ts_h is None
+                     else ts_h.reshape(T, n, Bs).transpose(1, 0, 2))
+        with dispatch_stage(clock, "stage.put"):
+            raw_d = jax.device_put(
+                raw_sh, self._shard_spec(*([None] * (2 + len(trail)))))
+            srel_d = jax.device_put(srel_sh, self._shard_spec(None, None))
+            ts_d = None
+            if ts_sh is not None:
+                ts_d = jax.device_put(ts_sh, self._shard_spec(None, None))
+            plan = tuple(jax.device_put(a) for a in plan_np) + (fires,)
+            if clock is not None:
+                clock.staged((raw_sh, srel_sh, ts_sh) + plan_np,
+                             sum(len(step[1]) for step in steps))
         return raw_d, srel_d, ts_d, plan
 
     def process_superbatch_raw(self, steps, watermarks, *,

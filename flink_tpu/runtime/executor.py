@@ -48,7 +48,13 @@ from flink_tpu.metrics.emission_latency import (
     watermark_lag_ms,
 )
 from flink_tpu.metrics.registry import MetricRegistry
-from flink_tpu.metrics.task_io import DeviceTimer, TaskIOMetrics
+from flink_tpu.metrics.task_io import (
+    StageClock,
+    TaskIOMetrics,
+    merge_stage_tables,
+    section,
+    stage,
+)
 from flink_tpu.state.heap import HeapKeyedStateBackend, value_state
 from flink_tpu.utils.arrays import as_device_column, canonical_column, obj_array
 from flink_tpu.core.keygroups import KeyGroupRange
@@ -102,6 +108,9 @@ class _FanOut:
 
 class StepRunner:
     downstream: Optional[_FanOut] = None
+    #: the stage clock this runner's sites report to (metrics/task_io.py);
+    #: None = observability.device-timing.enabled off
+    stage_clock: Optional[StageClock] = None
     sides: Optional[Dict[str, _FanOut]] = None   # side-output channels by tag
     num_inputs: int = 1
 
@@ -386,11 +395,17 @@ class ChainRunner(StepRunner):
         return obj_array(list(vals))
 
     def on_batch(self, values: np.ndarray, timestamps: np.ndarray) -> None:
+        with stage(self.stage_clock, "chain.host"):
+            vals, ts = self._apply(values, timestamps)
+        if len(ts) and self.downstream:
+            self.downstream.on_batch(vals, ts)
+
+    def _apply(self, values: np.ndarray, timestamps: np.ndarray):
         vals = values
         ts = np.asarray(timestamps, dtype=np.int64)
         for t in self.transforms:
             if len(ts) == 0:
-                return
+                break
             fn = t.config["fn"]
             vec = t.config.get("vectorized", False)
             if t.config.get("traceable"):
@@ -451,8 +466,7 @@ class ChainRunner(StepRunner):
                     ts = np.asarray(new_ts, dtype=np.int64)
             else:
                 raise NotImplementedError(t.kind)
-        if len(ts) and self.downstream:
-            self.downstream.on_batch(vals, ts)
+        return vals, ts
 
 
 def _max_source_out_of_orderness(step: Step) -> Optional[int]:
@@ -691,17 +705,26 @@ class WindowStepRunner(StepRunner):
         # marked so the job can report which execution path SQL selected
         # (job.sqlFusedSelected gauge + /jobs/:id visibility)
         self.sql_origin = bool(cfg.get("sql_origin"))
-        # per-fused-stage device-time attribution (host clock around the
-        # already-synchronous dispatch/readback sections; never adds syncs)
         self._drain_resolves_device = getattr(
             self, "_drain_resolves_device", False)
-        self.device_timer = (
-            DeviceTimer()
-            if self.device and config.get(ObservabilityOptions.DEVICE_TIMING_ENABLED)
-            else None
-        )
+        self._init_stage_clock(config)
         self._init_device_stats(config)
         self._init_emission_plane(config)
+
+    def _init_stage_clock(self, config: Configuration) -> None:
+        """The operator's stage clock (metrics/task_io.py): named stages of
+        the job's thread inside this runner, its operator and its pipeline,
+        plus the outer sections round the already-synchronous dispatch and
+        resolve calls (host clock; never adds syncs)."""
+        self.stage_clock = (
+            StageClock()
+            if self.device
+            and config.get(ObservabilityOptions.DEVICE_TIMING_ENABLED)
+            else None
+        )
+        attach = getattr(self.op, "attach_stage_clock", None)
+        if attach is not None and self.stage_clock is not None:
+            attach(self.stage_clock)
 
     def _init_emission_plane(self, config: Configuration) -> None:
         """Emission-latency plane (observability.emission-latency.*).
@@ -779,19 +802,27 @@ class WindowStepRunner(StepRunner):
             )
 
     def _device_stats_tick(self) -> None:
-        if self.key_stats is not None:
-            self.key_stats.maybe_collect()
+        ks = self.key_stats
+        if ks is not None and ks.due():
+            # the fold reads the ring the newest dispatch writes: its
+            # readback waits for that dispatch, on the job's thread
+            with stage(self.stage_clock, "keys.stats"):
+                ks.collect()
 
     def device_roofline(self) -> Dict[str, float]:
-        """hbmUtilizationPct / flopsUtilizationPct over the DeviceTimer's
-        measured device wall time (0.0 when device timing is ungated).
-        Empty when there are no peaks to divide by: the device kind has no
-        DEVICE_PEAKS row and none are configured."""
+        """hbmUtilizationPct / flopsUtilizationPct: XLA's cost analysis over
+        the stage clock's outer sections (0.0 when device timing is
+        ungated). NOTE the denominator is HOST time in the dispatch and
+        resolve sections (`deviceTimeMsTotal`), not device time, and the
+        figure still feeds scheduler/signals.py: to be re-sourced from a
+        device trace or removed (ROADMAP.md). Empty when there are no peaks
+        to divide by: the device kind has no DEVICE_PEAKS row and none are
+        configured."""
         from flink_tpu.metrics.device_stats import roofline_pct
 
         if self._roofline_peaks is None:
             return {}
-        tracker, timer = self.device_stats, self.device_timer
+        tracker, timer = self.device_stats, self.stage_clock
         if tracker is None or timer is None:
             return {"hbmUtilizationPct": 0.0, "flopsUtilizationPct": 0.0}
         hbm, tflops = self._roofline_peaks
@@ -815,29 +846,10 @@ class WindowStepRunner(StepRunner):
                 values = _columnarize_records(values, "key_by selector")
             values = canonical_column(values, "key_by selector input")
         if self.device:
-            if self.key_vectorized:
-                keys = np.asarray(self.key_selector(values))
-            else:
-                raw_keys = [self.key_selector(v) for v in values]
-                keys = np.asarray(raw_keys)
-                if keys.ndim != 1 or keys.dtype.kind not in "iuUS":
-                    keys = obj_array(raw_keys)
-            # typed key columns (int/str) unlock the native C++ dictionary
-            if keys.ndim != 1 or keys.dtype.kind not in "iuUSO":
-                keys = obj_array(list(keys))
-            if self._needs_value:
-                if self.value_vectorized:
-                    nums = np.asarray(self.value_fn(values), dtype=np.float32)
-                else:
-                    nums = np.asarray(
-                        [self.value_fn(v) for v in values], dtype=np.float32
-                    )
-            else:  # pure-count aggregates ignore the value column
-                nums = np.zeros(len(values), dtype=np.float32)
-            if self.device_timer is not None:
-                with self.device_timer.section():
-                    self.op.process_batch(keys, nums, timestamps)
-            else:
+            clock = self.stage_clock
+            with stage(clock, "chain.host"):
+                keys, nums = self._keys_and_values(values)
+            with section(clock):
                 self.op.process_batch(keys, nums, timestamps)
             self._device_stats_tick()
         else:
@@ -866,15 +878,36 @@ class WindowStepRunner(StepRunner):
                 self.op.advance_processing_time(int(time.time() * 1000))
                 self._drain()
 
+    def _keys_and_values(self, values: np.ndarray):
+        """The key column and the f32 value column of one batch (the key
+        selector and value function of the host-keyed path)."""
+        if self.key_vectorized:
+            keys = np.asarray(self.key_selector(values))
+        else:
+            raw_keys = [self.key_selector(v) for v in values]
+            keys = np.asarray(raw_keys)
+            if keys.ndim != 1 or keys.dtype.kind not in "iuUS":
+                keys = obj_array(raw_keys)
+        # typed key columns (int/str) unlock the native C++ dictionary
+        if keys.ndim != 1 or keys.dtype.kind not in "iuUSO":
+            keys = obj_array(list(keys))
+        if self._needs_value:
+            if self.value_vectorized:
+                nums = np.asarray(self.value_fn(values), dtype=np.float32)
+            else:
+                nums = np.asarray(
+                    [self.value_fn(v) for v in values], dtype=np.float32
+                )
+        else:  # pure-count aggregates ignore the value column
+            nums = np.zeros(len(values), dtype=np.float32)
+        return keys, nums
+
     def on_watermark(self, watermark: int) -> None:
         if self.device and self.key_stats is not None:
             # fold BEFORE the watermark's purge sweep so a due collection
             # sees the state the advance is about to retire
             self._device_stats_tick()
-        if self.device_timer is not None:
-            with self.device_timer.section():
-                self.op.process_watermark(watermark)
-        else:
+        with section(self.stage_clock):
             self.op.process_watermark(watermark)
         self._drain()
         # fused operators emit asynchronously (superbatch granularity):
@@ -915,13 +948,11 @@ class WindowStepRunner(StepRunner):
                     self.emit_side(tag_id, vals, tss)
                 # rows without a consumer are dropped, not accumulated
                 op_sides[tag_id] = []
-        if self.device_timer is not None and self._drain_resolves_device:
-            # the fused operator resolves deferred dispatches here — drain
-            # IS the blocking readback section; other operators' drain is a
-            # host list swap and is deliberately not timed
-            with self.device_timer.section():
-                out = self.op.drain_output()
-        else:
+        clock = self.stage_clock
+        # an outer section of the fused operator only (kept so that
+        # deviceDispatches counts what it always counted); other
+        # operators' drain is a host list swap and is not timed
+        with section(clock if self._drain_resolves_device else None):
             out = self.op.drain_output()
         if out and self._emission_at_drain:
             tr, lateness = self.emission_tracker, self._emission_lateness
@@ -929,14 +960,17 @@ class WindowStepRunner(StepRunner):
                 tr.record_fire(getattr(w, "end", int(t) + 1),
                                lateness_ms=lateness)
         if out and self.downstream:
-            vals = obj_array(
-                [
-                    r if (self.window_fn is not None or k is None) else (k, r)
-                    for (k, _w, r, _t) in out
-                ]
-            )
-            ts = np.asarray([t for (_k, _w, _r, t) in out], dtype=np.int64)
-            self.downstream.on_batch(vals, ts)
+            with stage(clock, "drain"):
+                vals = obj_array(
+                    [
+                        r if (self.window_fn is not None or k is None)
+                        else (k, r)
+                        for (k, _w, r, _t) in out
+                    ]
+                )
+                ts = np.asarray([t for (_k, _w, _r, t) in out],
+                                dtype=np.int64)
+                self.downstream.on_batch(vals, ts)
 
     def register_metrics(self, group) -> None:
         super().register_metrics(group)
@@ -963,9 +997,9 @@ class WindowStepRunner(StepRunner):
                         fold="emission", kind="histogram")
             group.gauge("watermarkLagMs", lambda: watermark_lag_ms(_wm()),
                         fold="max")
-        if self.device_timer is not None:
-            self.device_timer._hist = group.histogram("deviceDispatchMs")
-            self.device_timer.register(group)
+        if self.stage_clock is not None:
+            self.stage_clock._hist = group.histogram("deviceDispatchMs")
+            self.stage_clock.register(group)
         state_bytes = getattr(self.op, "state_bytes", None)
         if state_bytes is not None:
             # HBM-resident state footprint of this operator's device arrays
@@ -1098,11 +1132,7 @@ class DeviceChainRunner(WindowStepRunner):
         self.uid = t.uid
         self.sql_origin = bool(cfg.get("sql_origin"))
         self._drain_resolves_device = True
-        self.device_timer = (
-            DeviceTimer()
-            if config.get(ObservabilityOptions.DEVICE_TIMING_ENABLED)
-            else None
-        )
+        self._init_stage_clock(config)
         self._init_device_stats(config)
         self._init_emission_plane(config)
         self._warned_object_columns = False
@@ -1113,7 +1143,14 @@ class DeviceChainRunner(WindowStepRunner):
             hook("device", self.uid)
         if len(timestamps) == 0:
             return   # idle poll / watermark-only step: nothing to stage
-        vals = values
+        clock = self.stage_clock
+        with stage(clock, "chain.host"):
+            vals = self._device_column(values)
+        with section(clock):
+            self.op.process_raw_batch(vals, timestamps)
+        self._device_stats_tick()
+
+    def _device_column(self, vals):
         if getattr(vals, "dtype", None) == object or not isinstance(vals, np.ndarray):
             # record-mode source: one columnarization pass per batch. A
             # columnar source (numeric ndarray batches) or the binary wire
@@ -1128,15 +1165,8 @@ class DeviceChainRunner(WindowStepRunner):
                     "columnar numeric batches to feed the device directly",
                     RuntimeWarning,
                 )
-            vals = _columnarize_records(vals, "fused device chain")
-        else:
-            vals = as_device_column(vals)
-        if self.device_timer is not None:
-            with self.device_timer.section():
-                self.op.process_raw_batch(vals, timestamps)
-        else:
-            self.op.process_raw_batch(vals, timestamps)
-        self._device_stats_tick()
+            return _columnarize_records(vals, "fused device chain")
+        return as_device_column(vals)
 
 
 class SharedWindowSiblingRunner(StepRunner):
@@ -1180,24 +1210,22 @@ class SharedWindowRunner(DeviceChainRunner):
             yield spec, r.downstream, (r.sides or None)
 
     def _drain(self) -> None:
-        if self.device_timer is not None and self._drain_resolves_device:
-            with self.device_timer.section():
-                drained = [self.op.drain_spec_output(s)
-                           for s in range(len(self.member_runners))]
-        else:
+        clock = self.stage_clock
+        with section(clock if self._drain_resolves_device else None):
             drained = [self.op.drain_spec_output(s)
                        for s in range(len(self.member_runners))]
         for spec, fan, _sides in self._spec_fanouts():
             out = drained[spec]
             if out and fan:
-                # same record shape as the base _drain: columnar-output
-                # entries (k is None) forward the bare device triple —
-                # sharing must never change what downstream receives
-                vals = obj_array([r if k is None else (k, r)
-                                  for (k, _w, r, _t) in out])
-                ts = np.asarray([t for (_k, _w, _r, t) in out],
-                                dtype=np.int64)
-                fan.on_batch(vals, ts)
+                with stage(clock, "drain"):
+                    # same record shape as the base _drain: columnar-output
+                    # entries (k is None) forward the bare device triple —
+                    # sharing must never change what downstream receives
+                    vals = obj_array([r if k is None else (k, r)
+                                      for (k, _w, r, _t) in out])
+                    ts = np.asarray([t for (_k, _w, _r, t) in out],
+                                    dtype=np.int64)
+                    fan.on_batch(vals, ts)
 
     def _forward_watermark(self, watermark: int) -> None:
         for _spec, fan, sides in self._spec_fanouts():
@@ -1632,7 +1660,8 @@ class SinkRunner(StepRunner):
         super().on_marker(wall_ms)
 
     def on_batch(self, values: np.ndarray, timestamps: np.ndarray) -> None:
-        self.writer.write_batch(values, timestamps)
+        with stage(self.stage_clock, "sink.write"):
+            self.writer.write_batch(values, timestamps)
 
     def commit_epoch(self, epoch_id: str = "final") -> None:
         if self.committer is not None:
@@ -2009,6 +2038,15 @@ class JobRuntime:
         self.config = config
         self.traces = traces    # optional TraceRegistry for device spans
         self.runners, feeds = build_runners(graph, config)
+        # the job thread's stages outside any device operator (source.poll,
+        # the host chains, sink.write) report to one job-level clock
+        self.stage_clock = (
+            StageClock()
+            if config.get(ObservabilityOptions.DEVICE_TIMING_ENABLED)
+            else None)
+        for r in self.runners:
+            if isinstance(r, (ChainRunner, SinkRunner)):
+                r.stage_clock = self.stage_clock
         self.sources = [
             JobRuntime._SourceDriver(t, feeds.get(t.id, []))
             for t in graph.sources
@@ -2082,9 +2120,9 @@ class JobRuntime:
                     for r in rs)),
                 fold="min")
         job_group.gauge("deviceTimeMsTotal", lambda: sum(
-            r.device_timer.total_s * 1000.0
+            r.stage_clock.total_s * 1000.0
             for r in self.runners
-            if getattr(r, "device_timer", None) is not None),
+            if getattr(r, "stage_clock", None) is not None),
             fold="sum", kind="counter")
         # device plane: job-level compile/roofline/skew gauges — these are
         # the keys the TM heartbeat ships and the autoscaler's signal
@@ -2281,7 +2319,7 @@ class JobRuntime:
         for idx, r in enumerate(self.runners):
             tracker = getattr(r, "device_stats", None)
             ks = getattr(r, "key_stats", None)
-            timer = getattr(r, "device_timer", None)
+            timer = getattr(r, "stage_clock", None)
             tier_fn = getattr(getattr(r, "op", None), "tier_payload", None)
             has_tier = callable(tier_fn) and tier_fn() is not None
             routing_fn = getattr(getattr(r, "op", None), "routing_payload",
@@ -2294,6 +2332,8 @@ class JobRuntime:
             if timer is not None:
                 entry["deviceTimeMsTotal"] = round(timer.total_s * 1000.0, 3)
                 entry["deviceDispatches"] = timer.dispatches
+                entry["stages"] = timer.stage_table()
+                entry["link"] = timer.link()
             if tracker is not None:
                 cp = tracker.payload()
                 compile_payloads.append(cp)
@@ -2321,6 +2361,12 @@ class JobRuntime:
                     entry["routing"] = rp
             ops[getattr(r, "uid", f"runner-{idx}")] = entry
         payload["operators"] = ops
+        # the whole job thread: the job-level clock and every operator's
+        clocks = {id(c): c for c in
+                  [self.stage_clock] + [getattr(r, "stage_clock", None)
+                                        for r in self.runners]
+                  if c is not None}
+        payload["stages"] = merge_stage_tables(clocks.values())
         payload["compile"] = merge_compile_payloads(
             compile_payloads,
             history_size=self.config.get(
@@ -2407,7 +2453,8 @@ class JobRuntime:
                 loop_t0 = time.perf_counter()
                 if cancel_check is not None and cancel_check():
                     raise JobCancelledException()
-                batch = d.reader.poll_batch(batch_size)
+                with stage(self.stage_clock, "source.poll"):
+                    batch = d.reader.poll_batch(batch_size)
                 if batch is None:
                     d.current_split = d.enumerator.next_split()
                     busy_dt = 0.0
@@ -2451,15 +2498,16 @@ class JobRuntime:
                 if t_mark is not None:
                     d.emit_marker(t_mark)
                 if d.generator is not None:
-                    wm = (
-                        d.generator.on_batch_np(ts)
-                        if hasattr(d.generator, "on_batch_np")
-                        else None
-                    )
-                    if wm is None:
-                        for v, t in zip(values, ts):
-                            d.generator.on_event(v, int(t))
-                        wm = d.generator.on_periodic_emit()
+                    with stage(self.stage_clock, "source.watermark"):
+                        wm = (
+                            d.generator.on_batch_np(ts)
+                            if hasattr(d.generator, "on_batch_np")
+                            else None
+                        )
+                        if wm is None:
+                            for v, t in zip(values, ts):
+                                d.generator.on_event(v, int(t))
+                            wm = d.generator.on_periodic_emit()
                     if wm is not None and wm > MIN_WATERMARK:
                         d.emit_watermark(wm)
                 if self.iteration_heads:

@@ -42,6 +42,7 @@ import numpy as np
 from flink_tpu.api.windowing.assigners import WindowAssigner
 from flink_tpu.core.time import MAX_WATERMARK, MIN_WATERMARK
 from flink_tpu.lint.contracts import inflight_ring
+from flink_tpu.metrics.task_io import dispatch_stage, stage
 from flink_tpu.ops.aggregators import ONE, VALUE, resolve
 from flink_tpu.runtime.fused_window_pipeline import FusedWindowPipeline
 from flink_tpu.scheduler.latency_controller import (
@@ -613,13 +614,15 @@ class FusedWindowOperator:
             self._process_batch_tiered(np.asarray(keys), values,
                                        np.asarray(timestamps, np.int64))
             return
-        ids, required = self.keydict.lookup_or_insert(np.asarray(keys))
-        self.pipe.ensure_key_capacity(required)
+        clock = self.stage_clock
+        with stage(clock, "keys.lookup"):
+            ids, required = self.keydict.lookup_or_insert(np.asarray(keys))
+            self.pipe.ensure_key_capacity(required)
         vals = np.asarray(values, np.float32) if self._needs_value else None
-        self._push_steps(
-            self.norm.push(ids.astype(np.int32), vals,
-                           np.asarray(timestamps, np.int64))
-        )
+        with stage(clock, "normalize"):
+            steps = self.norm.push(ids.astype(np.int32), vals,
+                                   np.asarray(timestamps, np.int64))
+        self._push_steps(steps)
         self._maybe_dispatch()
 
     # ------------------------------------------------------------------
@@ -660,7 +663,8 @@ class FusedWindowOperator:
             self.flush_all()
         vals = (np.asarray(values, np.float32)
                 if self._needs_value and values is not None else None)
-        routed = tier.route(keys, s_abs, vals, np.asarray(late, bool))
+        with stage(self.stage_clock, "keys.lookup"):
+            routed = tier.route(keys, s_abs, vals, np.asarray(late, bool))
         if routed.demotions or routed.promotions:
             lo, hi, limit = self._tier_span()
             tier.apply_demotions(routed.demotions, lo, hi)
@@ -679,7 +683,9 @@ class FusedWindowOperator:
         if live_hot.any():
             tier.note_hot_cells(ids[live_hot].astype(np.int64),
                                 s_abs[live_hot])
-        self._push_steps(self.norm.push(ids.astype(np.int32), vals, ts))
+        with stage(self.stage_clock, "normalize"):
+            steps = self.norm.push(ids.astype(np.int32), vals, ts)
+        self._push_steps(steps)
         self._maybe_dispatch()
 
     def process_raw_batch(self, values: np.ndarray,
@@ -689,16 +695,18 @@ class FusedWindowOperator:
         runs inside the compiled dispatch."""
         if len(timestamps) == 0:
             return
-        self._push_steps(
-            self.norm.push(values, None, np.asarray(timestamps, np.int64))
-        )
+        with stage(self.stage_clock, "normalize"):
+            steps = self.norm.push(values, None,
+                                   np.asarray(timestamps, np.int64))
+        self._push_steps(steps)
         self._maybe_dispatch()
 
     def process_watermark(self, watermark: int) -> None:
         if watermark <= self.current_watermark:
             return
         self.current_watermark = watermark
-        steps = self.norm.advance(watermark)
+        with stage(self.stage_clock, "normalize"):
+            steps = self.norm.advance(watermark)
         # a single-step advance rides the preceding data step (the pipeline
         # fires after ingesting step t's batch, so batch-then-advance in one
         # step is exactly the executor's batch-then-watermark order)
@@ -776,12 +784,22 @@ class FusedWindowOperator:
 
     def _dispatch(self, group: List[_Step]) -> None:
         wms = [s.wm for s in group]
-        if self.prologue is not None:
-            d = self.pipe.process_superbatch_raw(
-                [(s.kid, s.ts, s.s_abs) for s in group], wms, defer=True)
-        else:
-            d = self.pipe.process_superbatch(
-                [(s.kid, s.vals, s.ts) for s in group], wms, defer=True)
+        clock = self.stage_clock
+        seq = 0
+        if clock is not None:
+            # this dispatch's number: its dispatch span, the pipeline's
+            # stage.fill / stage.put inside it, and later its resolve and
+            # emit spans all carry it
+            clock.seq = seq = clock.seq + 1
+        # the enqueue: everything of the pipeline's call that is not
+        # staging; CompileTracker.call names the program on the span
+        with dispatch_stage(clock, "dispatch"):
+            if self.prologue is not None:
+                d = self.pipe.process_superbatch_raw(
+                    [(s.kid, s.ts, s.s_abs) for s in group], wms, defer=True)
+            else:
+                d = self.pipe.process_superbatch(
+                    [(s.kid, s.vals, s.ts) for s in group], wms, defer=True)
         if self._controller is not None:
             self._ladder_geoms.add(len(group))
         # the purge frontier as of THIS dispatch's staging: cold-tier rows
@@ -789,11 +807,18 @@ class FusedWindowOperator:
         # have resolved (they read the cold rows of the windows that just
         # fired) — a lagged frontier each ring entry carries to its own
         # resolve, so purge_below always advances with resolution order
-        self._inflight.append((d, group[-1].wm, self.pipe.purged_to))
+        self._inflight.append((d, group[-1].wm, self.pipe.purged_to, seq))
         # depth 1 reproduces the historical slot byte-for-byte: the new
         # dispatch enqueues first, THEN the previous one resolves
         while len(self._inflight) > self._max_inflight:
             self._resolve_oldest()
+
+    #: the runner's stage clock (metrics/task_io.py); None = off
+    stage_clock = None
+
+    def attach_stage_clock(self, clock) -> None:
+        self.stage_clock = clock
+        self.pipe.attach_stage_clock(clock)
 
     # emission-latency plane: set by the runner when the plane is on;
     # stamped at the DEFERRED RESOLVE below — the only point where a
@@ -809,13 +834,19 @@ class FusedWindowOperator:
             self._resolve_oldest()
 
     def _resolve_oldest(self) -> None:
-        d, wm, purged_to = self._inflight.popleft()
+        d, wm, purged_to, seq = self._inflight.popleft()
         tracker = self.emission_tracker
-        for window, counts, fields in d.resolve():
+        clock = self.stage_clock
+        with stage(clock, "resolve", seq):
+            fired = d.resolve()
+        if clock is not None:
+            clock.d2h_bytes += d.nbytes
+        for window, counts, fields in fired:
             if tracker is not None:
                 w = window[1] if type(window) is tuple else window
                 tracker.record_fire(w.end)
-            self._emit(window, counts, fields)
+            with stage(clock, "emit", seq):
+                self._emit(window, counts, fields)
         if wm > self.emitted_watermark:
             self.emitted_watermark = wm
         if self.tier is not None:
@@ -850,6 +881,7 @@ class FusedWindowOperator:
         live = np.flatnonzero(counts > 0)
         if live.size == 0:
             return
+        self._count_rows(live.size)
         fdict: Dict[str, Any] = {
             f.name: (counts if f.source == ONE
                      else np.asarray(fields[f.name]))
@@ -863,7 +895,12 @@ class FusedWindowOperator:
         for i in live:
             sink.append((int(i), window, result[i].item(), ts))
 
+    def _count_rows(self, n: int) -> None:
+        if self.stage_clock is not None:
+            self.stage_clock.rows_emitted += int(n)
+
     def _emit_keydict_rows(self, window, counts, fields, live) -> None:
+        self._count_rows(live.size)
         fdict: Dict[str, Any] = {}
         for f in self.agg.fields:
             if f.source == ONE:
@@ -916,6 +953,7 @@ class FusedWindowOperator:
                                    {n: cfields[n][i] for n in cfields}))
         ts = window.max_timestamp()
         live = np.flatnonzero(counts > 0)
+        self._count_rows(live.size + len(extras))
         if live.size:
             fdict = {f.name: (counts if f.source == ONE else vals[f.name])
                      for f in self.agg.fields}

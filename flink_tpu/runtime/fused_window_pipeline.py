@@ -38,6 +38,7 @@ import numpy as np
 
 from flink_tpu.api.windowing.assigners import WindowAssigner
 from flink_tpu.core.time import MIN_WATERMARK, TimeWindow
+from flink_tpu.metrics.task_io import dispatch_stage
 from flink_tpu.ops.aggregators import DeviceAggregator, ONE, VALUE, resolve
 from flink_tpu.utils.arrays import canonical_column
 
@@ -96,6 +97,11 @@ class DeferredEmissions:
         # observability); folded into the pipeline's totals at resolve so
         # the readback rides the same async copy as the fire rows
         self._phase_counts = phase_counts
+        #: bytes resolve() reads back (the stage clock's d2hBytes)
+        self.nbytes = sum(
+            int(getattr(a, "nbytes", 0))
+            for a in (count_out, *outs.values(), key_bounds, phase_counts)
+            if a is not None)
         try:
             count_out.copy_to_host_async()
             for v in outs.values():
@@ -147,6 +153,7 @@ class _StreamedEmissions:
 
     def __init__(self, parts: List[DeferredEmissions]):
         self._parts = parts
+        self.nbytes = sum(p.nbytes for p in parts)
 
     def resolve(self):
         out = []
@@ -321,6 +328,7 @@ class FusedWindowPipeline:
         # attach_device_stats BEFORE the first dispatch — phase_counters is
         # part of the executable cache key.
         self.compile_tracker = None
+        self.stage_clock = None
         self.phase_counters = False
         self.phase_totals = np.zeros(3, np.int64)  # [ingest, fire, purge]
         # latency-mode dispatch shape (scheduler/latency_controller.py),
@@ -625,6 +633,11 @@ class FusedWindowPipeline:
         sig.update(program_extra)
         return sig
 
+    def attach_stage_clock(self, clock) -> None:
+        """The operator's stage clock (metrics/task_io.py): stage.fill and
+        stage.put report to it."""
+        self.stage_clock = clock
+
     def _tracked(self, program: str, fn, args: tuple, extra: Dict[str, Any]):
         """Dispatch through the attached CompileTracker (or directly)."""
         if self.compile_tracker is None:
@@ -819,67 +832,74 @@ class FusedWindowPipeline:
         import jax
         import jax.numpy as jnp
 
-        T = len(batches)
-        B = max(max((len(b[2]) for b in batches), default=0), 1)
-        B = -(-B // self.chunk) * self.chunk
+        clock = self.stage_clock
+        with dispatch_stage(clock, "stage.fill"):
+            T = len(batches)
+            B = max(max((len(b[2]) for b in batches), default=0), 1)
+            B = -(-B // self.chunk) * self.chunk
 
-        idx_h = np.full((T, B), -1, dtype=np.int32)
-        # value-less aggregates (count) carry a [T,1] placeholder instead of
-        # shipping a dead [T,B] f32 column to the device
-        vals_h = np.zeros((T, B if self._needs_vals else 1), dtype=np.float32)
-        smin_pos = np.zeros(T, dtype=np.int32)
-        fire_pos = np.zeros((T, self.F), dtype=np.int32)
-        fire_valid = np.zeros((T, self.F), dtype=np.int32)
-        fire_row = np.zeros((T, self.F), dtype=np.int32)
-        purge_mask = np.ones((T, self.S), dtype=np.int32)
-        fires: List[_PlannedFire] = []
+            idx_h = np.full((T, B), -1, dtype=np.int32)
+            # value-less aggregates (count) carry a [T,1] placeholder instead of
+            # shipping a dead [T,B] f32 column to the device
+            vals_h = np.zeros((T, B if self._needs_vals else 1), dtype=np.float32)
+            smin_pos = np.zeros(T, dtype=np.int32)
+            fire_pos = np.zeros((T, self.F), dtype=np.int32)
+            fire_valid = np.zeros((T, self.F), dtype=np.int32)
+            fire_row = np.zeros((T, self.F), dtype=np.int32)
+            purge_mask = np.ones((T, self.S), dtype=np.int32)
+            fires: List[_PlannedFire] = []
 
-        cur = self._cursor()
-        for t, (kid, vals, ts) in enumerate(batches):
-            n = len(ts)
-            s_abs = self._slice_of(np.asarray(ts, dtype=np.int64))
-            keep = np.ones(n, dtype=bool)
-            if cur.wm > MIN_WATERMARK:
-                keep = s_abs >= self._min_live_slice(cur.wm)
-                self.num_late_records_dropped += int(n - keep.sum())
-            if keep.any():
-                live = s_abs[keep]
-                smin = int(live.min())
-                cur.observe(smin, int(live.max()))
-                srel = (s_abs - smin).astype(np.int32)
-                # kid -1 = a cold-routed record (state/tier_manager.py):
-                # it rides the step so fires over its slices get PLANNED,
-                # but it must never scatter into a hot row — mask to the
-                # same -1 the ingest drops (pad-row semantics)
-                kid64 = np.asarray(kid, dtype=np.int64)
-                idx_h[t, :n] = np.where(
-                    keep & (kid64 >= 0), kid64 * self.NSB + srel, -1
-                ).astype(np.int32)
-                if vals is not None and self._needs_vals:
-                    vals_h[t, :n] = np.where(keep, vals, 0.0)
-                smin_pos[t] = smin % self.S
-            cur.advance(t, watermarks[t], fire_pos, fire_valid, fire_row,
-                        purge_mask, fires)
-        cur.commit()
+            cur = self._cursor()
+            for t, (kid, vals, ts) in enumerate(batches):
+                n = len(ts)
+                s_abs = self._slice_of(np.asarray(ts, dtype=np.int64))
+                keep = np.ones(n, dtype=bool)
+                if cur.wm > MIN_WATERMARK:
+                    keep = s_abs >= self._min_live_slice(cur.wm)
+                    self.num_late_records_dropped += int(n - keep.sum())
+                if keep.any():
+                    live = s_abs[keep]
+                    smin = int(live.min())
+                    cur.observe(smin, int(live.max()))
+                    srel = (s_abs - smin).astype(np.int32)
+                    # kid -1 = a cold-routed record (state/tier_manager.py):
+                    # it rides the step so fires over its slices get PLANNED,
+                    # but it must never scatter into a hot row — mask to the
+                    # same -1 the ingest drops (pad-row semantics)
+                    kid64 = np.asarray(kid, dtype=np.int64)
+                    idx_h[t, :n] = np.where(
+                        keep & (kid64 >= 0), kid64 * self.NSB + srel, -1
+                    ).astype(np.int32)
+                    if vals is not None and self._needs_vals:
+                        vals_h[t, :n] = np.where(keep, vals, 0.0)
+                    smin_pos[t] = smin % self.S
+                cur.advance(t, watermarks[t], fire_pos, fire_valid, fire_row,
+                            purge_mask, fires)
+            cur.commit()
 
-        if self._use_pallas():
-            # the fused kernel consumes flat [T*B] chunk streams; flatten on
-            # host (free: idx_h is contiguous) so no device reshape is needed
-            idx_d = jax.device_put(idx_h.reshape(-1))
-            vals_d = jax.device_put(
-                vals_h.reshape(-1) if self._needs_vals else vals_h
+        with dispatch_stage(clock, "stage.put"):
+            if self._use_pallas():
+                # the fused kernel consumes flat [T*B] chunk streams; flatten on
+                # host (free: idx_h is contiguous) so no device reshape is needed
+                idx_d = jax.device_put(idx_h.reshape(-1))
+                vals_d = jax.device_put(
+                    vals_h.reshape(-1) if self._needs_vals else vals_h
+                )
+            else:
+                idx_d = jax.device_put(idx_h)
+                vals_d = jax.device_put(vals_h)
+            plan = (
+                jax.device_put(smin_pos),
+                jax.device_put(fire_pos),
+                jax.device_put(fire_valid),
+                jax.device_put(fire_row),
+                jax.device_put(purge_mask),
+                fires,
             )
-        else:
-            idx_d = jax.device_put(idx_h)
-            vals_d = jax.device_put(vals_h)
-        plan = (
-            jax.device_put(smin_pos),
-            jax.device_put(fire_pos),
-            jax.device_put(fire_valid),
-            jax.device_put(fire_row),
-            jax.device_put(purge_mask),
-            fires,
-        )
+            if clock is not None:
+                clock.staged((idx_h, vals_h, smin_pos, fire_pos, fire_valid,
+                              fire_row, purge_mask),
+                             sum(len(b[2]) for b in batches))
         return idx_d, vals_d, plan
 
     def plan_superbatch(self, slice_bounds, watermarks):
@@ -899,39 +919,45 @@ class FusedWindowPipeline:
         """
         import jax
 
-        T = len(slice_bounds)
-        assert T == len(watermarks)
-        smin_pos = np.zeros(T, dtype=np.int32)
-        smin_abs = np.zeros(T, dtype=np.int32)
-        fire_pos = np.zeros((T, self.F), dtype=np.int32)
-        fire_valid = np.zeros((T, self.F), dtype=np.int32)
-        fire_row = np.zeros((T, self.F), dtype=np.int32)
-        purge_mask = np.ones((T, self.S), dtype=np.int32)
-        fires: List[_PlannedFire] = []
+        clock = self.stage_clock
+        with dispatch_stage(clock, "stage.fill"):
+            T = len(slice_bounds)
+            assert T == len(watermarks)
+            smin_pos = np.zeros(T, dtype=np.int32)
+            smin_abs = np.zeros(T, dtype=np.int32)
+            fire_pos = np.zeros((T, self.F), dtype=np.int32)
+            fire_valid = np.zeros((T, self.F), dtype=np.int32)
+            fire_row = np.zeros((T, self.F), dtype=np.int32)
+            purge_mask = np.ones((T, self.S), dtype=np.int32)
+            fires: List[_PlannedFire] = []
 
-        cur = self._cursor()
-        for t, (smin, smax) in enumerate(slice_bounds):
-            if cur.wm > MIN_WATERMARK and smin < self._min_live_slice(cur.wm):
-                raise ValueError(
-                    "plan_superbatch requires a late-free schedule: step "
-                    f"{t} smin={smin} is below the live frontier "
-                    f"{self._min_live_slice(cur.wm)}"
-                )
-            cur.observe(smin, smax)
-            smin_pos[t] = smin % self.S
-            smin_abs[t] = smin
-            cur.advance(t, watermarks[t], fire_pos, fire_valid, fire_row,
-                        purge_mask, fires)
-        cur.commit()
+            cur = self._cursor()
+            for t, (smin, smax) in enumerate(slice_bounds):
+                if cur.wm > MIN_WATERMARK and smin < self._min_live_slice(cur.wm):
+                    raise ValueError(
+                        "plan_superbatch requires a late-free schedule: step "
+                        f"{t} smin={smin} is below the live frontier "
+                        f"{self._min_live_slice(cur.wm)}"
+                    )
+                cur.observe(smin, smax)
+                smin_pos[t] = smin % self.S
+                smin_abs[t] = smin
+                cur.advance(t, watermarks[t], fire_pos, fire_valid, fire_row,
+                            purge_mask, fires)
+            cur.commit()
 
-        plan = (
-            jax.device_put(smin_pos),
-            jax.device_put(fire_pos),
-            jax.device_put(fire_valid),
-            jax.device_put(fire_row),
-            jax.device_put(purge_mask),
-            fires,
-        )
+        with dispatch_stage(clock, "stage.put"):
+            plan = (
+                jax.device_put(smin_pos),
+                jax.device_put(fire_pos),
+                jax.device_put(fire_valid),
+                jax.device_put(fire_row),
+                jax.device_put(purge_mask),
+                fires,
+            )
+            if clock is not None:
+                clock.staged((smin_pos, fire_pos, fire_valid, fire_row,
+                              purge_mask))
         return plan, smin_abs
 
     # ------------------------------------------------------------------
@@ -954,11 +980,18 @@ class FusedWindowPipeline:
         as live."""
         import jax
 
-        raw_h, srel_h, ts_h, plan_np, fires = self._stage_raw_host(
-            steps, watermarks)
-        plan = tuple(jax.device_put(a) for a in plan_np) + (fires,)
-        ts_d = jax.device_put(ts_h) if ts_h is not None else None
-        return jax.device_put(raw_h), jax.device_put(srel_h), ts_d, plan
+        clock = self.stage_clock
+        with dispatch_stage(clock, "stage.fill"):
+            raw_h, srel_h, ts_h, plan_np, fires = self._stage_raw_host(
+                steps, watermarks)
+        with dispatch_stage(clock, "stage.put"):
+            plan = tuple(jax.device_put(a) for a in plan_np) + (fires,)
+            ts_d = jax.device_put(ts_h) if ts_h is not None else None
+            staged = jax.device_put(raw_h), jax.device_put(srel_h), ts_d, plan
+            if clock is not None:
+                clock.staged((raw_h, srel_h, ts_h) + plan_np,
+                             sum(len(step[1]) for step in steps))
+        return staged
 
     def _stage_raw_host(self, steps, watermarks):
         """The host half of stage_superbatch_raw: plan + fill the staging
@@ -1231,44 +1264,45 @@ class FusedWindowPipeline:
                 raw, srel = args[0], args[1]
                 ts = None
                 rest = args[2:]
-            col = raw
-            mask = srel >= 0
-            for kind, fn in transforms:
-                if kind == "map":
-                    col = fn(col)
-                elif kind == "map_ts":
-                    col = fn(col, ts)
-                else:  # filter
-                    mask = mask & jnp.asarray(fn(col)).astype(bool)
-            keys = jnp.asarray(key_fn(col)).astype(jnp.int32)
-            live = mask & (keys >= 0) & (keys < K)
-            idx = jnp.where(live, keys * NSB + srel, jnp.int32(-1))
-            idx = idx.astype(jnp.int32)
-            if needs_vals:
-                vcol = value_fn(col) if value_fn is not None else col
-                # dead/pad rows hold uninitialized staging bytes that can
-                # decode as NaN/inf; zero them BEFORE ingest — the matmul
-                # histogram multiplies the zero one-hot by the raw value,
-                # and 0 * NaN = NaN would poison every sum in the chunk
-                # (the scatter path drops by index, but identical inputs
-                # keep both ingest forms bit-identical)
-                vals = jnp.where(
-                    live, jnp.asarray(vcol).astype(jnp.float32), 0.0)
-            else:
-                vals = jnp.zeros((1,), jnp.float32)
-            # key range observed over every SURVIVING record (pre range
-            # clamp): an out-of-range key is a hard error at resolve, never
-            # a silent drop or a silent alias of another key's row
-            key_bounds = jnp.stack([
-                jnp.maximum(key_bounds[0],
-                            jnp.max(jnp.where(mask, keys, jnp.int32(-1)))),
-                jnp.minimum(key_bounds[1],
-                            jnp.min(jnp.where(mask, keys, jnp.int32(0)))),
-            ])
+            with jax.named_scope("prologue"):
+                col = raw
+                mask = srel >= 0
+                for kind, fn in transforms:
+                    if kind == "map":
+                        col = fn(col)
+                    elif kind == "map_ts":
+                        col = fn(col, ts)
+                    else:  # filter
+                        mask = mask & jnp.asarray(fn(col)).astype(bool)
+                keys = jnp.asarray(key_fn(col)).astype(jnp.int32)
+                live = mask & (keys >= 0) & (keys < K)
+                idx = jnp.where(live, keys * NSB + srel, jnp.int32(-1))
+                idx = idx.astype(jnp.int32)
+                if needs_vals:
+                    vcol = value_fn(col) if value_fn is not None else col
+                    # dead/pad rows hold uninitialized staging bytes that can
+                    # decode as NaN/inf; zero them BEFORE ingest — the matmul
+                    # histogram multiplies the zero one-hot by the raw value,
+                    # and 0 * NaN = NaN would poison every sum in the chunk
+                    # (the scatter path drops by index, but identical inputs
+                    # keep both ingest forms bit-identical)
+                    vals = jnp.where(
+                        live, jnp.asarray(vcol).astype(jnp.float32), 0.0)
+                else:
+                    vals = jnp.zeros((1,), jnp.float32)
+                # key range observed over every SURVIVING record (pre range
+                # clamp): an out-of-range key is a hard error at resolve, never
+                # a silent drop or a silent alias of another key's row
+                key_bounds = jnp.stack([
+                    jnp.maximum(key_bounds[0],
+                                jnp.max(jnp.where(mask, keys, jnp.int32(-1)))),
+                    jnp.minimum(key_bounds[1],
+                                jnp.min(jnp.where(mask, keys, jnp.int32(0)))),
+                ])
             inner, _ = step(inner, (idx, vals) + rest)
             return (inner, key_bounds), None
 
-        def run(state, count, outs, count_out, *xs):
+        def run_fused_chained_superscan(state, count, outs, count_out, *xs):
             kb0 = jnp.asarray([-1, 0], jnp.int32)
             inner0 = (state, count, outs, count_out)
             if phases:
@@ -1285,8 +1319,9 @@ class FusedWindowPipeline:
             # the dispatch is enqueued (the pipeline rebinds to the outputs
             # unconditionally), so hand them to XLA for in-place reuse —
             # the deferred handles hold OUTPUT buffers, never the carry
-            return jax.jit(run, donate_argnums=(0, 1))
-        return jax.jit(run)
+            return jax.jit(run_fused_chained_superscan,
+                           donate_argnums=(0, 1))
+        return jax.jit(run_fused_chained_superscan)
 
     # ------------------------------------------------------------------
     def snapshot(self) -> dict:
@@ -1363,10 +1398,13 @@ def _build_superscan(agg, K, S, NSB, F, R, SPW, chunk, exact, T, B,
     jit = (functools.partial(jax.jit, donate_argnums=(0, 1)) if donate
            else jax.jit)
 
+    # the function's name is the program's name on the device: the trace's
+    # module reads jit_run_<CompileTracker program>
     if phases:
         @jit
-        def run(state, count, outs, count_out, idx, vals, smin_pos,
-                fire_pos, fire_valid, fire_row, purge_mask):
+        def run_fused_superscan(state, count, outs, count_out, idx, vals,
+                                smin_pos, fire_pos, fire_valid, fire_row,
+                                purge_mask):
             carry0 = (state, count, outs, count_out,
                       jnp.zeros((3,), jnp.int32))
             (state, count, outs, count_out, pc), _ = jax.lax.scan(
@@ -1376,10 +1414,12 @@ def _build_superscan(agg, K, S, NSB, F, R, SPW, chunk, exact, T, B,
             )
             return state, count, outs, count_out, pc
 
-        return run
+        return run_fused_superscan
 
     @jit
-    def run(state, count, outs, count_out, idx, vals, smin_pos, fire_pos, fire_valid, fire_row, purge_mask):
+    def run_fused_superscan(state, count, outs, count_out, idx, vals,
+                            smin_pos, fire_pos, fire_valid, fire_row,
+                            purge_mask):
         (state, count, outs, count_out), _ = jax.lax.scan(
             step,
             (state, count, outs, count_out),
@@ -1387,7 +1427,7 @@ def _build_superscan(agg, K, S, NSB, F, R, SPW, chunk, exact, T, B,
         )
         return state, count, outs, count_out
 
-    return run
+    return run_fused_superscan
 
 
 # ---------------------------------------------------------------------------

@@ -28,7 +28,7 @@ from flink_tpu.metrics.registry import (
     prometheus_text,
     prometheus_text_from_snapshot,
 )
-from flink_tpu.metrics.task_io import DeviceTimer, TaskIOMetrics
+from flink_tpu.metrics.task_io import StageClock, TaskIOMetrics
 from flink_tpu.metrics.traces import Span, TraceRegistry, job_trace_id
 from flink_tpu.runtime.minicluster import JobStatus, MiniCluster
 from flink_tpu.runtime.rest import RestServer
@@ -160,7 +160,7 @@ def test_metrics_snapshot_plain_data():
 
 
 # ---------------------------------------------------------------------------
-# TaskIOMetrics + DeviceTimer
+# TaskIOMetrics + the stage clock's outer sections (DeviceTimer's case, ported)
 # ---------------------------------------------------------------------------
 
 def test_task_io_ratios_and_windowed_sampling():
@@ -188,9 +188,9 @@ def test_task_io_ratios_and_windowed_sampling():
             "job.backPressuredTimeMsPerSecond"} <= keys
 
 
-def test_device_timer_sections_accumulate():
+def test_stage_clock_outer_sections_accumulate():
     h = Histogram()
-    t = DeviceTimer(histogram=h)
+    t = StageClock(histogram=h)
     for _ in range(3):
         with t.section():
             time.sleep(0.002)
