@@ -229,6 +229,10 @@ class StageClock:
         self.h2d_bytes = 0
         self.d2h_bytes = 0
         self.events_staged = 0
+        # the newest traced-chain dispatch: fields staged / fields of the
+        # record (both 0 on the host-keyed path, which ships key ids)
+        self.columns_staged = 0
+        self.record_columns = 0
         self.rows_emitted = 0
         self.seq = 0            # the dispatch being staged (`dispatches` so far)
         self.total_s = 0.0
@@ -256,11 +260,14 @@ class StageClock:
     def section(self) -> "_Section":
         return StageClock._Section(self)
 
-    def staged(self, arrays, events: int = 0) -> None:
+    def staged(self, arrays, events: int = 0, columns=None) -> None:
         """Host arrays handed to `jax.device_put` in a stage.put, and the
-        events they carry."""
+        events they carry; `columns` = (fields staged, fields of the
+        record) where the dispatch ships a traced chain's record."""
         self.h2d_bytes += sum(a.nbytes for a in arrays if a is not None)
         self.events_staged += events
+        if columns is not None:
+            self.columns_staged, self.record_columns = columns
 
     def stage_table(self) -> Dict[str, Dict[str, float]]:
         return merge_stage_tables((self,))
@@ -268,6 +275,8 @@ class StageClock:
     def link(self) -> Dict[str, int]:
         return {"h2dBytes": self.h2d_bytes, "d2hBytes": self.d2h_bytes,
                 "eventsStaged": self.events_staged,
+                "columnsStaged": self.columns_staged,
+                "recordColumns": self.record_columns,
                 "rowsEmitted": self.rows_emitted, "dispatches": self.seq}
 
     def register(self, group) -> None:

@@ -673,12 +673,15 @@ class ShardedFusedPipeline:
     # to its key-range owner — the keyBy shuffle as an ICI collective
     # inside the compiled scan, replacing the host dataplane hop
     # ------------------------------------------------------------------
-    def _build_raw(self, T: int, B: int):
+    def _build_raw(self, T: int, B: int, layout=None):
         phases = self.phase_counters
         combine = self.local_combine
         routed = self.routing is not None
+        # layout (the planner's ColumnLayout): the staged fields and the
+        # record's width — chains reading different fields never share one
         key = ("raw", T, B, phases, combine,
-               None if not routed else self.routing.G, self.donate_carry)
+               None if not routed else self.routing.G, self.donate_carry,
+               layout)
         if key in self._fn_cache:
             return self._fn_cache[key]
 
@@ -691,8 +694,6 @@ class ShardedFusedPipeline:
         scatters = [f.scatter for f in self._value_fields]
         pro = self.prologue
         needs_ts = pro.needs_ts
-        transforms = tuple(pro.transforms)
-        key_fn, value_fn = pro.key_fn, pro.value_fn
 
         def per_shard(count, state_t, raw, srel, *rest):
             if routed:
@@ -701,7 +702,7 @@ class ShardedFusedPipeline:
             else:
                 owner = self._dst_and_local(None)
             count = count[0]
-            raw = raw[0]
+            raw = jax.tree.map(lambda a: a[0], raw)
             srel = srel[0]
             if needs_ts:
                 ts, rest = rest[0][0], rest[1:]
@@ -730,39 +731,9 @@ class ShardedFusedPipeline:
                 # any routing: filter/projection/keying happen where the
                 # data landed, only survivors cross the interconnect
                 with jax.named_scope("prologue"):
-                    col = raw_row
-                    mask = srel_row >= 0
-                    for kind, fn in transforms:
-                        if kind == "map":
-                            col = fn(col)
-                        elif kind == "map_ts":
-                            col = fn(col, ts_row)
-                        else:  # filter
-                            mask = mask & jnp.asarray(fn(col)).astype(bool)
-                    keys = jnp.asarray(key_fn(col)).astype(jnp.int32)
-                    live = mask & (keys >= 0) & (keys < K)
-                    idx = jnp.where(live, keys * NSB + srel_row,
-                                    jnp.int32(-1)).astype(jnp.int32)
-                    # key range observed over every SURVIVING record (pre range
-                    # clamp), exactly like the single-chip chained program: an
-                    # out-of-range key is a hard error at resolve, never a
-                    # silent drop or a silent alias of another shard's row
-                    key_bounds = jnp.stack([
-                        jnp.maximum(key_bounds[0],
-                                    jnp.max(jnp.where(mask, keys, jnp.int32(-1)))),
-                        jnp.minimum(key_bounds[1],
-                                    jnp.min(jnp.where(mask, keys, jnp.int32(0)))),
-                    ])
-                    if nf:
-                        vcol = value_fn(col) if value_fn is not None else col
-                        # dead/pad rows hold uninitialized staging bytes; zero
-                        # them BEFORE the shuffle so 0 * NaN can never poison
-                        # an owner shard's sums (combine path: a NaN times a
-                        # zero one-hot in the partial histogram, same hazard)
-                        vals = jnp.where(
-                            live, jnp.asarray(vcol).astype(jnp.float32), 0.0)
-                    else:
-                        vals = jnp.zeros((1,), jnp.float32)
+                    live, keys, idx, vals, key_bounds = pro.apply(
+                        raw_row, srel_row, ts_row, key_bounds, K=K, NSB=NSB,
+                        needs_vals=bool(nf), layout=layout)
                 if combine:
                     # the map-side combiner: this shard's survivors
                     # segment-reduce by (owner, key, rel-slice) and ONLY
@@ -831,7 +802,11 @@ class ShardedFusedPipeline:
                 out = out + (pc[None],)
             return out
 
-        raw_ndim = 3 + len(self._planner._raw_shape or ())
+        if layout is not None:
+            raw_spec = (P(axis, None, None),) * len(layout.columns)
+        else:
+            raw_spec = P(axis, None, None,
+                         *([None] * len(self._planner._raw_shape or ())))
         out_specs = (
             P(axis, None, None),
             (P(axis, None, None),) * nf,
@@ -844,7 +819,7 @@ class ShardedFusedPipeline:
         in_specs = (
             P(axis, None, None),                          # count [n,Kl,S]
             (P(axis, None, None),) * nf,                  # field states
-            P(axis, *([None] * (raw_ndim - 1))),          # raw [n,T,Bs,...]
+            raw_spec,                       # raw [n,T,Bs,...] or its fields
             P(axis, None, None),                          # srel [n,T,Bs]
         )
         if needs_ts:
@@ -893,34 +868,32 @@ class ShardedFusedPipeline:
             T, B = srel_h.shape
             n = self.n
             Bs = -(-B // n)
-            if Bs * n != B:
-                pad = Bs * n - B
-                srel_h = np.concatenate(
-                    [srel_h, np.full((T, pad), -1, np.int32)], axis=1)
-                raw_h = np.concatenate(
-                    [raw_h,
-                     np.zeros((T, pad) + raw_h.shape[2:], raw_h.dtype)],
-                    axis=1)
-                if ts_h is not None:
-                    ts_h = np.concatenate(
-                        [ts_h, np.zeros((T, pad), ts_h.dtype)], axis=1)
-            trail = raw_h.shape[2:]
-            raw_sh = raw_h.reshape((T, n, Bs) + trail).transpose(
-                (1, 0, 2) + tuple(range(3, 3 + len(trail))))
-            srel_sh = srel_h.reshape(T, n, Bs).transpose(1, 0, 2)
-            ts_sh = (None if ts_h is None
-                     else ts_h.reshape(T, n, Bs).transpose(1, 0, 2))
+
+            def deal(a, fill):
+                # [T, B, ...] -> [n, T, Bs, ...]: lanes dealt contiguously
+                if Bs * n != B:
+                    a = np.concatenate(
+                        [a, np.full((T, Bs * n - B) + a.shape[2:], fill,
+                                    a.dtype)], axis=1)
+                return np.swapaxes(a.reshape((T, n, Bs) + a.shape[2:]), 0, 1)
+
+            srel_sh = deal(srel_h, -1)
+            ts_sh = None if ts_h is None else deal(ts_h, 0)
+            fields_h, columns = self._planner._record_fields(raw_h)
+            fields_sh = tuple(deal(f, 0) for f in fields_h)
         with dispatch_stage(clock, "stage.put"):
-            raw_d = jax.device_put(
-                raw_sh, self._shard_spec(*([None] * (2 + len(trail)))))
+            fields_d = tuple(
+                jax.device_put(f, self._shard_spec(*([None] * (f.ndim - 1))))
+                for f in fields_sh)
+            raw_d = fields_d if isinstance(raw_h, tuple) else fields_d[0]
             srel_d = jax.device_put(srel_sh, self._shard_spec(None, None))
             ts_d = None
             if ts_sh is not None:
                 ts_d = jax.device_put(ts_sh, self._shard_spec(None, None))
             plan = tuple(jax.device_put(a) for a in plan_np) + (fires,)
             if clock is not None:
-                clock.staged((raw_sh, srel_sh, ts_sh) + plan_np,
-                             sum(len(step[1]) for step in steps))
+                clock.staged(fields_sh + (srel_sh, ts_sh) + plan_np,
+                             sum(len(step[1]) for step in steps), columns)
         return raw_d, srel_d, ts_d, plan
 
     def process_superbatch_raw(self, steps, watermarks, *,
@@ -945,7 +918,8 @@ class ShardedFusedPipeline:
         smin_pos, fire_pos, fire_valid, fire_row, purge_mask, fires = plan
         T = int(srel_d.shape[1])
         B = int(srel_d.shape[2])
-        run = self._build_raw(T, B)
+        layout = self._planner._staged_layout(raw_d)
+        run = self._build_raw(T, B, layout)
         names = [f.name for f in self._value_fields]
         args = (self._count, tuple(self._state[nm] for nm in names),
                 raw_d, srel_d)
@@ -958,7 +932,7 @@ class ShardedFusedPipeline:
             out = self.compile_tracker.call(
                 "sharded_chained_superscan", run, args,
                 {"T": T, "B": B, "K": self.K, "S": self.S, "n": self.n,
-                 "raw_dtype": str(raw_d.dtype),
+                 **self._planner._record_signature(raw_d, layout),
                  "dtype": "+".join(str(np.dtype(f.dtype))
                                    for f in self._value_fields) or "count"})
         else:

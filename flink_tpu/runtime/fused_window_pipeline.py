@@ -32,7 +32,8 @@ allowed_lateness == 0, dense int keys or pre-densified key ids.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+import functools
+from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -55,6 +56,19 @@ class _PlannedFire:
     spec: int = 0     # window spec (shared-partial pipelines; 0 otherwise)
 
 
+class ColumnLayout(NamedTuple):
+    """The staged form of a rank-1 record ([n, width] per batch): one
+    lane-dense [T, B] array per field the traced chain reads, nothing for
+    the fields it does not read."""
+
+    columns: Tuple[int, ...]   # fields staged, ascending
+    width: int                 # fields of the record
+    dtype: str                 # the record's canonical dtype
+
+    def __str__(self) -> str:  # the CompileTracker signature's "columns"
+        return "+".join(map(str, self.columns)) + f"/{self.width}"
+
+
 @dataclasses.dataclass(frozen=True)
 class TracedPrologue:
     """The traced pre-stage of a fused device chain (whole-graph fusion,
@@ -72,6 +86,121 @@ class TracedPrologue:
     @property
     def needs_ts(self) -> bool:
         return any(kind == "map_ts" for kind, _fn in self.transforms)
+
+    def column_layout(self, raw_shape, raw_dtype,
+                      needs_vals: bool) -> Optional[ColumnLayout]:
+        """How a record of `raw_shape` per event is staged: per-field
+        arrays of the fields the chain reads when the record is rank 1,
+        None (the record array as it is) for scalars and higher ranks."""
+        if len(raw_shape) != 1:
+            return None
+        return _column_layout(self, int(raw_shape[0]),
+                              np.dtype(raw_dtype).name, bool(needs_vals))
+
+    def apply(self, raw, srel, ts, key_bounds, *, K: int, NSB: int,
+              needs_vals: bool, layout: Optional[ColumnLayout] = None):
+        """One scan step of the chain, traced: record lanes -> (live, keys,
+        idx, vals, key_bounds). THE prologue of the single-chip, shared and
+        sharded programs, and what `column_layout` reads the fields from.
+
+        raw: the record lanes [B, ...], or with a `layout` the staged
+        fields ([B] each). The record handed to the user's functions is
+        rebuilt with zeros for the fields that were not staged — fields no
+        equation of this very trace reads; XLA folds each static slice of
+        the stack to its operand, so no [B, width] array exists on the
+        device."""
+        import jax.numpy as jnp
+
+        if layout is None:
+            col = raw
+        else:
+            staged = dict(zip(layout.columns, raw))
+            unread = jnp.zeros(srel.shape, layout.dtype)
+            col = jnp.stack([staged.get(c, unread)
+                             for c in range(layout.width)], axis=1)
+        mask = srel >= 0
+        for kind, fn in self.transforms:
+            if kind == "map":
+                col = fn(col)
+            elif kind == "map_ts":
+                col = fn(col, ts)
+            else:  # filter
+                mask = mask & jnp.asarray(fn(col)).astype(bool)
+        keys = jnp.asarray(self.key_fn(col)).astype(jnp.int32)
+        live = mask & (keys >= 0) & (keys < K)
+        idx = jnp.where(live, keys * NSB + srel, jnp.int32(-1))
+        idx = idx.astype(jnp.int32)
+        if needs_vals:
+            vcol = self.value_fn(col) if self.value_fn is not None else col
+            # dead/pad rows hold uninitialized staging bytes that can
+            # decode as NaN/inf; zero them BEFORE ingest or any shuffle —
+            # the matmul histogram multiplies the zero one-hot by the raw
+            # value, and 0 * NaN = NaN would poison every sum in the chunk
+            # (the scatter path drops by index, but identical inputs keep
+            # both ingest forms bit-identical)
+            vals = jnp.where(
+                live, jnp.asarray(vcol).astype(jnp.float32), 0.0)
+        else:
+            vals = jnp.zeros((1,), jnp.float32)
+        # key range observed over every SURVIVING record (pre range clamp):
+        # an out-of-range key is a hard error at resolve, never a silent
+        # drop or a silent alias of another key's (or shard's) row
+        key_bounds = jnp.stack([
+            jnp.maximum(key_bounds[0],
+                        jnp.max(jnp.where(mask, keys, jnp.int32(-1)))),
+            jnp.minimum(key_bounds[1],
+                        jnp.min(jnp.where(mask, keys, jnp.int32(0)))),
+        ])
+        return live, keys, idx, vals, key_bounds
+
+
+#: rows of the record the column analysis traces the chain on (column
+#: functions are per-record: the host chain runs them on any batch length)
+_ANALYSIS_ROWS = 8
+
+
+@functools.lru_cache(maxsize=256)
+def _column_layout(pro: TracedPrologue, width: int, dtype: str,
+                   needs_vals: bool) -> ColumnLayout:
+    """Which fields of a [n, width] record the chain reads, from the jaxpr
+    of `pro.apply` — conservatively. Every equation that consumes the
+    record variable has to be a `slice` over all rows with unit stride
+    (what `col[:, c]` and `col[:, a:b]` with static bounds lower to); the
+    union of their field ranges is the answer. Any other consumer — a
+    matmul, a reduction over the fields, `dynamic_slice` (a traced or a
+    negative index), a gather, a nested jit / scan taking the record — reads
+    every field."""
+    import jax
+    import jax.numpy as jnp
+    from jax import dtypes as _jdt
+
+    B = _ANALYSIS_ROWS
+    cdtype = np.dtype(_jdt.canonicalize_dtype(dtype))
+    every = ColumnLayout(tuple(range(width)), width, cdtype.name)
+
+    def chain(rec, srel, ts, key_bounds):
+        return pro.apply(rec, srel, ts, key_bounds, K=1, NSB=1,
+                         needs_vals=needs_vals)
+
+    lanes = jax.ShapeDtypeStruct((B,), jnp.int32)
+    ts = (jax.ShapeDtypeStruct((B,), _jdt.canonicalize_dtype(np.int64))
+          if pro.needs_ts else None)
+    jaxpr = jax.make_jaxpr(chain)(
+        jax.ShapeDtypeStruct((B, width), cdtype), lanes, ts,
+        jax.ShapeDtypeStruct((2,), jnp.int32)).jaxpr
+    rec = jaxpr.invars[0]   # never an output: `apply` hands on derived lanes
+    read = set()
+    for eqn in jaxpr.eqns:
+        if not any(v is rec for v in eqn.invars):
+            continue
+        p = eqn.params
+        if (eqn.primitive.name != "slice"
+                or p["strides"] not in (None, (1, 1))
+                or p["start_indices"][0] != 0
+                or p["limit_indices"][0] != B):
+            return every
+        read.update(range(p["start_indices"][1], p["limit_indices"][1]))
+    return every._replace(columns=tuple(sorted(read)))
 
 
 #: compiled chained-superscan executables, shared across pipeline instances
@@ -987,18 +1116,51 @@ class FusedWindowPipeline:
         with dispatch_stage(clock, "stage.put"):
             plan = tuple(jax.device_put(a) for a in plan_np) + (fires,)
             ts_d = jax.device_put(ts_h) if ts_h is not None else None
+            # raw_h: the record array, or a tuple of its staged fields
             staged = jax.device_put(raw_h), jax.device_put(srel_h), ts_d, plan
             if clock is not None:
-                clock.staged((raw_h, srel_h, ts_h) + plan_np,
-                             sum(len(step[1]) for step in steps))
+                fields_h, columns = self._record_fields(raw_h)
+                clock.staged(fields_h + (srel_h, ts_h) + plan_np,
+                             sum(len(step[1]) for step in steps), columns)
         return staged
+
+    def _layout(self) -> Optional[ColumnLayout]:
+        """The staged form of this stream's record (None: the record array
+        as it is — scalars, higher ranks, or no record seen yet)."""
+        if self._raw_shape is None:
+            return None
+        return self.prologue.column_layout(
+            self._raw_shape, self._raw_dtype, self._needs_vals)
+
+    def _staged_layout(self, raw) -> Optional[ColumnLayout]:
+        """The layout a staged record (host or device side) was filled by."""
+        return self._layout() if isinstance(raw, tuple) else None
+
+    def _record_fields(self, raw) -> Tuple[tuple, Tuple[int, int]]:
+        """A staged record's arrays, and (fields staged, fields of the
+        record) for the stage clock's link row."""
+        layout = self._staged_layout(raw)
+        if layout is not None:
+            return raw, (len(raw), layout.width)
+        width = int(np.prod(self._raw_shape or ()))
+        return (raw,), (width, width)
+
+    @staticmethod
+    def _record_signature(raw_d, layout: Optional[ColumnLayout]) -> Dict[str, str]:
+        """The record's part of a chained dispatch's CompileTracker
+        signature."""
+        if layout is None:
+            return {"raw_dtype": str(raw_d.dtype), "columns": "record"}
+        return {"raw_dtype": layout.dtype, "columns": str(layout)}
 
     def _stage_raw_host(self, steps, watermarks):
         """The host half of stage_superbatch_raw: plan + fill the staging
         buffers, but leave device placement to the caller — the sharded
         pipeline (parallel/sharded_superscan.py) re-shapes the same buffers
         onto mesh lanes and device_puts them with a NamedSharding instead.
-        Returns (raw_h, srel_h, ts_h|None, plan_arrays, fires)."""
+        Returns (raw_h, srel_h, ts_h|None, plan_arrays, fires); raw_h is a
+        tuple of [T, B] arrays, one per field of `_layout().columns`, for a
+        rank-1 record, and one [T, B, ...] array for any other."""
         if self.prologue is None:
             raise RuntimeError("stage_superbatch_raw requires a prologue")
         T = len(steps)
@@ -1047,8 +1209,13 @@ class FusedWindowPipeline:
         # dispatch — a full extra copy, and the garbage pad bytes overflow
         # the narrowing float cast (RuntimeWarning). Real rows cast at fill.
         from jax import dtypes as _jdt
-        raw_h = np.empty((T, B) + raw_shape,
-                         dtype=_jdt.canonicalize_dtype(raw_dtype))
+        layout = self._layout()
+        if layout is None:
+            raw_h = np.empty((T, B) + raw_shape,
+                             dtype=_jdt.canonicalize_dtype(raw_dtype))
+        else:
+            raw_h = tuple(np.empty((T, B), dtype=layout.dtype)
+                          for _c in layout.columns)
         srel_h = np.full((T, B), -1, dtype=np.int32)
         ts_h = (np.empty((T, B), dtype=_jdt.canonicalize_dtype(np.int64))
                 if self.prologue.needs_ts else None)
@@ -1082,9 +1249,17 @@ class FusedWindowPipeline:
                 # narrowing into the staging dtype must not silently wrap
                 # (same contract as the timestamp guard below); the host
                 # fallback casts through the same helper, so both paths
-                # compute on identical canonical inputs
-                raw_h[t, :n] = canonical_column(
-                    raw, "fused chain record column")
+                # compute on identical canonical inputs. With a layout
+                # the check covers the staged fields: a field no traced
+                # function reads never reaches one
+                if layout is None:
+                    raw_h[t, :n] = canonical_column(
+                        raw, "fused chain record column")
+                else:
+                    rec = np.asarray(raw)
+                    for field_h, c in zip(raw_h, layout.columns):
+                        field_h[t, :n] = canonical_column(
+                            rec[:, c], f"fused chain record column {c}")
                 if ts_h is not None:
                     if ts_h.dtype.itemsize < 8 and (
                         int(ts_arr.max()) > np.iinfo(ts_h.dtype).max
@@ -1138,7 +1313,8 @@ class FusedWindowPipeline:
                 T, B, Tg, raw_d, srel_d, ts_d, smin_pos, fire_pos,
                 fire_valid, fire_row, purge_mask, fires)
             return deferred if defer else deferred.resolve()
-        run = self._chained_superscan(T, B)
+        layout = self._staged_layout(raw_d)
+        run = self._chained_superscan(T, B, layout)
         outs0 = {
             f.name: jnp.zeros((self.R, self.K), jnp.dtype(f.dtype))
             for f in self._value_fields
@@ -1151,7 +1327,7 @@ class FusedWindowPipeline:
         out = self._tracked(
             "fused_chained_superscan", run,
             (self._state, self._count, outs0, count_out0) + xs,
-            {"T": T, "B": B, "raw_dtype": str(raw_d.dtype)},
+            {"T": T, "B": B, **self._record_signature(raw_d, layout)},
         )
         pc = None
         if self.phase_counters:
@@ -1179,7 +1355,8 @@ class FusedWindowPipeline:
         because the groups partition the span's steps."""
         import jax.numpy as jnp
 
-        run = self._chained_superscan(Tg, B)
+        layout = self._staged_layout(raw_d)
+        run = self._chained_superscan(Tg, B, layout)
         needs_ts = self.prologue.needs_ts
         parts: List[DeferredEmissions] = []
         done = 0
@@ -1190,7 +1367,9 @@ class FusedWindowPipeline:
                 for f in self._value_fields
             }
             count_out0 = jnp.zeros((self.R, self.K), jnp.int32)
-            xs = (raw_d[lo:hi], srel_d[lo:hi])
+            raw_g = (tuple(f[lo:hi] for f in raw_d) if layout is not None
+                     else raw_d[lo:hi])
+            xs = (raw_g, srel_d[lo:hi])
             if needs_ts:
                 xs = xs + (ts_d[lo:hi],)
             xs = xs + (smin_pos[lo:hi], fire_pos[lo:hi], fire_valid[lo:hi],
@@ -1198,7 +1377,7 @@ class FusedWindowPipeline:
             out = self._tracked(
                 "fused_chained_superscan", run,
                 (self._state, self._count, outs0, count_out0) + xs,
-                {"T": Tg, "B": B, "raw_dtype": str(raw_d.dtype)},
+                {"T": Tg, "B": B, **self._record_signature(raw_d, layout)},
             )
             pc = None
             if self.phase_counters:
@@ -1216,22 +1395,28 @@ class FusedWindowPipeline:
                 key_capacity=self.K, phase_counts=pc))
         return _StreamedEmissions(parts)
 
-    def _chained_superscan(self, T: int, B: int):
+    def _chained_superscan(self, T: int, B: int,
+                           layout: Optional[ColumnLayout] = None):
         # module-level memo: the key holds STRONG references to the user
         # fns (via the frozen TracedPrologue), so identity-hashed entries
         # can never collide with a recycled id; builtin DeviceAggregators
-        # are memoized singletons, custom ones identity-hash conservatively
+        # are memoized singletons, custom ones identity-hash conservatively.
+        # The layout names the staged fields and the record's width: two
+        # chains that read different fields never share an executable
         key = (self.prologue, self.agg, self.K, self.S, self.NSB, self.F,
                self.R, self.spw, self.chunk, self.exact_sums, T, B,
-               self.phase_counters, self._fire_spws, self.donate_carry)
+               self.phase_counters, self._fire_spws, self.donate_carry,
+               layout)
         fn = _CHAINED_CACHE.get(key)
         if fn is None:
             while len(_CHAINED_CACHE) >= _CHAINED_CACHE_MAX:
                 _CHAINED_CACHE.pop(next(iter(_CHAINED_CACHE)))
-            fn = _CHAINED_CACHE[key] = self._build_chained_superscan(T, B)
+            fn = _CHAINED_CACHE[key] = self._build_chained_superscan(
+                T, B, layout)
         return fn
 
-    def _build_chained_superscan(self, T: int, B: int):
+    def _build_chained_superscan(self, T: int, B: int,
+                                 layout: Optional[ColumnLayout] = None):
         """Compile prologue + T-step superscan into one program. On CPU
         backends ingest uses direct scatter-adds ([K, S] is cache-resident
         and the MXU one-hot matmuls that win on TPU lose badly on a scalar
@@ -1252,8 +1437,6 @@ class FusedWindowPipeline:
         K, NSB = self.K, self.NSB
         needs_vals = self._needs_vals
         needs_ts = pro.needs_ts
-        transforms = tuple(pro.transforms)
-        key_fn, value_fn = pro.key_fn, pro.value_fn
 
         def body(carry, args):
             inner, key_bounds = carry
@@ -1265,40 +1448,9 @@ class FusedWindowPipeline:
                 ts = None
                 rest = args[2:]
             with jax.named_scope("prologue"):
-                col = raw
-                mask = srel >= 0
-                for kind, fn in transforms:
-                    if kind == "map":
-                        col = fn(col)
-                    elif kind == "map_ts":
-                        col = fn(col, ts)
-                    else:  # filter
-                        mask = mask & jnp.asarray(fn(col)).astype(bool)
-                keys = jnp.asarray(key_fn(col)).astype(jnp.int32)
-                live = mask & (keys >= 0) & (keys < K)
-                idx = jnp.where(live, keys * NSB + srel, jnp.int32(-1))
-                idx = idx.astype(jnp.int32)
-                if needs_vals:
-                    vcol = value_fn(col) if value_fn is not None else col
-                    # dead/pad rows hold uninitialized staging bytes that can
-                    # decode as NaN/inf; zero them BEFORE ingest — the matmul
-                    # histogram multiplies the zero one-hot by the raw value,
-                    # and 0 * NaN = NaN would poison every sum in the chunk
-                    # (the scatter path drops by index, but identical inputs
-                    # keep both ingest forms bit-identical)
-                    vals = jnp.where(
-                        live, jnp.asarray(vcol).astype(jnp.float32), 0.0)
-                else:
-                    vals = jnp.zeros((1,), jnp.float32)
-                # key range observed over every SURVIVING record (pre range
-                # clamp): an out-of-range key is a hard error at resolve, never
-                # a silent drop or a silent alias of another key's row
-                key_bounds = jnp.stack([
-                    jnp.maximum(key_bounds[0],
-                                jnp.max(jnp.where(mask, keys, jnp.int32(-1)))),
-                    jnp.minimum(key_bounds[1],
-                                jnp.min(jnp.where(mask, keys, jnp.int32(0)))),
-                ])
+                _live, _keys, idx, vals, key_bounds = pro.apply(
+                    raw, srel, ts, key_bounds, K=K, NSB=NSB,
+                    needs_vals=needs_vals, layout=layout)
             inner, _ = step(inner, (idx, vals) + rest)
             return (inner, key_bounds), None
 
@@ -1353,9 +1505,6 @@ class FusedWindowPipeline:
         self.min_used_slice = snap["min_used_slice"]
         self.max_seen_slice = snap["max_seen_slice"]
         self.num_late_records_dropped = snap["num_late_dropped"]
-
-
-import functools
 
 
 @functools.lru_cache(maxsize=None)

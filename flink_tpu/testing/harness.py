@@ -123,3 +123,22 @@ def keyed_window_stream(seed: int, steps: int, batch: int, num_keys: int,
         wms.append(int(base[-1]) - wm_lag_ms)
         t_cursor += ms_per_batch
     return batches, wms
+
+
+def seven_field_stream(steps: int, batch: int, num_keys: int, seed: int = 11):
+    """Deterministic record stream for the traced-chain tests: [batch, 7]
+    float32 records of small integers, the key in field 5, a 0/1 flag in
+    field 2; 250 ms of event time per step, the watermark in its middle (no
+    record is late). Returns ([(records, ts)], watermarks)."""
+    import numpy as np
+
+    rng = np.random.default_rng(seed)
+    out, wms = [], []
+    for s in range(steps):
+        rec = rng.integers(0, 6, (batch, 7)).astype(np.float32)
+        rec[:, 5] = rng.integers(0, num_keys, batch)
+        rec[:, 2] = rng.integers(0, 2, batch)
+        ts = (s * 250 + rng.integers(0, 250, batch)).astype(np.int64)
+        out.append((rec, ts))
+        wms.append(s * 250 + 125)
+    return out, wms

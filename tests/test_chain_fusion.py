@@ -652,3 +652,376 @@ def test_plan_describe_names_the_chain():
     (p,) = plans.values()
     assert isinstance(p, DeviceChainPlan)
     assert "=>" in p.name
+
+
+# ---------------------------------------------------------------------------
+# the staged form of the record: one [T, B] array per field the chain reads
+# ---------------------------------------------------------------------------
+
+_W = 7                      # fields of the record, as the YSB event has
+_KEYS = 11
+_weights = jnp.arange(_W, dtype=jnp.float32)
+
+
+def _key5(col):
+    return col[:, 5].astype(jnp.int32)
+
+
+def _val1(col):
+    return col[:, 1]
+
+
+def _record_batches(shape, n=1500, seed=23):
+    """Seeded batches of integer-valued records of `shape` per event: the
+    key in field 5 (or the scalar itself), small values elsewhere."""
+    rng = np.random.default_rng(seed)
+    rec = rng.integers(0, 6, size=(n,) + shape).astype(np.float32)
+    if shape == (_W,):
+        rec[:, 5] = rng.integers(0, _KEYS, size=n)
+        rec[:, 2] = rng.integers(0, 2, size=n)
+    ts = (10_000 + np.arange(n) * 7 - rng.integers(0, 30, size=n))
+    return rec, ts.astype(np.int64)
+
+
+#: case -> (record shape, transforms, key_fn, value_fn, aggregate,
+#:          the fields staged: a tuple, "all", or None for today's form)
+COLUMN_CASES = {
+    # static col[:, c] in a filter / a map / the key / the value
+    "filter_and_key": (
+        (_W,), (("filter", lambda col: col[:, 2] < 0.5),), _key5, None,
+        "count", (2, 5)),
+    "map_projects": (
+        (_W,), (("map", lambda col: jnp.stack(
+            [col[:, 5], col[:, 3] * 2.0], axis=1)),),
+        lambda col: col[:, 0].astype(jnp.int32), lambda col: col[:, 1],
+        "sum", (3, 5)),
+    "key_and_value": ((_W,), (), _key5, _val1, "sum", (1, 5)),
+    "static_range": (
+        (_W,), (), _key5, lambda col: col[:, 1:3].sum(axis=1), "sum",
+        (1, 2, 5)),
+    "identity_map_then_key": (
+        (_W,), (("map", lambda col: col),), _key5, _val1, "max", (1, 5)),
+    "map_ts_and_key": (
+        (_W,), (("map_ts", lambda col, ts: jnp.stack(
+            [col[:, 5], col[:, 4] + (ts % 2).astype(jnp.float32)],
+            axis=1)),),
+        lambda col: col[:, 0].astype(jnp.int32), lambda col: col[:, 1],
+        "sum", (4, 5)),
+    # consumers the analysis does not read: every field is staged
+    "matmul": (
+        (_W,), (("filter", lambda col: (col @ _weights) >= 20.0),), _key5,
+        None, "count", "all"),
+    "reduce_over_fields": (
+        (_W,), (), _key5, lambda col: col.sum(axis=1), "sum", "all"),
+    "traced_index": (
+        (_W,), (), _key5,
+        lambda col: col[:, jnp.argmin(jnp.zeros(3)) + 1], "sum", "all"),
+    "nested_jit": (
+        (_W,), (), _key5, lambda col: __import__("jax").jit(_val1)(col),
+        "sum", "all"),
+    "negative_index": (
+        (_W,), (), _key5, lambda col: col[:, -1], "sum", "all"),
+    # records that are not rank 1 per event keep today's form
+    "scalar_record": (
+        (), (), lambda col: col.astype(jnp.int32) % _KEYS, None, "count",
+        None),
+    "scalar_record_identity_value": (
+        (), (), lambda col: col.astype(jnp.int32) % _KEYS, None, "sum",
+        None),
+    "rank2_record": (
+        (2, 2), (), lambda col: col[:, 0, 1].astype(jnp.int32),
+        lambda col: col[:, 1, 0], "sum", None),
+}
+
+
+def _column_job(case, fused):
+    shape, transforms, key_fn, value_fn, aggregate, _cols = COLUMN_CASES[case]
+    rec, ts = _record_batches(shape)
+    cfg = Configuration()
+    cfg.set(ExecutionOptions.BATCH_SIZE, 200)
+    cfg.set(ExecutionOptions.SUPERBATCH_STEPS, 4)
+    cfg.set(ExecutionOptions.KEY_CAPACITY, 16)
+    cfg.set(ExecutionOptions.CHAIN_FUSION, fused)
+    from flink_tpu.config import ObservabilityOptions
+    cfg.set(ObservabilityOptions.DEVICE_TIMING_ENABLED, True)
+    env = StreamExecutionEnvironment.get_execution_environment(cfg)
+    ds = env.from_source(
+        DataGeneratorSource(lambda idx: Batch(rec[idx], ts[idx]), len(ts)),
+        watermark_strategy=WatermarkStrategy.for_bounded_out_of_orderness(40))
+    for kind, fn in transforms:
+        if kind == "filter":
+            ds = ds.filter(fn, traceable=True)
+        elif kind == "map":
+            ds = ds.map(fn, traceable=True)
+        else:
+            ds = ds.map_with_timestamp(fn, traceable=True)
+    keyed = ds.key_by(key_fn, traceable=True)
+    win = keyed.window(TumblingEventTimeWindows.of(1_000))
+    if value_fn is None:
+        sink = win.aggregate(aggregate).collect()
+    else:
+        sink = win.aggregate(aggregate, value_fn=value_fn,
+                             value_traceable=True).collect()
+    result = env.execute()
+    links = [op["link"] for op in
+             result.metrics["device"]["operators"].values() if "link" in op]
+    return sorted((int(k), float(v)) for k, v in sink.results), links
+
+
+@pytest.mark.parametrize("case", sorted(COLUMN_CASES))
+def test_column_analysis_and_parity_with_the_host_chain(case):
+    """Which fields a traced chain reads is read off its jaxpr,
+    conservatively; whatever it decides, the fused job gives the host
+    chain's rows on the same seeded batches."""
+    from flink_tpu.runtime.fused_window_pipeline import (
+        ColumnLayout,
+        TracedPrologue,
+    )
+
+    shape, transforms, key_fn, value_fn, aggregate, want = COLUMN_CASES[case]
+    needs_vals = aggregate != "count"
+    pro = TracedPrologue(transforms=transforms, key_fn=key_fn,
+                         value_fn=value_fn)
+    layout = pro.column_layout(shape, np.float32, needs_vals)
+    if want is None:
+        assert layout is None
+        staged = total = int(np.prod(shape))
+    else:
+        cols = tuple(range(_W)) if want == "all" else want
+        assert layout == ColumnLayout(cols, _W, "float32")
+        staged, total = len(cols), _W
+
+    fused, links = _column_job(case, fused=True)
+    host, _ = _column_job(case, fused=False)
+    assert fused == host and len(fused) > 20
+    (link,) = links
+    assert (link["columnsStaged"], link["recordColumns"]) == (staged, total)
+
+
+def test_identity_value_is_never_a_layout_that_drops_fields():
+    """value_fn=None hands the record itself to the aggregate ("the column
+    IS the value"). Over a [n] record that is today's form (the case
+    `scalar_record_identity_value` above); over a [n, 7] record it was never
+    a program on any path, and the analysis refuses it with the trace's own
+    error rather than answer with a subset."""
+    from flink_tpu.runtime.fused_window_pipeline import TracedPrologue
+
+    pro = TracedPrologue(transforms=(), key_fn=_key5, value_fn=None)
+    with pytest.raises(ValueError, match="Incompatible shapes"):
+        pro.column_layout((_W,), np.float32, True)
+    # the same chain under a count never touches the value: key only
+    assert pro.column_layout((_W,), np.float32, False).columns == (5,)
+    # and a float64 record is staged in the canonical dtype
+    assert pro.column_layout((_W,), np.float64, False).dtype == "float32"
+
+
+def _count_by_key(rec, keep, key_col=5):
+    return np.bincount(rec[keep][:, key_col].astype(np.int64),
+                       minlength=_KEYS)
+
+
+def _pipeline_counts(pipe, rec, ts):
+    rows = pipe.process_superbatch_raw([(rec, ts)], [int(ts.max()) + 5_000])
+    total = np.zeros(pipe.K, np.int64)
+    for _window, counts, _fields in rows:
+        total += counts
+    return total[:_KEYS]
+
+
+def test_chains_reading_different_fields_get_their_own_executables():
+    from flink_tpu.runtime import fused_window_pipeline as fwp
+
+    rec, ts = _record_batches((_W,), n=400)
+    geom = dict(key_capacity=16, num_slices=16, nsb=4, chunk=256,
+                fires_per_step=8, out_rows=16, backend="xla")
+    assigner = TumblingEventTimeWindows.of(1_000)
+    by_type = fwp.TracedPrologue(
+        transforms=(("filter", lambda col: col[:, 2] < 0.5),), key_fn=_key5)
+    by_value = fwp.TracedPrologue(
+        transforms=(("filter", lambda col: col[:, 4] < 2.5),), key_fn=_key5)
+    before = set(fwp._CHAINED_CACHE)
+    got_type = _pipeline_counts(
+        fwp.FusedWindowPipeline(assigner, "count", prologue=by_type, **geom),
+        rec, ts)
+    got_value = _pipeline_counts(
+        fwp.FusedWindowPipeline(assigner, "count", prologue=by_value,
+                                **geom), rec, ts)
+    np.testing.assert_array_equal(got_type,
+                                  _count_by_key(rec, rec[:, 2] < 0.5))
+    np.testing.assert_array_equal(got_value,
+                                  _count_by_key(rec, rec[:, 4] < 2.5))
+    # ONE chain over records of two widths stages the same two [T, B]
+    # arrays: only the layout in the key keeps the executables apart
+    wide = np.concatenate([rec, rec[:, :2]], axis=1)
+    got_wide = _pipeline_counts(
+        fwp.FusedWindowPipeline(assigner, "count", prologue=by_type, **geom),
+        wide, ts)
+    np.testing.assert_array_equal(got_wide, got_type)
+    layouts = sorted(str(k[-1]) for k in set(fwp._CHAINED_CACHE) - before)
+    assert layouts == ["2+5/7", "2+5/9", "4+5/7"]
+
+
+def test_narrowing_check_covers_the_staged_fields_only():
+    """An int64 field too wide for int32 raises when a traced function
+    reads it; an unread one never reaches a traced function, is not
+    staged, and is not checked."""
+    from flink_tpu.runtime.fused_window_pipeline import (
+        FusedWindowPipeline,
+        TracedPrologue,
+    )
+
+    rec = np.zeros((6, _W), np.int64)
+    rec[:, 5] = [0, 1, 2, 0, 1, 2]
+    rec[:, 3] = 5_000_000_000                     # > 2**31, never read
+    ts = np.arange(6, dtype=np.int64) * 10 + 10_000
+    geom = dict(key_capacity=8, num_slices=16, chunk=256, backend="xla")
+    reads_5 = TracedPrologue(transforms=(), key_fn=_key5)
+    pipe = FusedWindowPipeline(TumblingEventTimeWindows.of(1_000), "count",
+                               prologue=reads_5, **geom)
+    raw_h, *_ = pipe._stage_raw_host([(rec, ts)], [10_000])
+    assert [a.dtype for a in raw_h] == [np.int32] and raw_h[0].shape[0] == 1
+    np.testing.assert_array_equal(_pipeline_counts(
+        FusedWindowPipeline(TumblingEventTimeWindows.of(1_000), "count",
+                            prologue=reads_5, **geom), rec, ts)[:3],
+        [2, 2, 2])
+    reads_3 = TracedPrologue(
+        transforms=(), key_fn=lambda col: col[:, 3] // 1_000_000_000)
+    wide = FusedWindowPipeline(TumblingEventTimeWindows.of(1_000), "count",
+                               prologue=reads_3, **geom)
+    with pytest.raises(TypeError, match="column 3.*would silently wrap"):
+        wide.stage_superbatch_raw([(rec, ts)], [10_000])
+
+
+# ---------------------------------------------------------------------------
+# per-field staging under the other callers of the traced-chain path
+# ---------------------------------------------------------------------------
+
+def _seven_field_stream():
+    from flink_tpu.testing.harness import seven_field_stream
+
+    steps, wms = seven_field_stream(24, 48, 96)
+    return [(rec, ts, wm) for (rec, ts), wm in zip(steps, wms)]
+
+
+def _expected_sums(size_ms):
+    """numpy: sum of field 1 per (key in field 5, tumbling window) over
+    the records whose field 2 is 0."""
+    want = {}
+    for rec, ts, _wm in _seven_field_stream():
+        for row, t in zip(rec, ts):
+            if row[2] < 0.5:
+                k = (int(row[5]), int(t) // size_ms * size_ms)
+                want[k] = want.get(k, 0.0) + float(row[1])
+    return sorted((k, w, v) for (k, w), v in want.items())
+
+
+def _sum_chain():
+    from flink_tpu.runtime.fused_window_pipeline import TracedPrologue
+
+    return TracedPrologue(
+        transforms=(("filter", lambda col: col[:, 2] < 0.5),),
+        key_fn=_key5, value_fn=_val1)
+
+
+def _raw_op(**kw):
+    from flink_tpu.runtime.fused_window_operator import FusedWindowOperator
+
+    assigner = None if "assigners" in kw else TumblingEventTimeWindows.of(1000)
+    return FusedWindowOperator(assigner, "sum", key_capacity=256,
+                               superbatch_steps=8, prologue=_sum_chain(),
+                               **kw)
+
+
+def _rows(out):
+    return sorted((int(k), int(w.start), float(v)) for k, w, v, _ in out)
+
+
+def _feed(op, stream, drain):
+    out = []
+    for rec, ts, wm in stream:
+        op.process_raw_batch(rec, ts)
+        op.process_watermark(wm)
+        out.extend(drain(op))
+    return out
+
+
+def _caller_latency_grouped_readback():
+    """Latency mode's streamed readback: an 8-step span dispatched as four
+    2-step programs, every staged field sliced [lo:hi] per group."""
+    from flink_tpu.scheduler.latency_controller import LatencySpec
+
+    class Pinned:
+        def observe(self, n_steps, now=None):
+            pass
+
+        def steps(self, now=None):
+            return 8
+
+        current_steps = steps
+
+        def reset(self):
+            pass
+
+    op = _raw_op(latency=LatencySpec(target_ms=50, max_inflight=2,
+                                     readback_steps=2))
+    op._controller = Pinned()
+    grouped = []
+    inner = op.pipe._process_grouped_raw
+
+    def spy(T, B, Tg, raw_d, *rest):
+        grouped.append((Tg, len(raw_d), raw_d[0].shape == (T, B)))
+        return inner(T, B, Tg, raw_d, *rest)
+
+    op.pipe._process_grouped_raw = spy
+    out = _feed(op, _seven_field_stream(), lambda o: o.drain_output())
+    op.process_watermark(MAX_WATERMARK - 1)
+    out.extend(op.drain_output())
+    assert len(grouped) > 2 and set(grouped) == {(2, 3, True)}
+    return _rows(out), _expected_sums(1000)
+
+
+def _caller_shared_partials():
+    """SharedWindowPipeline inherits the chained program: two correlated
+    tumbling windows over one scan, fed per-field."""
+    op = _raw_op(assigners=[TumblingEventTimeWindows.of(1000),
+                            TumblingEventTimeWindows.of(2000)])
+    lanes = ([], [])
+
+    def drain(o):
+        for i, lane in enumerate(lanes):
+            lane.extend(o.drain_spec_output(i))
+        return ()
+
+    _feed(op, _seven_field_stream(), drain)
+    op.process_watermark(MAX_WATERMARK - 1)
+    drain(op)
+    assert op.pipe._layout().columns == (1, 2, 5)
+    return ((_rows(lanes[0]), _rows(lanes[1])),
+            (_expected_sums(1000), _expected_sums(2000)))
+
+
+def _caller_snapshot_restore_mid_superbatch():
+    """A snapshot with three steps of an eight-step superbatch buffered
+    flushes them as a padded tail; the restored operator stages the rest."""
+    stream = _seven_field_stream()
+    first = _raw_op()
+    out = _feed(first, stream[:11], lambda o: o.drain_output())
+    assert 0 < len(first._steps) < 8
+    snap = first.snapshot()     # the flushed tail's rows travel in it
+    second = _raw_op()
+    second.restore(snap)
+    out.extend(_feed(second, stream[11:], lambda o: o.drain_output()))
+    second.process_watermark(MAX_WATERMARK - 1)
+    out.extend(second.drain_output())
+    assert second.pipe._layout().columns == (1, 2, 5)
+    return _rows(out), _expected_sums(1000)
+
+
+@pytest.mark.parametrize("caller", [
+    _caller_latency_grouped_readback,
+    _caller_shared_partials,
+    _caller_snapshot_restore_mid_superbatch,
+], ids=lambda f: f.__name__[len("_caller_"):])
+def test_per_field_staging_under_the_other_callers(caller):
+    got, want = caller()
+    assert got == want and len(got)

@@ -17,6 +17,7 @@ def _mesh(n=8):
 
 
 from flink_tpu.testing.harness import keyed_window_stream as _stream
+from flink_tpu.testing.harness import seven_field_stream
 
 
 def _drain(pipe, batches, wms, chunksize=4):
@@ -218,3 +219,56 @@ def test_sharded_device_stats_attach_parity_and_telemetry():
     p = ks.payload()
     assert p["keySkew"] is not None
     assert p["activeKeys"] > 0
+
+
+# ---------------------------------------------------------------------------
+# the traced chain over the mesh: each staged field dealt over the shards
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("n_dev,num_keys,aggregate,combine", [
+    (8, 256, "count", False),
+    (3, 192, "sum", False),     # B / n uneven: 1024 lanes pad to 3 x 342
+    (4, 256, "sum", True),      # the map-side combiner's exchange
+], ids=["mesh8_count", "mesh3_uneven_sum", "mesh4_combine_sum"])
+def test_sharded_traced_chain_stages_per_field(n_dev, num_keys, aggregate,
+                                               combine):
+    import jax.numpy as jnp
+
+    from flink_tpu.runtime.fused_window_pipeline import TracedPrologue
+
+    pro = TracedPrologue(
+        transforms=(("filter", lambda col: col[:, 2] < 0.5),),
+        key_fn=lambda col: col[:, 5].astype(jnp.int32),
+        value_fn=lambda col: col[:, 1])
+    geom = dict(key_capacity=num_keys, num_slices=16, nsb=4,
+                fires_per_step=4, out_rows=16, chunk=1024, prologue=pro)
+    assigner = SlidingEventTimeWindows.of(2000, 500)
+    single = FusedWindowPipeline(assigner, aggregate, backend="xla", **geom)
+    sharded = ShardedFusedPipeline(_mesh(n_dev), assigner, aggregate,
+                                   local_combine=combine, **geom)
+    steps, wms = seven_field_stream(8, 600, num_keys, seed=5)
+    read = (1, 2, 5) if aggregate == "sum" else (2, 5)
+
+    staged = sharded.stage_superbatch_raw(steps[:4], wms[:4])
+    fields_d, srel_d = staged[0], staged[1]
+    Bs = -(-1024 // n_dev)
+    assert sharded._planner._layout().columns == read
+    assert [f.shape for f in fields_d] == [(n_dev, 4, Bs)] * len(read)
+    assert srel_d.shape == (n_dev, 4, Bs)
+    # lanes are dealt contiguously: shard i holds lanes [i*Bs, (i+1)*Bs)
+    lanes = np.concatenate(list(np.asarray(fields_d[-1])), axis=1)
+    np.testing.assert_array_equal(lanes[0, :600], steps[0][0][:, 5])
+    assert (np.asarray(srel_d).transpose(1, 0, 2).reshape(4, -1)[:, 600:]
+            == -1).all()
+
+    got = sharded.process_superbatch_raw(steps[:4], wms[:4], staged=staged)
+    got += sharded.process_superbatch_raw(steps[4:], wms[4:])
+    ref = single.process_superbatch_raw(steps[:4], wms[:4])
+    ref += single.process_superbatch_raw(steps[4:], wms[4:])
+    ref, got = _norm(ref), _norm(got)
+    assert len(ref) == len(got) > 0
+    for (rs, rc, rf), (gs, gc, gf) in zip(ref, got):
+        assert rs == gs
+        assert np.array_equal(rc, gc) and rc.sum() > 0
+        for name in rf:
+            np.testing.assert_array_equal(rf[name][rc > 0], gf[name][rc > 0])

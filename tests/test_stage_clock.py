@@ -112,8 +112,16 @@ def test_link_counters_and_seq():
     clock.staged((np.zeros((4, 8), np.float32), None,
                   np.zeros(3, np.int32)), events=4)
     assert clock.link() == {"h2dBytes": 4 * 8 * 4 + 12, "d2hBytes": 0,
-                            "eventsStaged": 4, "rowsEmitted": 0,
+                            "eventsStaged": 4, "columnsStaged": 0,
+                            "recordColumns": 0, "rowsEmitted": 0,
                             "dispatches": 1}
+    # a traced chain's dispatch says how much of the record it shipped;
+    # the gauge is the newest dispatch's, not a sum
+    clock.staged((np.zeros(2, np.int32),), events=2, columns=(2, 7))
+    clock.staged((np.zeros(2, np.int32),), events=2, columns=(2, 7))
+    link = clock.link()
+    assert (link["columnsStaged"], link["recordColumns"]) == (2, 7)
+    assert link["eventsStaged"] == 8
     assert task_io.dispatch_stage(clock, "stage.fill").seq == 1
     assert stage(clock, "emit", 7).seq == 7 and stage(clock, "drain").seq is None
     task_io.tag_dispatch("fused_superscan")     # no open span: a no-op
@@ -280,6 +288,10 @@ def test_stage_and_link_tables_agree_with_the_job(traced_job):
     assert link["rowsEmitted"] == len(rows) > 0
     assert sum(v for _k, v in rows) == passed
     assert link["h2dBytes"] > 0 and link["d2hBytes"] > 0
+    # the 2-field record: the traced chain reads both (filter, key); the
+    # host-keyed path ships key ids, no record
+    assert (link["columnsStaged"], link["recordColumns"]) == \
+        ((0, 0) if traced_job["host_keyed"] else (2, 2))
     # each table row counts what the trace shows of that stage
     seen = {}
     for n, _a, _b in traced_job["spans"]:
@@ -308,6 +320,59 @@ def test_timing_off_enters_no_site(tmp_path):
     for entry in device["operators"].values():
         assert "stages" not in entry and "link" not in entry
         assert "deviceTimeMsTotal" not in entry
+
+
+# ---------------------------------------------------------------------------
+# the link row says how much of the record a traced chain ships
+# ---------------------------------------------------------------------------
+
+def _seven_field_chain(reads_all: bool):
+    from flink_tpu.runtime.fused_window_pipeline import TracedPrologue
+
+    weights = jnp.arange(7, dtype=jnp.float32)
+    if reads_all:     # a matmul over the fields: the analysis gives up
+        transforms = (("filter", lambda col: (col @ weights) >= 0.0),)
+    else:
+        transforms = (("filter", lambda col: col[:, 2] < 0.5),)
+    return TracedPrologue(transforms=transforms,
+                          key_fn=lambda col: col[:, 5].astype(jnp.int32))
+
+
+@pytest.mark.parametrize("mesh", [0, 4], ids=["one_chip", "mesh4"])
+@pytest.mark.parametrize("reads_all,staged", [(False, 2), (True, 7)],
+                         ids=["two_of_seven", "fallback_seven_of_seven"])
+def test_link_row_counts_the_columns_staged(mesh, reads_all, staged):
+    from jax.sharding import Mesh
+
+    from flink_tpu.parallel.sharded_superscan import ShardedFusedPipeline
+    from flink_tpu.runtime.fused_window_pipeline import FusedWindowPipeline
+
+    geom = dict(key_capacity=64, num_slices=16, nsb=4, chunk=256,
+                fires_per_step=4, out_rows=16,
+                prologue=_seven_field_chain(reads_all))
+    assigner = TumblingEventTimeWindows.of(1_000)
+    if mesh:
+        pipe = ShardedFusedPipeline(
+            Mesh(np.array(jax.devices()[:mesh]), ("shards",)), assigner,
+            "count", **geom)
+    else:
+        pipe = FusedWindowPipeline(assigner, "count", backend="xla", **geom)
+    clock = StageClock()
+    pipe.attach_stage_clock(clock)
+    rng = np.random.RandomState(11)
+    n = 300
+    rec = rng.randint(0, 2, (n, 7)).astype(np.float32)
+    rec[:, 5] = rng.randint(0, 64, n)
+    ts = np.sort(rng.randint(0, 900, n)).astype(np.int64)
+    rows = pipe.process_superbatch_raw([(rec, ts)], [2_000])
+    link = clock.link()
+    assert (link["columnsStaged"], link["recordColumns"]) == (staged, 7)
+    B = 512          # 300 lanes staged at the next power-of-two of chunks
+    # per staged lane: 4 B a staged field + the 4 B slice index
+    plan_bytes = link["h2dBytes"] - B * 4 * (staged + 1)
+    assert 0 < plan_bytes < 1024
+    kept = rec if reads_all else rec[rec[:, 2] < 0.5]
+    assert sum(int(counts.sum()) for _w, counts, _f in rows) == len(kept)
 
 
 # ---------------------------------------------------------------------------

@@ -214,14 +214,62 @@ def test_chained_superscan_compiles_at_served_defaults(v5e, monkeypatch):
             transforms=(("filter", lambda col: col[:, 1] < 0.5),),
             key_fn=lambda col: col[:, 0].astype(jnp.int32)),
     )
-    run = pipe._build_chained_superscan(T, B)
+    # the staged form of the two-field record: both fields are read, each
+    # ships as its own [T, B] array
+    pipe._raw_shape, pipe._raw_dtype = (2,), jnp.float32
+    layout = pipe._layout()
+    assert layout.columns == (0, 1)
+    run = pipe._build_chained_superscan(T, B, layout)
     dev = v5e.devices[0]
     i32 = jnp.int32
     args = ({}, _on(dev, (K, pipe.S), i32), {}, _on(dev, (pipe.R, K), i32),
-            _on(dev, (T, B, 2), jnp.float32), _on(dev, (T, B), i32),
+            (_on(dev, (T, B), jnp.float32),) * 2, _on(dev, (T, B), i32),
             ) + _plan_specs(dev, T, pipe.F, pipe.S)
     mem = run.trace(*args).lower().compile().memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 8 << 30
+
+
+def test_ysb_chain_compiles_per_column_and_builds_no_record(v5e, monkeypatch):
+    """The benchmark's advertising chain (benchmarks/jobs/ysb_traced.py:
+    view filter, ad -> campaign join as a table gather, count per campaign
+    per 10 s window) over the 7-field event: two fields are staged, and the
+    compiled program reads them as they are — the record the user's
+    functions are handed is never built on the device."""
+    import numpy as np
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    table = jnp.asarray(np.arange(1000, dtype=np.int32) % 100)
+
+    def project_and_join(col):
+        campaign = jnp.take(table, col[:, 2].astype(jnp.int32), axis=0)
+        return jnp.stack([campaign.astype(jnp.float32), col[:, 2]], axis=1)
+
+    K, T, B = 1 << 16, 32, 1 << 16
+    pipe = FusedWindowPipeline(
+        SlidingEventTimeWindows.of(10_000, 10_000), "count", key_capacity=K,
+        fires_per_step=EXEC["F"], out_rows=EXEC["R"], chunk=EXEC["CH"],
+        plan_only=True,
+        prologue=TracedPrologue(
+            transforms=(("filter", lambda col: col[:, 4] < 0.5),
+                        ("map", project_and_join)),
+            key_fn=lambda col: col[:, 0].astype(jnp.int32)),
+    )
+    pipe._raw_shape, pipe._raw_dtype = (7,), jnp.float32
+    layout = pipe._layout()
+    assert (layout.columns, layout.width) == ((2, 4), 7)
+    run = pipe._build_chained_superscan(T, B, layout)
+    dev = v5e.devices[0]
+    i32 = jnp.int32
+    args = ({}, _on(dev, (K, pipe.S), i32), {}, _on(dev, (pipe.R, K), i32),
+            (_on(dev, (T, B), jnp.float32),) * 2, _on(dev, (T, B), i32),
+            ) + _plan_specs(dev, T, pipe.F, pipe.S)
+    compiled = run.trace(*args).lower().compile()
+    hlo = compiled.as_text()
+    assert f"f32[{B},7]" not in hlo and f"f32[{T},{B},7]" not in hlo
+    mem = compiled.memory_analysis()
+    # two [T, B] f32 fields + the slice index, not seven
+    assert mem.argument_size_in_bytes < (K * pipe.S + pipe.R * K) * 4 \
+        + 3 * T * B * 4 + (1 << 20)
 
 
 def test_sharded_chained_superscan_compiles_on_the_2x2_mesh(v5e, monkeypatch):
@@ -245,8 +293,10 @@ def test_sharded_chained_superscan_compiles_on_the_2x2_mesh(v5e, monkeypatch):
         prologue=TracedPrologue(
             transforms=(("filter", lambda col: col[:, 1] < 0.5),),
             key_fn=lambda col: col[:, 0].astype(jnp.int32)))
-    pipe.planner._raw_shape = (2,)
-    run = pipe._build_raw(T, B // n)
+    pipe.planner._raw_shape, pipe.planner._raw_dtype = (2,), jnp.float32
+    layout = pipe.planner._layout()
+    assert layout.columns == (0, 1)
+    run = pipe._build_raw(T, B // n, layout)
 
     def on_mesh(shape, dtype, *spec):
         return jax.ShapeDtypeStruct(
@@ -255,7 +305,7 @@ def test_sharded_chained_superscan_compiles_on_the_2x2_mesh(v5e, monkeypatch):
     i32, F, S = jnp.int32, pipe.F, pipe.S
     run.trace(
         on_mesh((n, K // n, S), i32, "shards"), (),
-        on_mesh((n, T, B // n, 2), jnp.float32, "shards"),
+        (on_mesh((n, T, B // n), jnp.float32, "shards"),) * 2,
         on_mesh((n, T, B // n), i32, "shards"),
         on_mesh((T,), i32), on_mesh((T, F), i32), on_mesh((T, F), i32),
         on_mesh((T, F), i32), on_mesh((T, S), i32),
