@@ -12,7 +12,8 @@ with a plain numpy reference (per-slice `bincount` over the same
   leg 2  host-keyed count (8192 keys) and sum (4096 keys), 2^22 events
          each: the Pallas kernel as the operator selects it, Mosaic-compiled;
   leg 3  leg 1 sharded over a 4-device mesh — only where 4 devices are
-         visible.
+         visible; fails if a dispatch reads back more fire rows than its
+         fires used, and prints the link's bytes back per dispatch and event.
 
 Exits non-zero when JAX finds no TPU, when a leg raises, or when a result
 differs from the reference. Wall and compile times are printed as set-up
@@ -327,11 +328,43 @@ def leg3(leg1_got, leg1_stream):
     if len(owners) != 4 or any(s.data.size == 0 for s in shards):
         raise AssertionError(
             f"leg 3: ring shards {[(s.device, s.data.shape) for s in shards]}")
-    facts, got, dev = run_job("leg3-mesh-4", leg1_stream, config, traced=True,
-                              aggregate="count",
-                              ref_kwargs={"keep_aux_below": 1})
+    # what each dispatch hands to the deferred readback: (fires, rows, bytes)
+    from flink_tpu.runtime import fused_window_pipeline as fwp
+
+    readbacks = []
+    init = fwp.DeferredEmissions.__init__
+
+    def probe(self, pipe, fires, count_out, outs, **kw):
+        init(self, pipe, fires, count_out, outs, **kw)
+        readbacks.append((len(fires), int(count_out.shape[0]), self.nbytes))
+
+    fwp.DeferredEmissions.__init__ = probe
+    try:
+        facts, got, dev = run_job("leg3-mesh-4", leg1_stream, config,
+                                  traced=True, aggregate="count",
+                                  ref_kwargs={"keep_aux_below": 1})
+    finally:
+        fwp.DeferredEmissions.__init__ = init
     if facts["mesh_devices"] != 4:
         raise AssertionError(f"leg 3: job reports mesh {facts['mesh_devices']}")
+    # only the fire rows a dispatch used come back from the four shards
+    K = op.pipe.K
+    for fires, rows, nbytes in readbacks:
+        used = -(-max(fires, 1) // 16) * 16
+        if rows > used or nbytes > used * K * 4 + 64:
+            raise AssertionError(
+                f"leg 3: a dispatch with {fires} fires reads back {rows} "
+                f"rows, {nbytes} B: more than {used} rows of {K} keys")
+    (link,) = [o["link"] for o in dev["operators"].values() if "link" in o]
+    if link["d2hBytes"] != sum(r[2] for r in readbacks):
+        raise AssertionError(
+            f"leg 3: the stage clock counts {link['d2hBytes']} B read back, "
+            f"the dispatches handed over {sum(r[2] for r in readbacks)}")
+    facts["d2h_bytes_per_dispatch"] = round(
+        link["d2hBytes"] / max(link["dispatches"], 1))
+    facts["d2h_bytes_per_event"] = round(
+        link["d2hBytes"] / max(link["eventsStaged"], 1), 3)
+    facts["readback_rows_per_dispatch"] = [r[1] for r in readbacks]
     per_dev = [e for o in dev["operators"].values()
                for e in o.get("keys", {}).get("perDevice", [])]
     facts["per_device_records"] = [e.get("records") for e in per_dev]

@@ -127,8 +127,8 @@ class TaskIOMetrics:
 #: per-operator `stages` table (docs/observability.md tabulates this tuple).
 STAGES = (
     "source.poll", "source.watermark", "chain.host", "keys.lookup",
-    "normalize", "stage.fill", "stage.put", "dispatch", "resolve", "emit",
-    "drain", "sink.write", "keys.stats",
+    "normalize", "stage.fill", "stage.shard", "stage.put", "dispatch",
+    "resolve", "emit", "drain", "sink.write", "keys.stats",
 )
 SPAN_PREFIX = "flink_tpu."
 

@@ -573,7 +573,7 @@ class ShardedFusedPipeline:
     # ------------------------------------------------------------------
     def stage_superbatch(self, batches: Sequence, watermarks: Sequence[int]):
         """Host planning + staging. `batches[t] = (keys, vals|None, ts)` is
-        the step's GLOBAL record set; lanes are dealt round-robin across the
+        the step's GLOBAL record set; lanes are dealt contiguously across the
         n source shards (any split works — the in-scan all-to-all re-routes
         by key ownership)."""
         clock = self.stage_clock
@@ -582,22 +582,12 @@ class ShardedFusedPipeline:
             plan_idx, plan_vals, plan = self._planner.stage_superbatch(
                 batches, watermarks)
             idx_h = np.asarray(plan_idx)          # [T, B_padded] int32
-            T, B = idx_h.shape
-            # pad B so every shard gets an equal lane count
-            Bs = -(-B // self.n)
-            if Bs * self.n != B:
-                pad = Bs * self.n - B
-                idx_h = np.concatenate(
-                    [idx_h, np.full((T, pad), -1, np.int32)], axis=1)
-            idx_sh = idx_h.reshape(T, self.n, Bs).transpose(1, 0, 2)
-            vals_sh = None
-            if self._needs_vals:
-                vals_h = np.asarray(plan_vals)
-                if Bs * self.n != B:
-                    vals_h = np.concatenate(
-                        [vals_h,
-                         np.zeros((T, Bs * self.n - B), np.float32)], axis=1)
-                vals_sh = vals_h.reshape(T, self.n, Bs).transpose(1, 0, 2)
+            vals_h = np.asarray(plan_vals) if self._needs_vals else None
+            with dispatch_stage(clock, "stage.shard"):
+                idx_sh = self._deal_lanes(idx_h, -1)
+                vals_sh = (None if vals_h is None
+                           else self._deal_lanes(vals_h, 0))
+            T = idx_h.shape[0]
         with dispatch_stage(clock, "stage.put"):
             # host arrays go to device_put as they are: each device receives
             # its own lanes, nothing is first committed whole to device 0
@@ -609,6 +599,19 @@ class ShardedFusedPipeline:
             if clock is not None:
                 clock.staged((idx_sh, vals_sh))
         return idx_d, vals_d, plan
+
+    def _deal_lanes(self, a: np.ndarray, fill) -> np.ndarray:
+        """[T, B, ...] -> [n, T, Bs, ...]: every step's lanes dealt
+        contiguously over the n source shards, B padded with `fill` so each
+        shard gets an equal lane count."""
+        T, B = a.shape[:2]
+        n = self.n
+        Bs = -(-B // n)
+        if Bs * n != B:
+            a = np.concatenate(
+                [a, np.full((T, Bs * n - B) + a.shape[2:], fill, a.dtype)],
+                axis=1)
+        return np.swapaxes(a.reshape((T, n, Bs) + a.shape[2:]), 0, 1)
 
     def process_superbatch(self, batches, watermarks, *, staged=None,
                            defer: bool = False):
@@ -644,21 +647,28 @@ class ShardedFusedPipeline:
         self._count = count
         self._state = dict(zip(names, states))
         count_rows, out_rows = self._canonical_fire_rows(
-            count_out, field_outs, names)
+            count_out, field_outs, names, len(fires))
         deferred = DeferredEmissions(self._planner, fires, count_rows,
                                      out_rows, phase_counts=pc_total)
         return deferred if defer else deferred.resolve()
 
-    def _canonical_fire_rows(self, count_out, field_outs, names):
-        """[n, R, K_local] per-shard fire slabs -> [R, K] canonical key
-        order: contiguous ranges concatenate; a routing table additionally
-        permutes columns (one deferred device gather — the rows ride the
-        same async readback either way)."""
+    def _canonical_fire_rows(self, count_out, field_outs, names, fired):
+        """[n, R, K_local] per-shard fire slabs -> [used, K] canonical key
+        order, `used` the rows the dispatch's `fired` fires filled (sliced
+        on each shard first: what follows, and the deferred readback, move
+        only those): contiguous ranges concatenate; a routing table
+        additionally permutes columns (one deferred device gather — the
+        rows ride the same async readback either way)."""
+        from flink_tpu.runtime.fused_window_pipeline import _used_fire_rows
+
+        count_out, outs = _used_fire_rows(
+            count_out, dict(zip(names, field_outs)), fired, axis=1)
+        used = count_out.shape[1]
         count_rows = jnp.transpose(count_out, (1, 0, 2)).reshape(
-            self.R, self.K)
+            used, self.K)
         out_rows = {
-            nm: jnp.transpose(o, (1, 0, 2)).reshape(self.R, self.K)
-            for nm, o in zip(names, field_outs)
+            nm: jnp.transpose(o, (1, 0, 2)).reshape(used, self.K)
+            for nm, o in outs.items()
         }
         if self.routing is not None:
             count_rows = jnp.take(count_rows, self._perm_dev, axis=1)
@@ -865,22 +875,11 @@ class ShardedFusedPipeline:
         with dispatch_stage(clock, "stage.fill"):
             raw_h, srel_h, ts_h, plan_np, fires = \
                 self._planner._stage_raw_host(steps, watermarks)
-            T, B = srel_h.shape
-            n = self.n
-            Bs = -(-B // n)
-
-            def deal(a, fill):
-                # [T, B, ...] -> [n, T, Bs, ...]: lanes dealt contiguously
-                if Bs * n != B:
-                    a = np.concatenate(
-                        [a, np.full((T, Bs * n - B) + a.shape[2:], fill,
-                                    a.dtype)], axis=1)
-                return np.swapaxes(a.reshape((T, n, Bs) + a.shape[2:]), 0, 1)
-
-            srel_sh = deal(srel_h, -1)
-            ts_sh = None if ts_h is None else deal(ts_h, 0)
             fields_h, columns = self._planner._record_fields(raw_h)
-            fields_sh = tuple(deal(f, 0) for f in fields_h)
+            with dispatch_stage(clock, "stage.shard"):
+                srel_sh = self._deal_lanes(srel_h, -1)
+                ts_sh = None if ts_h is None else self._deal_lanes(ts_h, 0)
+                fields_sh = tuple(self._deal_lanes(f, 0) for f in fields_h)
         with dispatch_stage(clock, "stage.put"):
             fields_d = tuple(
                 jax.device_put(f, self._shard_spec(*([None] * (f.ndim - 1))))
@@ -946,7 +945,7 @@ class ShardedFusedPipeline:
         self._count = count
         self._state = dict(zip(names, states))
         count_rows, out_rows = self._canonical_fire_rows(
-            count_out, field_outs, names)
+            count_out, field_outs, names, len(fires))
         deferred = DeferredEmissions(self._planner, fires, count_rows,
                                      out_rows, key_bounds=kb,
                                      key_capacity=self.K,

@@ -893,12 +893,7 @@ class FusedWindowPipeline:
             else:
                 self._state, self._count, outs, count_out = out
 
-        # read back only the rows actually fired (padded to a few stable
-        # shapes so the slice executable is reused across dispatches)
-        used = -(-max(len(fires), 1) // 16) * 16
-        if used < self.R:
-            count_out = _slice_rows(count_out, used)
-            outs = {k: _slice_rows(v, used) for k, v in outs.items()}
+        count_out, outs = _used_fire_rows(count_out, outs, len(fires))
 
         deferred = DeferredEmissions(
             self, fires, count_out, outs,
@@ -947,10 +942,7 @@ class FusedWindowPipeline:
             done += len(g_fires)
             # rows are assigned in fire order across the WHOLE span: the
             # highest row this group can populate is the cumulative count
-            used = -(-max(done, 1) // 16) * 16
-            if used < self.R:
-                count_out = _slice_rows(count_out, used)
-                outs = {k: _slice_rows(v, used) for k, v in outs.items()}
+            count_out, outs = _used_fire_rows(count_out, outs, done)
             parts.append(DeferredEmissions(
                 self, g_fires, count_out, outs, phase_counts=pc))
         return _StreamedEmissions(parts)
@@ -1335,10 +1327,7 @@ class FusedWindowPipeline:
         else:
             self._state, self._count, outs, count_out, key_bounds = out
 
-        used = -(-max(len(fires), 1) // 16) * 16
-        if used < self.R:
-            count_out = _slice_rows(count_out, used)
-            outs = {k: _slice_rows(v, used) for k, v in outs.items()}
+        count_out, outs = _used_fire_rows(count_out, outs, len(fires))
         deferred = DeferredEmissions(self, fires, count_out, outs,
                                      key_bounds=key_bounds,
                                      key_capacity=self.K,
@@ -1386,10 +1375,7 @@ class FusedWindowPipeline:
                 self._state, self._count, outs, count_out, key_bounds = out
             g_fires = [pf for pf in fires if lo <= pf.step < hi]
             done += len(g_fires)
-            used = -(-max(done, 1) // 16) * 16
-            if used < self.R:
-                count_out = _slice_rows(count_out, used)
-                outs = {k: _slice_rows(v, used) for k, v in outs.items()}
+            count_out, outs = _used_fire_rows(count_out, outs, done)
             parts.append(DeferredEmissions(
                 self, g_fires, count_out, outs, key_bounds=key_bounds,
                 key_capacity=self.K, phase_counts=pc))
@@ -1508,14 +1494,27 @@ class FusedWindowPipeline:
 
 
 @functools.lru_cache(maxsize=None)
-def _row_slicer(n: int):
+def _row_slicer(n: int, axis: int):
     import jax
 
-    return jax.jit(lambda b: b[:n])
+    return jax.jit(lambda b: b[(slice(None),) * axis + (slice(n),)])
 
 
-def _slice_rows(buf, n: int):
-    return _row_slicer(n)(buf)
+def _slice_rows(buf, n: int, axis: int = 0):
+    return _row_slicer(n, axis)(buf)
+
+
+def _used_fire_rows(count_out, outs, fired: int, axis: int = 0):
+    """Read back only the fire rows a dispatch used: rows are assigned in
+    fire order, so `fired` fires fill the first `fired` of the R rows along
+    `axis` (padded to a few stable shapes so the slice executable is reused
+    across dispatches). The mesh's per-shard slabs [n, R, K_local] carry
+    their rows on axis 1."""
+    used = -(-max(fired, 1) // 16) * 16
+    if used < count_out.shape[axis]:
+        count_out = _slice_rows(count_out, used, axis)
+        outs = {k: _slice_rows(v, used, axis) for k, v in outs.items()}
+    return count_out, outs
 
 
 #: the per-step ingest/fire/purge body now lives in ops/superscan.py (a

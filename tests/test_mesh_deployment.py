@@ -1,0 +1,221 @@
+"""The keyed job at parallelism n: the deployment `ysb_keys64k_mesh4` of the
+benchmark (`benchmarks/configs/ysb_keys64k_mesh4.json`) at small sizes on the
+virtual CPU mesh.
+
+- the configuration's job through `env.execute()` with the two mesh options,
+  rows equal to the configuration's plain reference cell for cell;
+- the fire rows a mesh dispatch hands to the deferred readback: only the rows
+  its fires used, on both dispatch paths, with and without a routing table;
+- the stage clock on the mesh: the deal's own stage, the link's bytes back;
+- the lane deal over shards that do not divide the batch.
+"""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh
+
+from benchmarks import harness
+from flink_tpu.api.windowing.assigners import SlidingEventTimeWindows
+from flink_tpu.metrics.task_io import StageClock
+from flink_tpu.parallel.sharded_superscan import ShardedFusedPipeline
+from flink_tpu.runtime.fused_window_pipeline import (
+    FusedWindowPipeline,
+    TracedPrologue,
+)
+
+CONFIG = "ysb_keys64k_mesh4"
+KEYS = 4096
+
+
+def _mesh(n):
+    return Mesh(np.array(jax.devices()[:n]), ("shards",))
+
+
+# ---------------------------------------------------------------------------
+# the configuration's job through env.execute(), against its plain reference
+# ---------------------------------------------------------------------------
+
+def _small_spec(n: int):
+    """The shipped configuration with the key space and the key capacity cut
+    to test size and the mesh over `n` devices; the job, the record, the
+    window, the jitter and the reference are the file's."""
+    cfg = harness.load_json("configs", CONFIG + ".json")
+    for col in cfg["stream"]["columns"]:
+        if col["name"] == "campaign_id":
+            col["mod"] = KEYS
+    cfg["reference"]["keys"] = KEYS
+    cfg["options"] = dict(cfg["options"], **{
+        "parallel.mesh.devices": n, "execution.state.key-capacity": KEYS})
+    cfg["expect"] = ({"mesh_devices": n, "devices_with_records": n}
+                     if n > 1 else {})
+    return {"cell": {"name": f"test_keys4k_mesh{n}", "config": CONFIG,
+                     "traffic": "catchup", "chips": n},
+            "cfg": cfg, "traffic": harness.load_json("traffic", "catchup.json"),
+            "end_to_end": [], "per_layer": []}
+
+
+@pytest.mark.parametrize("n", [1, 2, 4])
+def test_the_job_on_n_devices_equals_the_plain_reference(n):
+    spec = _small_spec(n)
+    assert spec["cfg"]["reference"]["module"] == "keyed_window_count"
+    out = harness.run_cell(spec["cell"]["name"], 2600000000 + n, 1.0, False,
+                           rehearse=True, spec=spec, log=lambda _m: None)
+    assert out["correct"] is True, out["compared"]
+    assert out["attempted"] > 2 * KEYS and out["failed"] == 0   # > 2 windows
+    compared = {k: c["value"] for k, c in out["compared"].items()}
+    counters = out["_detail"]["counters"]
+    if n > 1:
+        assert compared["mesh_devices"] == n
+        assert compared["devices_with_records"] == n
+        assert counters["programs"]["sharded_chained_superscan"][
+            "dispatches"] >= 1
+    else:       # one device: no mesh applies, the one-chip program runs
+        assert counters["mesh_devices"] == 1
+        assert counters["programs"]["fused_chained_superscan"][
+            "dispatches"] >= 1
+
+
+# ---------------------------------------------------------------------------
+# only the fire rows a dispatch used are handed to the deferred readback
+# ---------------------------------------------------------------------------
+
+#: four dispatches of 8 steps x 500 ms of event time; the watermarks hold the
+#: fires back and release them: 0, 1, 16 and 17 fires (4 a step at most)
+STEPS, STEP_MS, BATCH, K, R = 8, 500, 300, 256, 32
+FIRES = (0, 1, 16, 17)
+WATERMARKS = (
+    [0] * 8,
+    [249] * 8,
+    [1249, 2249, 3249, 4249] + [4249] * 4,
+    [5249, 6249, 7249, 8249, 8499] + [8499] * 3,
+)
+ASSIGNER = SlidingEventTimeWindows.of(1000, 250)
+GEOM = dict(key_capacity=K, num_slices=128, nsb=4, fires_per_step=4,
+            out_rows=R, chunk=512)
+
+
+def _dispatches(seed=3):
+    """[(records [BATCH, 3] f32: key, value, flag; ts)] per step, per
+    dispatch; every record lies ahead of every watermark."""
+    rng = np.random.default_rng(seed)
+    out = []
+    for d in range(len(FIRES)):
+        steps = []
+        for s in range(STEPS):
+            rec = np.stack([rng.integers(0, K, BATCH),
+                            rng.integers(1, 9, BATCH),
+                            rng.integers(0, 2, BATCH)], axis=1)
+            t0 = (d * STEPS + s) * STEP_MS
+            ts = (t0 + rng.integers(0, STEP_MS, BATCH)).astype(np.int64)
+            steps.append((rec.astype(np.float32), ts))
+        out.append(steps)
+    return out
+
+
+def _prologue(aggregate):
+    return TracedPrologue(
+        transforms=(("filter", lambda col: col[:, 2] < 0.5),),
+        key_fn=lambda col: col[:, 0].astype(jnp.int32),
+        value_fn=(lambda col: col[:, 1]) if aggregate == "sum" else None)
+
+
+def _feed(pipe, steps, wms, raw: bool):
+    """One deferred dispatch through the path under test."""
+    if raw:
+        return pipe.process_superbatch_raw(steps, wms, defer=True)
+    batches = [(rec[:, 0].astype(np.int32), rec[:, 1], ts)
+               for rec, ts in steps]
+    return pipe.process_superbatch(batches, wms, defer=True)
+
+
+def _ceil16(fires):
+    return -(-max(fires, 1) // 16) * 16
+
+
+@pytest.mark.parametrize("raw", [False, True], ids=["keyed", "traced_chain"])
+@pytest.mark.parametrize("routed", [False, True], ids=["static", "table"])
+@pytest.mark.parametrize("aggregate", ["count", "sum"])
+@pytest.mark.parametrize("n", [2, 4])
+def test_mesh_dispatch_reads_back_only_the_fire_rows_it_used(
+        n, aggregate, routed, raw):
+    geom = dict(GEOM, prologue=_prologue(aggregate)) if raw else GEOM
+    single = FusedWindowPipeline(ASSIGNER, aggregate, backend="xla", **geom)
+    sharded = ShardedFusedPipeline(_mesh(n), ASSIGNER, aggregate,
+                                   skew_routing=routed, **geom)
+    if routed:      # a table that is no identity: the columns are permuted
+        sharded.set_routing_assignment(
+            np.repeat(np.arange(n)[::-1], sharded.routing.G // n))
+    fields = 1 + (aggregate == "sum")
+    for steps, wms, fires in zip(_dispatches(), WATERMARKS, FIRES):
+        want, got = (_feed(p, steps, wms, raw) for p in (single, sharded))
+        assert len(got._fires) == len(want._fires) == fires
+        rows = _ceil16(fires)
+        handed = [got._count_out, *got._outs.values()]
+        assert len(handed) == fields
+        assert [a.shape for a in handed] == [(rows, K)] * fields
+        # what resolve() reads back: the used rows (+ the key bounds, i32[2])
+        assert got.nbytes == rows * K * 4 * fields + (8 if raw else 0)
+        assert got.nbytes == want.nbytes
+        want, got = want.resolve(), got.resolve()
+        assert len(got) == len(want) == fires
+        for (ww, wc, wf), (gw, gc, gf) in zip(want, got):
+            assert (gw.start, gw.end) == (ww.start, ww.end)
+            np.testing.assert_array_equal(gc, wc)
+            live = np.asarray(wc) > 0
+            for name in wf:
+                np.testing.assert_array_equal(
+                    np.asarray(gf[name])[live], np.asarray(wf[name])[live])
+        if fires:
+            assert sum(int(np.asarray(c).sum()) for _w, c, _f in got) > 0
+
+
+# ---------------------------------------------------------------------------
+# the stage clock on the mesh
+# ---------------------------------------------------------------------------
+
+def test_the_deal_has_a_stage_of_its_own_and_the_link_counts_used_rows():
+    """The spans themselves (`stage.shard` inside `stage.fill`, one `seq`)
+    are read from a capture in tests/test_stage_clock.py's mesh4 job."""
+    sharded = ShardedFusedPipeline(_mesh(4), ASSIGNER, "count",
+                                   prologue=_prologue("count"), **GEOM)
+    clock = StageClock()
+    sharded.attach_stage_clock(clock)
+    for steps, wms in zip(_dispatches(), WATERMARKS):
+        d = sharded.process_superbatch_raw(steps, wms, defer=True)
+        d.resolve()
+        clock.d2h_bytes += d.nbytes         # as `_resolve_oldest` counts it
+    n = len(FIRES)
+    table = clock.stage_table()
+    assert table["stage.shard"]["count"] == table["stage.fill"]["count"] == n
+    # the deal is a view of the staged arrays: its copy is `stage.put`'s
+    assert table["stage.shard"]["ms"] < table["stage.fill"]["ms"]
+    link = clock.link()
+    assert link["eventsStaged"] == n * STEPS * BATCH
+    assert link["d2hBytes"] == sum(_ceil16(f) * K * 4 + 8 for f in FIRES)
+    # at the cell's shape, 2^21 events and at most 16 rows of 65 536 keys a
+    # dispatch: under 4 B per event where all R = 256 rows read 32
+    assert _ceil16(1) * 65_536 * 4 / 2 ** 21 < 4 < 256 * 65_536 * 4 / 2 ** 21
+
+
+# ---------------------------------------------------------------------------
+# the lane deal
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("n,B,tail", [(4, 1024, ()), (3, 1024, ()),
+                                      (3, 512, (7,)), (2, 6, ())])
+def test_lane_deal_drops_and_doubles_no_record(n, B, tail):
+    pipe = ShardedFusedPipeline(_mesh(n), ASSIGNER, "count",
+                                **dict(GEOM, key_capacity=6 * 64))
+    T = 4
+    a = np.arange(T * B * int(np.prod(tail, dtype=int)),
+                  dtype=np.int32).reshape((T, B) + tail)
+    dealt = pipe._deal_lanes(a, -1)
+    Bs = -(-B // n)
+    assert dealt.shape == (n, T, Bs) + tail
+    # shard i holds lanes [i*Bs, (i+1)*Bs) of every step, pads at the end
+    flat = np.concatenate(list(dealt), axis=1)          # [T, n*Bs, ...]
+    np.testing.assert_array_equal(flat[:, :B], a)
+    assert (flat[:, B:] == -1).all() and flat.shape[1] - B == n * Bs - B < n

@@ -225,7 +225,8 @@ def traced_job(request, tmp_path_factory):
 
 def test_every_documented_span_is_on_the_job_thread(traced_job):
     names = {n[len(SPAN):] for n, _a, _b in traced_job["spans"]}
-    want = COMMON | ({"keys.lookup"} if traced_job["host_keyed"] else set())
+    want = COMMON | ({"keys.lookup"} if traced_job["host_keyed"] else set()) \
+        | ({"stage.shard"} if traced_job["mesh"] else set())
     assert names == want
     assert names <= set(STAGES)
     # nothing of the program's lies on another thread
@@ -263,6 +264,20 @@ def test_one_dispatch_shares_a_seq_from_fill_to_emit(traced_job):
     assert sorted(by_seq) == list(range(1, link["dispatches"] + 1))
     for seq, names in by_seq.items():
         assert {"stage.fill", "stage.put", "dispatch", "resolve"} <= names
+    if traced_job["mesh"]:
+        # the deal over the shards lies inside the fill of its dispatch
+        # (by start: where the planner's own fill nests in the mesh's, the
+        # outer one is kept)
+        at = {}
+        for (name, a, b), st in sorted(traced_job["stats"].items(),
+                                       key=lambda kv: kv[0][1]):
+            if "seq" in st:
+                at.setdefault((name[len(SPAN):], int(st["seq"])), (a, b))
+        shards = [k for k in at if k[0] == "stage.shard"]
+        assert sorted(seq for _n, seq in shards) == sorted(by_seq)
+        for _n, seq in shards:
+            (fa, fb), (sa, sb) = at["stage.fill", seq], at["stage.shard", seq]
+            assert fa <= sa and sb <= fb
     assert any("emit" in names for names in by_seq.values())
     programs = {st["program"] for (n, _a, _b), st in
                 traced_job["stats"].items() if n == SPAN + "dispatch"}
@@ -308,6 +323,10 @@ def test_stage_and_link_tables_agree_with_the_job(traced_job):
     assert entry["deviceDispatches"] > 0
     if traced_job["mesh"]:
         assert result.metrics["mesh_devices"] == traced_job["mesh"]
+        # only the fire rows a dispatch used come back from the shards
+        # (all R = 256 rows of four slabs would be 32 B per event here)
+        assert link["d2hBytes"] / link["eventsStaged"] < 4
+        assert stages["stage.shard"]["count"] == link["dispatches"]
 
 
 def test_timing_off_enters_no_site(tmp_path):
