@@ -754,7 +754,10 @@ class ObservabilityOptions:
         "(source.poll .. sink.write, docs/observability.md) is a "
         "flink_tpu.<stage> span in any profiler capture and a count + self "
         "time in the per-operator stages table, with the link counters "
-        "(h2dBytes, d2hBytes, eventsStaged, rowsEmitted, dispatches). "
+        "(h2dBytes, d2hBytes, eventsStaged, rowsEmitted, dispatches, and "
+        "stepsPlannedScalar / stepsPlannedMasked: data steps whose slice "
+        "plan came from the batch's two timestamp extremes, or per record "
+        "under a late mask). "
         "deviceDispatchMs / deviceTimeMsTotal / deviceDispatches are derived "
         "from its outer sections: HOST time in the dispatch and resolve "
         "sections, not device time. Host clock round already-synchronous "

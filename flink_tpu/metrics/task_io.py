@@ -234,6 +234,10 @@ class StageClock:
         self.columns_staged = 0
         self.record_columns = 0
         self.rows_emitted = 0
+        # data steps staged, by how their slice plan was made: from the
+        # step's two timestamp extremes, or per record under a late mask
+        self.steps_planned_scalar = 0
+        self.steps_planned_masked = 0
         self.seq = 0            # the dispatch being staged (`dispatches` so far)
         self.total_s = 0.0
         self.dispatches = 0     # outer sections entered
@@ -269,6 +273,13 @@ class StageClock:
         if columns is not None:
             self.columns_staged, self.record_columns = columns
 
+    def planned(self, masked: bool) -> None:
+        """One data step's slice plan reached staging (StepPlan.masked)."""
+        if masked:
+            self.steps_planned_masked += 1
+        else:
+            self.steps_planned_scalar += 1
+
     def stage_table(self) -> Dict[str, Dict[str, float]]:
         return merge_stage_tables((self,))
 
@@ -277,7 +288,9 @@ class StageClock:
                 "eventsStaged": self.events_staged,
                 "columnsStaged": self.columns_staged,
                 "recordColumns": self.record_columns,
-                "rowsEmitted": self.rows_emitted, "dispatches": self.seq}
+                "rowsEmitted": self.rows_emitted, "dispatches": self.seq,
+                "stepsPlannedScalar": self.steps_planned_scalar,
+                "stepsPlannedMasked": self.steps_planned_masked}
 
     def register(self, group) -> None:
         group.gauge("deviceTimeMsTotal", lambda: self.total_s * 1000.0,

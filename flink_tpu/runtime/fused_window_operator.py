@@ -44,7 +44,10 @@ from flink_tpu.core.time import MAX_WATERMARK, MIN_WATERMARK
 from flink_tpu.lint.contracts import inflight_ring
 from flink_tpu.metrics.task_io import dispatch_stage, stage
 from flink_tpu.ops.aggregators import ONE, VALUE, resolve
-from flink_tpu.runtime.fused_window_pipeline import FusedWindowPipeline
+from flink_tpu.runtime.fused_window_pipeline import (
+    FusedWindowPipeline,
+    StepPlan,
+)
 from flink_tpu.scheduler.latency_controller import (
     LatencySpec,
     SuperbatchController,
@@ -66,17 +69,27 @@ class _Step:
     ts: np.ndarray
     wm: int
     n_fires: int
-    # slice ids of ts, when the normalizer already computed them (staging
-    # reuses them instead of re-dividing the whole timestamp column)
-    s_abs: Optional[np.ndarray] = None
+    # the step's finished slice plan (relative slice index, smin, smax),
+    # where the normalizer proved it from the batch's two timestamp
+    # extremes: staging copies it and plans nothing, the pipeline's
+    # _PlanCursor checks the span it claims. None: staging plans the step
+    # itself (late records, hold-back remainders, span splits, empty steps)
+    plan: Optional[StepPlan] = None
 
 
 class StepNormalizer:
     """Host-side simulation of the fused planner's frontier state, used to
-    pre-split raw steps so `stage_superbatch` never raises. Mirrors the
-    geometry formulas of FusedWindowPipeline exactly (same fire/purge
-    frontier math); divergence would be a planner error, so the pipeline's
-    own checks stay on as assertions."""
+    pre-split raw steps so `stage_superbatch` never raises, and the place a
+    data step is planned: a batch whose two timestamp extremes prove that no
+    record is late, none lies beyond the ring and it spans fewer than NSB
+    slices leaves as ONE step carrying its finished slice plan
+    (`FusedWindowPipeline.plan_scalar`: srel, smin, smax), which staging
+    copies. Any other batch takes the masked path (late mask, hold-back,
+    span split) and its steps leave bare, for staging to plan with the same
+    pipeline functions. The geometry is the pipeline's own (delegates, one
+    source of truth); a plan or a frontier that diverged from the
+    pipeline's would be a planner error, so `_PlanCursor`'s checks stay on
+    as the assertions of every step, planned here or there."""
 
     def __init__(self, pipe: FusedWindowPipeline, raw_payload: bool = False):
         self.p = pipe
@@ -103,9 +116,6 @@ class StepNormalizer:
 
     def _min_live_slice(self, wm: int) -> int:
         return self.p._min_live_slice(wm)
-
-    def _slice_of(self, ts: np.ndarray) -> np.ndarray:
-        return self.p._slice_of(np.asarray(ts, dtype=np.int64))
 
     def _fire_wm(self, j: int) -> int:
         """Smallest watermark at which window j fires."""
@@ -195,7 +205,7 @@ class StepNormalizer:
     def _held_min_slice(self) -> Optional[int]:
         if not self._future:
             return None
-        return min(int(self._slice_of(t).min()) for _, _, t in self._future)
+        return min(self.p.slice_span(t)[0] for _, _, t in self._future)
 
     def pad_step(self, wm: Optional[int] = None) -> _Step:
         """An empty no-op step. `wm` defaults to the normalizer's committed
@@ -223,45 +233,62 @@ class StepNormalizer:
         )
         self.wm = wm
 
-    def _append_data(self, out: List[_Step], kid, vals, ts) -> None:
-        p = self.p
-        n = len(ts)
-        if n == 0:
-            return
-        s_abs = self._slice_of(ts)
-        keep = np.ones(n, dtype=bool)
-        if self.wm > MIN_WATERMARK:
-            keep = s_abs >= self._min_live_slice(self.wm)  # late records: the
-            # pipeline drops/counts them itself; they must not affect splits
-        if not keep.any():
-            out.append(_Step(self._cast(kid), vals, np.asarray(ts, np.int64),
-                             self.wm, 0))
-            return
-
-        # ring-overflow hold-back: a record at slice s needs the full span
-        # [oldest-live-slice, s] resident. Before the first watermark the
-        # oldest live slice is the smallest slice ever ACCEPTED (min_used),
-        # not this batch's min — otherwise a far-future batch would alias
-        # cells still owned by earlier data (TpuWindowOperator._ring_floor)
-        floor = int(s_abs[keep].min())
+    def _ring_limit(self, smin: int) -> int:
+        """First slice beyond the ring for a batch whose oldest live slice
+        is `smin` (ring-overflow hold-back): a record at slice s needs the
+        full span [oldest-live-slice, s] resident. Before the first
+        watermark the oldest live slice is the smallest slice ever ACCEPTED
+        (min_used), not this batch's min — otherwise a far-future batch
+        would alias cells still owned by earlier data
+        (TpuWindowOperator._ring_floor)."""
+        floor = smin
         if self.min_used is not None:
             floor = min(floor, self.min_used)
         if self.wm > MIN_WATERMARK:
             floor = max(floor, self._min_live_slice(self.wm))
         if self.purged_to is not None:
             floor = max(floor, self.purged_to)
-        limit = floor + p.S - p.NSB
+        return floor + self.p.S - self.p.NSB
+
+    def _append_data(self, out: List[_Step], kid, vals, ts) -> None:
+        p = self.p
+        n = len(ts)
+        if n == 0:
+            return
+        ts = np.asarray(ts, np.int64)
+        plan = p.plan_scalar(ts, self.wm, self._ring_limit)
+        if plan is not None:
+            # hot path (in-order stream, batch within one slice block):
+            # single step, NO column copy, no mask and no group sort — on
+            # the fused chain path this forwards the raw source column
+            # untouched
+            out.append(_Step(
+                self._cast(kid),
+                None if vals is None else np.asarray(vals),
+                ts, self.wm, 0, plan=plan,
+            ))
+            self._note_data(plan.smin, plan.smax)
+            return
+
+        # masked path: late records, hold-back, span split
+        s_abs, keep = p.live_slices(ts, self.wm)  # late records: the
+        # pipeline drops/counts them itself; they must not affect splits
+        if not keep.any():
+            out.append(_Step(self._cast(kid), vals, ts, self.wm, 0))
+            return
+
+        limit = self._ring_limit(int(s_abs[keep].min()))
         over = keep & (s_abs >= limit)
         if over.any():
             idx = np.flatnonzero(over)
             self._future.append((
                 np.asarray(kid)[idx],
                 None if vals is None else np.asarray(vals)[idx],
-                np.asarray(ts)[idx],
+                ts[idx],
             ))
             self.num_future_held += len(idx)
             sel = ~over
-            kid, ts = np.asarray(kid)[sel], np.asarray(ts)[sel]
+            kid, ts = np.asarray(kid)[sel], ts[sel]
             vals = None if vals is None else np.asarray(vals)[sel]
             s_abs, keep = s_abs[sel], keep[sel]
             if len(ts) == 0:
@@ -269,34 +296,21 @@ class StepNormalizer:
             if not keep.any():
                 # only late rows survived the hold-back filter: ship them as
                 # a zero-fire step (the pipeline drops+counts them itself)
-                out.append(_Step(self._cast(kid), vals,
-                                 np.asarray(ts, np.int64), self.wm, 0))
+                out.append(_Step(self._cast(kid), vals, ts, self.wm, 0))
                 return
 
         # slice-span splitting: sub-steps each touching < nsb distinct slices
         smin = int(s_abs[keep].min())
         smax = int(s_abs[keep].max())
-        if smax - smin < p.NSB and bool(keep.all()):
-            # hot path (in-order stream, batch within one slice block):
-            # single step, NO column copy and no group sort — on the fused
-            # chain path this forwards the raw source column untouched
+        group = np.where(keep, (s_abs - smin) // p.NSB, 0)
+        for gval in np.unique(group):
+            sel = group == gval
             out.append(_Step(
-                self._cast(kid),
-                None if vals is None else np.asarray(vals),
-                np.asarray(ts, np.int64),
+                self._cast(np.asarray(kid)[sel]),
+                None if vals is None else np.asarray(vals)[sel],
+                ts[sel],
                 self.wm, 0,
-                s_abs=s_abs,
             ))
-        else:
-            group = np.where(keep, (s_abs - smin) // p.NSB, 0)
-            for gval in np.unique(group):
-                sel = group == gval
-                out.append(_Step(
-                    self._cast(np.asarray(kid)[sel]),
-                    None if vals is None else np.asarray(vals)[sel],
-                    np.asarray(ts)[sel].astype(np.int64),
-                    self.wm, 0,
-                ))
         self._note_data(smin, smax)
 
     def _drain_future(self, out: List[_Step]) -> None:
@@ -796,10 +810,11 @@ class FusedWindowOperator:
         with dispatch_stage(clock, "dispatch"):
             if self.prologue is not None:
                 d = self.pipe.process_superbatch_raw(
-                    [(s.kid, s.ts, s.s_abs) for s in group], wms, defer=True)
+                    [(s.kid, s.ts, s.plan) for s in group], wms, defer=True)
             else:
                 d = self.pipe.process_superbatch(
-                    [(s.kid, s.vals, s.ts) for s in group], wms, defer=True)
+                    [(s.kid, s.vals, s.ts, s.plan) for s in group], wms,
+                    defer=True)
         if self._controller is not None:
             self._ladder_geoms.add(len(group))
         # the purge frontier as of THIS dispatch's staging: cold-tier rows

@@ -56,6 +56,25 @@ class _PlannedFire:
     spec: int = 0     # window spec (shared-partial pipelines; 0 otherwise)
 
 
+class StepPlan(NamedTuple):
+    """One data step's slice plan (FusedWindowPipeline.plan_step): what
+    staging copies into `srel_h` / `idx_h` and hands the plan cursor.
+
+    srel: int32 [n], each record's slice relative to `smin` (-1 = late, in
+      the masked form only); or an int for the whole step: 0 when every
+      record lies in slice `smin`, -1 when every record is late.
+    smin, smax: the live records' slice span (None: no live record).
+    late: records below the live frontier (dropped and counted at staging).
+    masked: False = planned from the two scalars `ts.min()` / `ts.max()`
+      (no record late, span < NSB: no per-record mask was ever built)."""
+
+    srel: Any
+    smin: Optional[int]
+    smax: Optional[int]
+    late: int = 0
+    masked: bool = False
+
+
 class ColumnLayout(NamedTuple):
     """The staged form of a rank-1 record ([n, width] per batch): one
     lane-dense [T, B] array per field the traced chain reads, nothing for
@@ -695,6 +714,84 @@ class FusedWindowPipeline:
     def _slice_of(self, ts: np.ndarray) -> np.ndarray:
         return (ts - np.int64(self.offset)) // np.int64(self.g)
 
+    # ------------------------------------------------------------------
+    # per-step slice plan: the one place a step's timestamps become slice
+    # indices. StepNormalizer plans each step it emits and the step carries
+    # the plan to staging; staging plans only steps that arrive bare.
+    # ------------------------------------------------------------------
+    def slice_span(self, ts: np.ndarray) -> Tuple[int, int]:
+        """(smin, smax) of a non-empty timestamp column from its two
+        extremes: `_slice_of` is monotone, so they are exact."""
+        return ((int(ts.min()) - self.offset) // self.g,
+                (int(ts.max()) - self.offset) // self.g)
+
+    def live_slices(self, ts: np.ndarray, wm: int):
+        """The masked form's per-record arrays: (slice ids, live mask) at
+        watermark `wm` — late records are those whose slice was purged."""
+        s_abs = self._slice_of(ts)
+        if wm > MIN_WATERMARK:
+            return s_abs, s_abs >= self._min_live_slice(wm)
+        return s_abs, np.ones(len(ts), dtype=bool)
+
+    def plan_scalar(self, ts: np.ndarray, wm: int,
+                    limit_of=None) -> Optional[StepPlan]:
+        """The step's plan from two scalars, or None where they cannot prove
+        it: some record is late at `wm`, the step spans NSB or more slices,
+        or (`limit_of(smin)`, the normalizer's hold-back bound) some record
+        lies beyond the ring. No i64 division per record: a step inside one
+        slice has srel 0, any other divides `ts - slice_start(smin)`, which
+        lies in [0, NSB * g), in int32."""
+        smin, smax = self.slice_span(ts)
+        if smax - smin >= self.NSB:
+            return None
+        if wm > MIN_WATERMARK and smin < self._min_live_slice(wm):
+            return None
+        if limit_of is not None and smax >= limit_of(smin):
+            return None
+        if smin == smax:
+            return StepPlan(0, smin, smax)
+        narrow = self.NSB * self.g <= np.iinfo(np.int32).max
+        srel = np.empty(len(ts), np.int32 if narrow else np.int64)
+        np.subtract(ts, self.offset + smin * self.g, out=srel,
+                    casting="unsafe")
+        np.floor_divide(srel, self.g, out=srel)
+        return StepPlan(srel.astype(np.int32, copy=False), smin, smax)
+
+    def plan_masked(self, ts: np.ndarray, wm: int) -> StepPlan:
+        """The same plan per record: late records masked to srel -1 and
+        counted, the span taken over the live ones. The reference for
+        plan_scalar, and the path of out-of-order streams."""
+        s_abs, keep = self.live_slices(ts, wm)
+        late = int(len(ts) - keep.sum())
+        if late == len(ts):
+            return StepPlan(-1, None, None, late, True)
+        live = s_abs[keep] if late else s_abs
+        smin = int(live.min())
+        srel = np.where(keep, s_abs - smin, -1).astype(np.int32)
+        return StepPlan(srel, smin, int(live.max()), late, True)
+
+    def plan_step(self, ts: np.ndarray, wm: int) -> StepPlan:
+        """Plan one non-empty data step at watermark `wm`: from two scalars
+        where they suffice, per record otherwise."""
+        plan = self.plan_scalar(ts, wm)
+        return plan if plan is not None else self.plan_masked(ts, wm)
+
+    def _take_plan(self, plan: Optional[StepPlan], ts: np.ndarray,
+                   cur: "_PlanCursor", t: int, smin_pos) -> StepPlan:
+        """Staging's use of a step's plan (made here if the step came
+        bare): count it, and hand its span to the plan cursor — whose
+        checks stay on as the assertions of a plan made elsewhere."""
+        if plan is None:
+            plan = self.plan_step(ts, cur.wm)
+        self.num_late_records_dropped += plan.late
+        clock = self.stage_clock
+        if clock is not None:
+            clock.planned(plan.masked)
+        if plan.smin is not None:
+            cur.observe(plan.smin, plan.smax)
+            smin_pos[t] = plan.smin % self.S
+        return plan
+
     def _j_fired_upto(self, wm: int) -> int:
         return (wm + 1 - self.offset - self.size_ms) // self.slide_ms
 
@@ -809,7 +906,8 @@ class FusedWindowPipeline:
     ):
         """Run T = len(batches) steps in one dispatch.
 
-        batches: (key_ids int32[B], values f32[B] | None, timestamps int64[B]);
+        batches: (key_ids int32[B], values f32[B] | None, timestamps int64[B]
+        [, StepPlan]) — the normalizer's finished slice plan where it made one;
         watermarks[i] is the watermark after batch i. Returns one
         (window, count_row[K], {field: row[K]}) per fired window, in fire
         order; row entries for keys with count 0 are meaningless.
@@ -959,7 +1057,9 @@ class FusedWindowPipeline:
             B = max(max((len(b[2]) for b in batches), default=0), 1)
             B = -(-B // self.chunk) * self.chunk
 
-            idx_h = np.full((T, B), -1, dtype=np.int32)
+            # np.empty: real lanes are filled below, only the pad tails are
+            # set to -1 (whole rows of empty or all-late steps)
+            idx_h = np.empty((T, B), dtype=np.int32)
             # value-less aggregates (count) carry a [T,1] placeholder instead of
             # shipping a dead [T,B] f32 column to the device
             vals_h = np.zeros((T, B if self._needs_vals else 1), dtype=np.float32)
@@ -971,29 +1071,35 @@ class FusedWindowPipeline:
             fires: List[_PlannedFire] = []
 
             cur = self._cursor()
-            for t, (kid, vals, ts) in enumerate(batches):
+            for t, batch in enumerate(batches):
+                kid, vals, ts = batch[:3]
                 n = len(ts)
-                s_abs = self._slice_of(np.asarray(ts, dtype=np.int64))
-                keep = np.ones(n, dtype=bool)
-                if cur.wm > MIN_WATERMARK:
-                    keep = s_abs >= self._min_live_slice(cur.wm)
-                    self.num_late_records_dropped += int(n - keep.sum())
-                if keep.any():
-                    live = s_abs[keep]
-                    smin = int(live.min())
-                    cur.observe(smin, int(live.max()))
-                    srel = (s_abs - smin).astype(np.int32)
-                    # kid -1 = a cold-routed record (state/tier_manager.py):
-                    # it rides the step so fires over its slices get PLANNED,
-                    # but it must never scatter into a hot row — mask to the
-                    # same -1 the ingest drops (pad-row semantics)
-                    kid64 = np.asarray(kid, dtype=np.int64)
-                    idx_h[t, :n] = np.where(
-                        keep & (kid64 >= 0), kid64 * self.NSB + srel, -1
-                    ).astype(np.int32)
-                    if vals is not None and self._needs_vals:
-                        vals_h[t, :n] = np.where(keep, vals, 0.0)
-                    smin_pos[t] = smin % self.S
+                live = 0
+                if n:
+                    plan = self._take_plan(
+                        batch[3] if len(batch) > 3 else None,
+                        np.asarray(ts, dtype=np.int64), cur, t, smin_pos)
+                    if plan.smin is not None:
+                        live = n
+                        row = idx_h[t, :n]
+                        kid = np.asarray(kid)
+                        np.multiply(kid, self.NSB, out=row, casting="unsafe")
+                        if isinstance(plan.srel, np.ndarray):   # else 0
+                            row += plan.srel
+                        keep = None
+                        if plan.masked:     # late records: srel -1
+                            keep = plan.srel >= 0
+                            row[~keep] = -1
+                        # kid -1 = a cold-routed record (state/tier_manager.py):
+                        # it rides the step so fires over its slices get PLANNED,
+                        # but it must never scatter into a hot row — mask to the
+                        # same -1 the ingest drops (pad-row semantics)
+                        if int(kid.min()) < 0:
+                            row[kid < 0] = -1
+                        if vals is not None and self._needs_vals:
+                            vals_h[t, :n] = (vals if keep is None
+                                             else np.where(keep, vals, 0.0))
+                idx_h[t, live:] = -1
                 cur.advance(t, watermarks[t], fire_pos, fire_valid, fire_row,
                             purge_mask, fires)
             cur.commit()
@@ -1089,16 +1195,17 @@ class FusedWindowPipeline:
     def stage_superbatch_raw(self, steps, watermarks):
         """Host planning + device staging for one traced-chain dispatch.
 
-        steps: [(raw_column [n, ...], timestamps int64 [n][, slice_ids])] —
-        raw source values BEFORE any chain transform (slice_ids optional:
-        the normalizer's precomputed `_slice_of(ts)`). The host plans
+        steps: [(raw_column [n, ...], timestamps int64 [n][, StepPlan])] —
+        raw source values BEFORE any chain transform (the plan optional:
+        the normalizer's finished slice plan, copied as given; a bare step
+        is planned here by the same `plan_step`). The host plans
         fires/purges from
         the timestamps alone (the chain never changes timestamps, and a
         filter only removes records, so timestamp-derived slice bounds stay
         valid upper bounds; windows planned over filtered-out slices fire
         empty rows, which emission drops). Late records are masked to
-        srel -1 here (and counted), so the traced program never sees them
-        as live."""
+        srel -1 by the plan (and counted here), so the traced program never
+        sees them as live."""
         import jax
 
         clock = self.stage_clock
@@ -1208,7 +1315,9 @@ class FusedWindowPipeline:
         else:
             raw_h = tuple(np.empty((T, B), dtype=layout.dtype)
                           for _c in layout.columns)
-        srel_h = np.full((T, B), -1, dtype=np.int32)
+        # np.empty too: each step's lanes take its plan's srel, only the pad
+        # tails (and whole rows of empty steps) are set to -1
+        srel_h = np.empty((T, B), dtype=np.int32)
         ts_h = (np.empty((T, B), dtype=_jdt.canonicalize_dtype(np.int64))
                 if self.prologue.needs_ts else None)
         smin_pos = np.zeros(T, dtype=np.int32)
@@ -1221,22 +1330,13 @@ class FusedWindowPipeline:
         cur = self._cursor()
         for t, step in enumerate(steps):
             raw, ts = step[0], step[1]
-            pre_s_abs = step[2] if len(step) > 2 else None
             n = len(ts)
+            srel_h[t, n:] = -1
             if n:
                 ts_arr = np.asarray(ts, dtype=np.int64)
-                s_abs = (pre_s_abs if pre_s_abs is not None
-                         else self._slice_of(ts_arr))
-                keep = np.ones(n, dtype=bool)
-                if cur.wm > MIN_WATERMARK:
-                    keep = s_abs >= self._min_live_slice(cur.wm)
-                    self.num_late_records_dropped += int(n - keep.sum())
-                if keep.any():
-                    live = s_abs[keep]
-                    smin = int(live.min())
-                    cur.observe(smin, int(live.max()))
-                    srel_h[t, :n] = np.where(keep, s_abs - smin, -1).astype(np.int32)
-                    smin_pos[t] = smin % self.S
+                plan = self._take_plan(step[2] if len(step) > 2 else None,
+                                       ts_arr, cur, t, smin_pos)
+                srel_h[t, :n] = plan.srel
                 # checked canonical cast: an int64/float64 source column
                 # narrowing into the staging dtype must not silently wrap
                 # (same contract as the timestamp guard below); the host
