@@ -398,20 +398,20 @@ def run_tpu_stream(T: int, B: int, spans: int, depth: int, t0_step: int = 0,
     def stage(p, lo):
         bounds = [step_bounds(lo + r, B, slide_ms) for r in range(T)]
         wms = [(lo + r + 1) * STEP_MS - WM_DELAY_MS for r in range(T)]
-        plan, smin_abs = p.plan_superbatch(bounds, wms)
+        staged, smin_abs = p.plan_superbatch(bounds, wms)
         out = gen(jnp.int32(lo), jnp.asarray(smin_abs))
         if with_vals:
             idx, vals = out
         else:
             idx, vals = out, jnp.zeros((T, 1), jnp.float32)
-        return (idx, vals, plan)
+        return staged._replace(xs=(idx, vals))
 
     if warmup:
         # compile gen + superscan + staging shapes on a throwaway pipe (the
         # compiled executables are shared via module-level caches), so the
         # timed region below measures steady-state streaming only
         wpipe = mk()
-        wpipe.process_superbatch(None, None, staged=stage(wpipe, t0_step))
+        wpipe.dispatch(stage(wpipe, t0_step))
         del wpipe
 
     # observability: host time split per pipeline stage — plan+generate+
@@ -421,9 +421,7 @@ def run_tpu_stream(T: int, B: int, spans: int, depth: int, t0_step: int = 0,
 
     def enqueue(i):
         t0 = time.perf_counter()
-        d = pipe.process_superbatch(
-            None, None, staged=stage(pipe, t0_step + i * T), defer=True,
-        )
+        d = pipe.dispatch(stage(pipe, t0_step + i * T), defer=True)
         stage_time["plan_stage_dispatch"] += time.perf_counter() - t0
         return d, time.perf_counter()
 

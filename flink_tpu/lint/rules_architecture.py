@@ -8,6 +8,10 @@ ArchUnit layer definitions with frozen stores):
   level (lazy, function-scoped imports are the sanctioned escape hatch).
 - ARCH002 checkpoint-below-runtime — flink_tpu/checkpoint must not import
   flink_tpu.runtime anywhere, lazy imports included.
+- ARCH003 one-staging-path — the stage clock's stage.fill and stage.put
+  sections have one `dispatch_stage` site each in the package, and the
+  fused window operator does not branch on the prologue to pick a pipeline
+  method.
 - DOC001 config-docs-complete — every declared ConfigOption key must
   appear in docs/configuration.md.
 """
@@ -17,7 +21,7 @@ from __future__ import annotations
 import ast
 from typing import Dict, Iterator, List, Tuple
 
-from flink_tpu.lint.index import ModuleIndex
+from flink_tpu.lint.index import ModuleIndex, enclosing_scope, parent_map
 from flink_tpu.lint.rule import Rule, Violation, register  # noqa: F401 — Violation used in annotations
 
 #: layer dir -> package-relative module prefixes it must NOT import at
@@ -133,6 +137,98 @@ class CheckpointBelowRuntimeRule(Rule):
                         (f"checkpoint layer imports {imp} (must stay below "
                          f"the runtime, lazy imports included)"),
                         symbol=base if n == 1 else f"{base}#{n}")
+
+
+#: the stage-clock sections that belong to the one staging path
+ONE_SITE_STAGES = ("stage.fill", "stage.put")
+
+#: the operator that hands groups of steps to a window pipeline
+WINDOW_OPERATOR = "runtime/fused_window_operator.py"
+
+
+def _mentions_prologue(test: ast.AST) -> bool:
+    return any((isinstance(n, ast.Attribute) and n.attr == "prologue")
+               or (isinstance(n, ast.Name) and n.id == "prologue")
+               for n in ast.walk(test))
+
+
+def _pipe_calls(nodes) -> Iterator[ast.Call]:
+    """Calls of a method on `<...>.pipe` anywhere under `nodes`."""
+    for root in nodes:
+        for n in ast.walk(root):
+            if (isinstance(n, ast.Call) and isinstance(n.func, ast.Attribute)
+                    and isinstance(n.func.value, ast.Attribute)
+                    and n.func.value.attr == "pipe"):
+                yield n
+
+
+@register
+class OneStagingPathRule(Rule):
+    id = "ARCH003"
+    name = "one-staging-path"
+    family = "architecture"
+    rationale = (
+        "Every fused window job stages and dispatches through ONE loop: "
+        "`FusedWindowPipeline.stage` holds the only stage.fill / stage.put "
+        "sections and `.dispatch` the only program call; payload, "
+        "placement and program are the three things that vary, each behind "
+        "one object. Before PR 28 the same job was written out eleven "
+        "times in two modules, and every host-side change had to be made "
+        "in two or three copies to hold in every benchmark cell. A second "
+        "stage.fill section, or an operator that "
+        "picks a pipeline method by `self.prologue`, is that fork "
+        "growing back."
+    )
+    hint = ("stage through FusedWindowPipeline.stage (a new payload, "
+            "placement or program is an object behind it, not a sibling "
+            "method)")
+
+    def check(self, index: ModuleIndex) -> Iterator[Violation]:
+        sites: Dict[str, List[Tuple]] = {name: [] for name in ONE_SITE_STAGES}
+        for mod in index.modules:
+            parents = None
+            for node in ast.walk(mod.tree):
+                if not (isinstance(node, ast.Call) and len(node.args) >= 2):
+                    continue
+                fn = node.func
+                called = fn.id if isinstance(fn, ast.Name) else \
+                    fn.attr if isinstance(fn, ast.Attribute) else ""
+                arg = node.args[1]
+                if (called == "dispatch_stage"
+                        and isinstance(arg, ast.Constant)
+                        and arg.value in sites):
+                    parents = parents or parent_map(mod.tree)
+                    sites[arg.value].append(
+                        (mod, node.lineno, enclosing_scope(parents, node)))
+        for name, found in sites.items():
+            for mod, line, scope in found[1:]:
+                yield self.violation(
+                    mod, line,
+                    (f"a second `{name}` section (the first is in "
+                     f"{found[0][0].rel_to_project}: {found[0][2]}) — "
+                     f"{len(found)} sites, one staging loop is the rule"),
+                    scope=scope, symbol=f"stage:{name}")
+        op = index.get(WINDOW_OPERATOR)
+        if op is None:
+            return
+        parents = parent_map(op.tree)
+        for node in ast.walk(op.tree):
+            if isinstance(node, ast.If):
+                arms = node.body + node.orelse
+            elif isinstance(node, ast.IfExp):
+                arms = [node.body, node.orelse]
+            else:
+                continue
+            if not _mentions_prologue(node.test):
+                continue
+            for call in _pipe_calls(arms):
+                yield self.violation(
+                    op, call.lineno,
+                    (f"`pipe.{call.func.attr}` chosen by a branch on the "
+                     "prologue: the pipeline's payload decides that, the "
+                     "operator hands every group to one method"),
+                    scope=enclosing_scope(parents, call),
+                    symbol=f"pipe.{call.func.attr}")
 
 
 def _declared_config_keys(index: ModuleIndex) -> List[Tuple[str, int, str]]:

@@ -279,6 +279,9 @@ class EmissionLatencyTracker:
 
 # -- tail attribution ----------------------------------------------------
 
+#: the scope whose spans outrank every other owner of a stall they overlap
+_ROOT_CAUSE_SCOPE = "recovery"
+
 def _span_fields(s: Any) -> Tuple[str, str, float, float, Dict[str, Any]]:
     if isinstance(s, dict):
         return (s.get("scope", ""), s.get("name", ""),
@@ -292,25 +295,32 @@ def _span_fields(s: Any) -> Tuple[str, str, float, float, Dict[str, Any]]:
 def stall_attribution(spans: List[Any], *,
                       slack_ms: float = 50.0) -> Dict[str, Any]:
     """Join `latency`-scope outlier spans against every concurrent
-    control-plane span by interval overlap. The owner of an outlier is
-    the control span with the largest overlap of its stall interval
-    `[due, resolve]`; outliers no control span touches stay unattributed
-    (the stall was the data plane itself: superbatch depth, readback)."""
+    control-plane span by interval overlap. A `recovery` span that the
+    stall interval `[due, resolve]` itself overlaps (no slack) owns the
+    outlier outright: what else runs inside a stall that spans a restart —
+    the restored job's first checkpoint, its recompiles — is the restart's
+    consequence, and whether one of those outlasts the restart span is
+    scheduling, not cause. Otherwise the owner is the control span with
+    the largest overlap of the interval widened by `slack_ms`; outliers no
+    control span touches stay unattributed (the stall was the data plane
+    itself: superbatch depth, readback)."""
     outliers, controls = [], []
     for s in spans:
         scope, name, start, end, attrs = _span_fields(s)
         if scope == LATENCY_SPAN_SCOPE:
             outliers.append((start, end, attrs))
         else:
-            controls.append((f"{scope}.{name}", start, end))
+            controls.append((f"{scope}.{name}", start, end,
+                             scope == _ROOT_CAUSE_SCOPE))
     attributed: Dict[str, Dict[str, float]] = {}
     unattributed = 0
     for start, end, attrs in outliers:
-        best, best_overlap = None, 0.0
-        for key, cs, ce in controls:
+        best, best_rank = None, (False, 0.0)
+        for key, cs, ce, root in controls:
             overlap = min(end + slack_ms, ce) - max(start - slack_ms, cs)
-            if overlap > best_overlap:
-                best, best_overlap = key, overlap
+            rank = (root and min(end, ce) > max(start, cs), overlap)
+            if overlap > 0.0 and rank > best_rank:
+                best, best_rank = key, rank
         if best is None:
             unattributed += 1
             continue

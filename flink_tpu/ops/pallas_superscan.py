@@ -21,7 +21,7 @@ partial histograms). This kernel removes both by fusing the full dispatch
 Its rate, alone and against the XLA superscan, is not measured on the
 current code and installation (jax 0.9.0, libtpu 0.0.34).
 
-Segment encoding matches the host planner (`stage_superbatch`):
+Segment encoding matches the host planner (`FusedWindowPipeline.stage`):
 `idx = key_id * NSB + rel_slice`, negative = dropped. In-kernel it is
 re-factored to `seg = rel_slice * K + key_id` so a segment's histogram
 lands at rows `rel_slice * K/128 + key_id/128`, lane `key_id % 128` —

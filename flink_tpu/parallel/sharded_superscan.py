@@ -57,7 +57,8 @@ ICI.
 
 from __future__ import annotations
 
-from typing import Any, Dict, Optional, Sequence
+import weakref
+from typing import Any, Dict, Optional
 
 import numpy as np
 import jax
@@ -79,9 +80,14 @@ class ShardedFusedPipeline:
     """Keyed window aggregation over a device mesh, T steps per dispatch.
 
     Presents the same pipeline surface `FusedWindowOperator` drives on one
-    chip (process_superbatch / process_superbatch_raw / ensure_key_capacity
-    / snapshot / restore plus the planner-geometry delegates), so the
+    chip (process_superbatch / stage / dispatch / ensure_key_capacity /
+    snapshot / restore plus the planner-geometry delegates), so the
     operator adapter — and through it DeviceChainRunner — is mesh-agnostic.
+    Staging and dispatch are the planner's own (`FusedWindowPipeline.stage`
+    / `.dispatch`, reached through `__getattr__`): the planner fills the
+    same host arrays as on one chip and asks its `deployment`, this object,
+    for the two things a mesh changes: the placement (`_place`) and the
+    program (`_program`).
     """
 
     def __init__(
@@ -140,6 +146,7 @@ class ShardedFusedPipeline:
                 exact_sums=exact_sums, backend="xla", plan_only=True,
                 prologue=prologue,
             )
+        self._planner._mesh = weakref.ref(self)
         self.agg = self._planner.agg
         self.prologue = prologue
         self.K = key_capacity
@@ -171,12 +178,6 @@ class ShardedFusedPipeline:
             self._refresh_route_tables()
         self._init_state()
         self._fn_cache: Dict[tuple, Any] = {}
-        # device-plane observability: an attached CompileTracker wraps the
-        # sharded dispatch; phase counters thread through the shared
-        # superscan step body (summed over shards at resolve, accumulated
-        # into the planner's phase_totals)
-        self.compile_tracker = None
-        self.phase_counters = False
         # latency mode (scheduler/latency_controller.py): donate the
         # sharded [n, Kl, S] scan carry to the executable. Streaming fire
         # readback (readback_steps) stays single-chip only — splitting the
@@ -191,7 +192,8 @@ class ShardedFusedPipeline:
     # the operator adapter read the frontier/geometry surface of a
     # single-chip pipeline (g/sl/spw/offset/size_ms/slide_ms, the
     # watermark/fire/purge cursors, _j_*/_slice_of/_window_of,
-    # phase_totals, num_late_records_dropped). On the mesh that state
+    # phase_totals, num_late_records_dropped, the attached CompileTracker
+    # and the phase-counter flag: `attach_device_stats`). On the mesh that state
     # lives in the plan-only planner — one source of truth for the window
     # math — so every attribute this class does not define itself
     # forwards there wholesale: a per-member delegate list would drift
@@ -207,17 +209,6 @@ class ShardedFusedPipeline:
         return getattr(self._planner, name)
 
     # ------------------------------------------------------------------
-    def attach_device_stats(self, tracker, phase_counters: bool = True) -> None:
-        """Wire a CompileTracker (metrics/device_stats.py) around the
-        sharded dispatch. Call before the first dispatch: the phase flag
-        is part of the executable cache key."""
-        self.compile_tracker = tracker
-        self.phase_counters = bool(phase_counters)
-
-    @property
-    def phase_totals(self):
-        return self._planner.phase_totals
-
     def key_loads(self):
         """Global per-key record counts ([K], canonical key order) for the
         key-stats fold — one reshape + segment-sum over the sharded count
@@ -299,9 +290,6 @@ class ShardedFusedPipeline:
     def key_stats_ready(self) -> bool:
         return self._planner.max_seen_slice is not None
 
-    def state_row_bytes(self) -> int:
-        return self._planner.state_row_bytes()
-
     # ------------------------------------------------------------------
     def _shard_spec(self, *tail):
         return NamedSharding(self.mesh, P(self.axis, *tail))
@@ -316,10 +304,6 @@ class ShardedFusedPipeline:
                              device=spec)
             for f in self._value_fields
         }
-
-    @property
-    def num_late_records_dropped(self) -> int:
-        return self._planner.num_late_records_dropped
 
     def ensure_key_capacity(self, required: int) -> None:
         """Grow the GLOBAL key dimension when the host dictionary outgrows
@@ -571,34 +555,23 @@ class ShardedFusedPipeline:
         return fn
 
     # ------------------------------------------------------------------
-    def stage_superbatch(self, batches: Sequence, watermarks: Sequence[int]):
-        """Host planning + staging. `batches[t] = (keys, vals|None, ts)` is
-        the step's GLOBAL record set; lanes are dealt contiguously across the
-        n source shards (any split works — the in-scan all-to-all re-routes
-        by key ownership)."""
-        clock = self.stage_clock
-        # the planner's own stage.fill / stage.put nest inside this fill
-        with dispatch_stage(clock, "stage.fill"):
-            plan_idx, plan_vals, plan = self._planner.stage_superbatch(
-                batches, watermarks)
-            idx_h = np.asarray(plan_idx)          # [T, B_padded] int32
-            vals_h = np.asarray(plan_vals) if self._needs_vals else None
-            with dispatch_stage(clock, "stage.shard"):
-                idx_sh = self._deal_lanes(idx_h, -1)
-                vals_sh = (None if vals_h is None
-                           else self._deal_lanes(vals_h, 0))
-            T = idx_h.shape[0]
-        with dispatch_stage(clock, "stage.put"):
-            # host arrays go to device_put as they are: each device receives
-            # its own lanes, nothing is first committed whole to device 0
-            idx_d = jax.device_put(idx_sh, self._shard_spec(None, None))
-            if vals_sh is not None:
-                vals_d = jax.device_put(vals_sh, self._shard_spec(None, None))
-            else:
-                vals_d = jnp.zeros((T, 1), jnp.float32)
-            if clock is not None:
-                clock.staged((idx_sh, vals_sh))
-        return idx_d, vals_d, plan
+    def _place(self, payload, xs_h, lanes):
+        """Placement on the mesh (`FusedWindowPipeline.stage` calls it
+        inside stage.fill): a step's lanes are dealt contiguously over the
+        n source shards (any split works — the in-scan all-to-all
+        re-routes every record to its key owner), and `device_put` hands
+        each device its own lanes: nothing is first committed whole to
+        device 0. Arrays without lanes (a value-less aggregate's [T, 1]
+        placeholder) are replicated."""
+        with dispatch_stage(self.stage_clock, "stage.shard"):
+            # the first array's -1 marks a dead lane: pad lanes are dead
+            xs_h = tuple(
+                self._deal_lanes(a, 0 if i else -1) if i < lanes else a
+                for i, a in enumerate(xs_h))
+        return xs_h, tuple(
+            self._shard_spec(*([None] * (a.ndim - 1))) if i < lanes
+            else NamedSharding(self.mesh, P())
+            for i, a in enumerate(xs_h))
 
     def _deal_lanes(self, a: np.ndarray, fill) -> np.ndarray:
         """[T, B, ...] -> [n, T, Bs, ...]: every step's lanes dealt
@@ -613,46 +586,10 @@ class ShardedFusedPipeline:
                 axis=1)
         return np.swapaxes(a.reshape((T, n, Bs) + a.shape[2:]), 0, 1)
 
-    def process_superbatch(self, batches, watermarks, *, staged=None,
-                           defer: bool = False):
-        from flink_tpu.runtime.fused_window_pipeline import DeferredEmissions
+    def _program(self, payload):
+        return _MESH_CHAINED if payload.record else _MESH_CLASSIC
 
-        if staged is None:
-            staged = self.stage_superbatch(batches, watermarks)
-        idx_d, vals_d, plan = staged
-        smin_pos, fire_pos, fire_valid, fire_row, purge_mask, fires = plan
-        T = int(smin_pos.shape[0])
-        B = int(idx_d.shape[2])
-        run = self._build(T, B)
-        names = [f.name for f in self._value_fields]
-        args = (self._count, tuple(self._state[nm] for nm in names),
-                idx_d, vals_d, smin_pos, fire_pos, fire_valid, fire_row,
-                purge_mask)
-        if self.routing is not None:
-            args = args + (self._g_dst, self._g_slot)
-        if self.compile_tracker is not None:
-            out = self.compile_tracker.call(
-                "sharded_superscan", run, args,
-                {"T": T, "B": B, "K": self.K, "S": self.S, "n": self.n,
-                 "dtype": "+".join(str(np.dtype(f.dtype))
-                                   for f in self._value_fields) or "count"})
-        else:
-            out = run(*args)
-        pc_total = None
-        if self.phase_counters:
-            count, states, count_out, field_outs, pc = out
-            pc_total = pc.sum(axis=0)   # fold the shard axis on device
-        else:
-            count, states, count_out, field_outs = out
-        self._count = count
-        self._state = dict(zip(names, states))
-        count_rows, out_rows = self._canonical_fire_rows(
-            count_out, field_outs, names, len(fires))
-        deferred = DeferredEmissions(self._planner, fires, count_rows,
-                                     out_rows, phase_counts=pc_total)
-        return deferred if defer else deferred.resolve()
-
-    def _canonical_fire_rows(self, count_out, field_outs, names, fired):
+    def _canonical_fire_rows(self, count_out, outs, fired):
         """[n, R, K_local] per-shard fire slabs -> [used, K] canonical key
         order, `used` the rows the dispatch's `fired` fires filled (sliced
         on each shard first: what follows, and the deferred readback, move
@@ -661,8 +598,7 @@ class ShardedFusedPipeline:
         rows ride the same async readback either way)."""
         from flink_tpu.runtime.fused_window_pipeline import _used_fire_rows
 
-        count_out, outs = _used_fire_rows(
-            count_out, dict(zip(names, field_outs)), fired, axis=1)
+        count_out, outs = _used_fire_rows(count_out, outs, fired, axis=1)
         used = count_out.shape[1]
         count_rows = jnp.transpose(count_out, (1, 0, 2)).reshape(
             used, self.K)
@@ -865,92 +801,6 @@ class ShardedFusedPipeline:
         self._fn_cache[key] = fn
         return fn
 
-    def stage_superbatch_raw(self, steps, watermarks):
-        """Host planning + mesh staging for one traced-chain dispatch:
-        the planner fills the same flat [T, B] staging buffers the
-        single-chip path uses, then lanes are dealt contiguously across
-        the n source shards (any split works — the in-scan all-to-all
-        re-routes every record to its key owner)."""
-        clock = self.stage_clock
-        with dispatch_stage(clock, "stage.fill"):
-            raw_h, srel_h, ts_h, plan_np, fires = \
-                self._planner._stage_raw_host(steps, watermarks)
-            fields_h, columns = self._planner._record_fields(raw_h)
-            with dispatch_stage(clock, "stage.shard"):
-                srel_sh = self._deal_lanes(srel_h, -1)
-                ts_sh = None if ts_h is None else self._deal_lanes(ts_h, 0)
-                fields_sh = tuple(self._deal_lanes(f, 0) for f in fields_h)
-        with dispatch_stage(clock, "stage.put"):
-            fields_d = tuple(
-                jax.device_put(f, self._shard_spec(*([None] * (f.ndim - 1))))
-                for f in fields_sh)
-            raw_d = fields_d if isinstance(raw_h, tuple) else fields_d[0]
-            srel_d = jax.device_put(srel_sh, self._shard_spec(None, None))
-            ts_d = None
-            if ts_sh is not None:
-                ts_d = jax.device_put(ts_sh, self._shard_spec(None, None))
-            plan = tuple(jax.device_put(a) for a in plan_np) + (fires,)
-            if clock is not None:
-                clock.staged(fields_sh + (srel_sh, ts_sh) + plan_np,
-                             sum(len(step[1]) for step in steps), columns)
-        return raw_d, srel_d, ts_d, plan
-
-    def process_superbatch_raw(self, steps, watermarks, *,
-                               staged: Optional[tuple] = None,
-                               defer: bool = False):
-        """Run T traced-chain steps in one sharded dispatch (the
-        prologue-bearing sibling of process_superbatch; same defer
-        contract as the single-chip pipeline)."""
-        from flink_tpu.runtime.fused_window_pipeline import DeferredEmissions
-
-        if staged is None and all(len(step[1]) == 0 for step in steps):
-            # watermark-only dispatch: with zero rows the prologue is
-            # irrelevant — run the classic fire/purge program over the
-            # same sharded state (mirrors the single-chip fallback, and
-            # covers restore-then-watermark before geometry is known)
-            empty = [(np.empty(0, np.int32), None, np.empty(0, np.int64))
-                     for _ in steps]
-            return self.process_superbatch(empty, watermarks, defer=defer)
-        if staged is None:
-            staged = self.stage_superbatch_raw(steps, watermarks)
-        raw_d, srel_d, ts_d, plan = staged
-        smin_pos, fire_pos, fire_valid, fire_row, purge_mask, fires = plan
-        T = int(srel_d.shape[1])
-        B = int(srel_d.shape[2])
-        layout = self._planner._staged_layout(raw_d)
-        run = self._build_raw(T, B, layout)
-        names = [f.name for f in self._value_fields]
-        args = (self._count, tuple(self._state[nm] for nm in names),
-                raw_d, srel_d)
-        if ts_d is not None:
-            args = args + (ts_d,)
-        args = args + (smin_pos, fire_pos, fire_valid, fire_row, purge_mask)
-        if self.routing is not None:
-            args = args + (self._g_dst, self._g_slot)
-        if self.compile_tracker is not None:
-            out = self.compile_tracker.call(
-                "sharded_chained_superscan", run, args,
-                {"T": T, "B": B, "K": self.K, "S": self.S, "n": self.n,
-                 **self._planner._record_signature(raw_d, layout),
-                 "dtype": "+".join(str(np.dtype(f.dtype))
-                                   for f in self._value_fields) or "count"})
-        else:
-            out = run(*args)
-        pc_total = None
-        if self.phase_counters:
-            count, states, count_out, field_outs, kb, pc = out
-            pc_total = pc.sum(axis=0)
-        else:
-            count, states, count_out, field_outs, kb = out
-        self._count = count
-        self._state = dict(zip(names, states))
-        count_rows, out_rows = self._canonical_fire_rows(
-            count_out, field_outs, names, len(fires))
-        deferred = DeferredEmissions(self._planner, fires, count_rows,
-                                     out_rows, key_bounds=kb,
-                                     key_capacity=self.K,
-                                     phase_counts=pc_total)
-        return deferred if defer else deferred.resolve()
 
     # ------------------------------------------------------------------
     # tiered-state row surface (state/tier_manager.py): same contract as
@@ -1091,3 +941,49 @@ class ShardedFusedPipeline:
         self._planner.num_late_records_dropped = snap["num_late_dropped"]
         if getattr(self._planner, "fire_cursors", None) is not None:
             self._planner.fire_cursors = list(snap["fire_cursors"])
+
+
+class _MeshProgram:
+    """One of the two sharded window programs as
+    `FusedWindowPipeline.dispatch` uses it (the contract of its
+    `_ChipProgram`; `p` is the mesh pipeline): `sharded_superscan` over key
+    ids, or with the traced prologue before the exchange
+    `sharded_chained_superscan` over a record. States go in as [n, Kl, S]
+    slabs with the plan after the lanes; the fire buffers are zeroed inside
+    the program. Streaming fire readback stays single-chip only — splitting
+    the mesh dispatch would multiply the per-step all-to-all count."""
+
+    flat = False
+    grouped = False
+
+    def __init__(self, chained: bool):
+        self.chained = chained
+        self.name = ("sharded_chained_superscan" if chained
+                     else "sharded_superscan")
+
+    def width(self, a, T: int) -> int:
+        return int(a.shape[2])      # lanes per source shard: [n, T, Bs]
+
+    def build(self, p: ShardedFusedPipeline, T: int, B: int, layout):
+        return p._build_raw(T, B, layout) if self.chained else p._build(T, B)
+
+    def call(self, p, run, staged, T: int, B: int):
+        names = [f.name for f in p._value_fields]
+        xs, record_sig = staged.scan_xs()
+        args = (p._count, tuple(p._state[nm] for nm in names)) + xs \
+            + staged.plan
+        if p.routing is not None:
+            args = args + (p._g_dst, p._g_slot)
+        p._count, states, count_out, field_outs, *tail = p._tracked(
+            self.name, run, args, {"T": T, "B": B, "n": p.n, **record_sig})
+        p._state = dict(zip(names, states))
+        key_bounds = tail.pop(0) if self.chained else None
+        # phase counters [n, 3]: fold the shard axis on device
+        return (count_out, dict(zip(names, field_outs)), key_bounds,
+                tail[0].sum(axis=0) if tail else None)
+
+    def fire_rows(self, p, count_out, outs, fired: int):
+        return p._canonical_fire_rows(count_out, outs, fired)
+
+
+_MESH_CLASSIC, _MESH_CHAINED = _MeshProgram(False), _MeshProgram(True)

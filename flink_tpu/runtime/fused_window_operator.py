@@ -79,7 +79,7 @@ class _Step:
 
 class StepNormalizer:
     """Host-side simulation of the fused planner's frontier state, used to
-    pre-split raw steps so `stage_superbatch` never raises, and the place a
+    pre-split raw steps so the pipeline's `stage` never raises, and the place a
     data step is planned: a batch whose two timestamp extremes prove that no
     record is late, none lies beyond the ring and it spans fewer than NSB
     slices leaves as ONE step carrying its finished slice plan
@@ -808,13 +808,10 @@ class FusedWindowOperator:
         # the enqueue: everything of the pipeline's call that is not
         # staging; CompileTracker.call names the program on the span
         with dispatch_stage(clock, "dispatch"):
-            if self.prologue is not None:
-                d = self.pipe.process_superbatch_raw(
-                    [(s.kid, s.ts, s.plan) for s in group], wms, defer=True)
-            else:
-                d = self.pipe.process_superbatch(
-                    [(s.kid, s.vals, s.ts, s.plan) for s in group], wms,
-                    defer=True)
+            # under a traced prologue s.kid is the raw record, s.vals None
+            d = self.pipe.process_superbatch(
+                [(s.kid, s.vals, s.ts, s.plan) for s in group], wms,
+                defer=True)
         if self._controller is not None:
             self._ladder_geoms.add(len(group))
         # the purge frontier as of THIS dispatch's staging: cold-tier rows

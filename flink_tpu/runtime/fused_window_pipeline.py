@@ -313,9 +313,8 @@ class _StreamedEmissions:
 class _PlanCursor:
     """The fire/purge planning state machine for one dispatch.
 
-    Both stage_superbatch (data-driven) and plan_superbatch (bounds-driven)
-    drive this cursor; the plans they produce must be bit-identical for
-    identical streams, so the per-step logic lives only here.
+    The staging loop (`FusedWindowPipeline._fill`) drives it, for data
+    steps and for `plan_superbatch`'s bounds alike.
     """
 
     def __init__(self, pipe: "FusedWindowPipeline"):
@@ -414,6 +413,224 @@ class _PlanCursor:
         p.min_used_slice = self.min_used
         p.max_seen_slice = self.max_seen
 
+
+def _longest(steps) -> int:
+    """Records of the longest step (at least 1: a width is never 0)."""
+    return max(max((len(s[2]) for s in steps), default=0), 1)
+
+
+class Staged(NamedTuple):
+    """One group of steps on the device: what `stage` hands `dispatch`.
+
+    payload: the payload that filled it (a record job's watermark-only
+      group is staged as key ids) — it picks the program.
+    xs: its arrays; the first is the one whose -1 marks a dead lane: key
+      ids (idx, vals); a record (srel, its staged fields or the record
+      array[, ts]).
+    plan: smin_pos, fire_pos, fire_valid, fire_row, purge_mask.
+    layout: how a rank-1 record was split into fields (None: it was not)."""
+
+    payload: Any
+    xs: tuple
+    plan: tuple
+    fires: list
+    layout: Optional[ColumnLayout] = None
+
+    @property
+    def T(self) -> int:
+        return int(self.plan[0].shape[0])
+
+    def scan_xs(self):
+        """(the arrays as a program's scan takes them, the record's part of
+        the dispatch's CompileTracker signature): a record goes first, as
+        the traced chain takes it — the tuple of its staged fields, or the
+        record array —, then srel[, ts]."""
+        if not self.payload.record:
+            return self.xs, {}
+        if self.layout is None:
+            raw, end = self.xs[1], 2
+            sig = {"raw_dtype": str(raw.dtype), "columns": "record"}
+        else:
+            end = 1 + len(self.layout.columns)
+            raw = self.xs[1:end]
+            sig = {"raw_dtype": self.layout.dtype, "columns": str(self.layout)}
+        return (raw,) + self.xs[:1] + self.xs[end:], sig
+
+    def rows(self, lo: int, hi: int) -> "Staged":
+        """Steps [lo, hi) of the group (latency mode's step groups)."""
+        return self._replace(
+            xs=tuple(a[lo:hi] for a in self.xs),
+            plan=tuple(a[lo:hi] for a in self.plan),
+            fires=[pf for pf in self.fires if lo <= pf.step < hi])
+
+
+class _KeyIdPayload:
+    """Steps carry dense key ids and, where the aggregate reads them,
+    values. Staged as idx = kid * NSB + srel per lane and vals; the width
+    is a chunk multiple."""
+
+    record = False
+
+    def alloc(self, p: "FusedWindowPipeline", steps):
+        """(host arrays, how many of them carry one entry per lane, the
+        record's layout)."""
+        T = len(steps)
+        B = -(-_longest(steps) // p.chunk) * p.chunk
+        idx_h = np.empty((T, B), dtype=np.int32)
+        # value-less aggregates (count) carry a [T,1] placeholder instead of
+        # shipping a dead [T,B] f32 column to the device
+        vals_h = np.zeros((T, B if p._needs_vals else 1), dtype=np.float32)
+        return (idx_h, vals_h), 2 if p._needs_vals else 1, None
+
+    def write(self, p, xs_h, layout, t: int, n: int, step,
+              plan: StepPlan) -> int:
+        """Fill step t's first n lanes; returns the lanes left alive."""
+        if plan.smin is None:       # every record late: the whole row dead
+            return 0
+        idx_h, vals_h = xs_h
+        kid, vals = np.asarray(step[0]), step[1]
+        row = idx_h[t, :n]
+        np.multiply(kid, p.NSB, out=row, casting="unsafe")
+        if isinstance(plan.srel, np.ndarray):   # else 0
+            row += plan.srel
+        keep = None
+        if plan.masked:     # late records: srel -1
+            keep = plan.srel >= 0
+            row[~keep] = -1
+        # kid -1 = a cold-routed record (state/tier_manager.py): it rides
+        # the step so fires over its slices get PLANNED, but it must never
+        # scatter into a hot row — mask to the same -1 the ingest drops
+        # (pad-row semantics)
+        if int(kid.min()) < 0:
+            row[kid < 0] = -1
+        if vals is not None and p._needs_vals:
+            vals_h[t, :n] = (vals if keep is None
+                             else np.where(keep, vals, 0.0))
+        return n
+
+    def columns(self, p, layout):
+        """(fields staged, fields of the record) for the stage clock."""
+        return None
+
+
+class _BoundsPayload(_KeyIdPayload):
+    """`plan_superbatch`'s steps: slice bounds and no lanes — the caller
+    stages idx (and vals) on the device itself; dispatched as key ids."""
+
+    def alloc(self, p, steps):
+        return (), 0, None
+
+    def write(self, p, xs_h, layout, t, n, step, plan) -> int:
+        return 0
+
+
+class _RecordPayload:
+    """Steps carry the raw source record BEFORE any chain transform: the
+    traced prologue runs inside the compiled program. Staged as the fields
+    of `_layout()` (or the record array), srel, and ts where the chain
+    reads it; late records are masked to srel -1 by the plan, so the
+    traced program never sees them as live."""
+
+    record = True
+
+    def alloc(self, p: "FusedWindowPipeline", steps):
+        from jax import dtypes as _jdt
+
+        T = len(steps)
+        # staged width quantized to power-of-two multiples of the chunk:
+        # ragged last batches land on a few bounded shapes (log2 many)
+        # instead of compiling a fresh (T, B) executable per width, while
+        # tiny tails keep tiny staging buffers — pad rows are srel -1 and
+        # never touch state
+        B = p.chunk * (
+            1 << max(0, -(-_longest(steps) // p.chunk) - 1).bit_length())
+
+        for step in steps:
+            if not len(step[2]):
+                continue
+            arr = np.asarray(step[0])
+            if p._raw_shape is None:
+                if arr.dtype == object:
+                    raise TypeError(
+                        "the fused device chain needs numeric record "
+                        "columns; this source yields Python objects — use a "
+                        "columnar source (numeric ndarray batches) or drop "
+                        "traceable=True to stay on the host chain"
+                    )
+                p._raw_shape, p._raw_dtype = arr.shape[1:], arr.dtype
+            elif arr.shape[1:] != p._raw_shape or arr.dtype != p._raw_dtype:
+                raise ValueError(
+                    f"record column geometry changed mid-stream: "
+                    f"{arr.dtype}{list(arr.shape[1:])} after "
+                    f"{p._raw_dtype}{list(p._raw_shape)} — the fused "
+                    "chain executable is shaped on a fixed column layout"
+                )
+
+        # np.empty, not zeros: pad rows are srel -1 — every traced consumer
+        # masks on that before touching raw/ts, so the 16MB+ staging memset
+        # per dispatch would be pure waste. Buffers are allocated in jax's
+        # CANONICAL dtype (x64-off: float64→float32, int64→int32): device_put
+        # of a non-canonical array re-casts the whole buffer host-side every
+        # dispatch — a full extra copy, and the garbage pad bytes overflow
+        # the narrowing float cast (RuntimeWarning). Real rows cast at fill.
+        layout = p._layout()
+        xs_h = (np.empty((T, B), dtype=np.int32),)      # srel
+        if layout is None:
+            xs_h += (np.empty((T, B) + p._raw_shape,
+                              dtype=_jdt.canonicalize_dtype(p._raw_dtype)),)
+        else:
+            xs_h += tuple(np.empty((T, B), dtype=layout.dtype)
+                          for _c in layout.columns)
+        if p.prologue.needs_ts:
+            xs_h += (np.empty((T, B),
+                              dtype=_jdt.canonicalize_dtype(np.int64)),)
+        return xs_h, len(xs_h), layout
+
+    def write(self, p, xs_h, layout, t: int, n: int, step,
+              plan: StepPlan) -> int:
+        raw = step[0]
+        xs_h[0][t, :n] = plan.srel
+        # checked canonical cast: an int64/float64 source column narrowing
+        # into the staging dtype must not silently wrap (same contract as
+        # the timestamp guard below); the host fallback casts through the
+        # same helper, so both paths compute on identical canonical inputs.
+        # With a layout the check covers the staged fields: a field no
+        # traced function reads never reaches one
+        if layout is None:
+            xs_h[1][t, :n] = canonical_column(
+                raw, "fused chain record column")
+        else:
+            rec = np.asarray(raw)
+            for field_h, c in zip(xs_h[1:], layout.columns):
+                field_h[t, :n] = canonical_column(
+                    rec[:, c], f"fused chain record column {c}")
+        if p.prologue.needs_ts:
+            ts_h = xs_h[-1]
+            ts_arr = np.asarray(step[2], dtype=np.int64)
+            if ts_h.dtype.itemsize < 8 and (
+                int(ts_arr.max()) > np.iinfo(ts_h.dtype).max
+                or int(ts_arr.min()) < np.iinfo(ts_h.dtype).min
+            ):
+                raise TypeError(
+                    "traceable map_with_timestamp under the fused "
+                    "chain stages timestamps in the backend's "
+                    f"canonical {ts_h.dtype} (jax x64 is disabled) "
+                    "and these event timestamps do not fit — they "
+                    "would silently wrap inside the traced UDF. "
+                    "Rebase event time near zero, enable jax x64, "
+                    "or drop traceable=True to run the host chain."
+                )
+            ts_h[t, :n] = ts_arr
+        return n
+
+    def columns(self, p, layout):
+        if layout is not None:
+            return len(layout.columns), layout.width
+        width = int(np.prod(p._raw_shape))
+        return width, width
+
+
+_KEY_IDS, _BOUNDS, _RECORD = _KeyIdPayload(), _BoundsPayload(), _RecordPayload()
 
 class FusedWindowPipeline:
     """One shard's keyed window aggregation, executed T steps per dispatch."""
@@ -530,7 +747,10 @@ class FusedWindowPipeline:
         self.max_seen_slice: Optional[int] = None
         self.num_late_records_dropped = 0
 
-        self._fn_cache: Dict[Tuple[int, int], Any] = {}
+        # staging (below): what a step of this job carries
+        self._payload = _KEY_IDS if prologue is None else _RECORD
+        # weakref to the mesh pipeline that plans through this one
+        self._mesh = None
 
     # ------------------------------------------------------------------
     # backend selection + state layout
@@ -544,11 +764,13 @@ class FusedWindowPipeline:
         shard_map/multi-chip path and CPU CI).
         """
         if self._pallas is None:
-            from flink_tpu.ops import pallas_superscan
-
             if self.backend == "xla":
                 self._pallas = False
             else:
+                # imported here: a job that never runs the kernel (a traced
+                # chain, the mesh planner) does not pay for loading pallas
+                from flink_tpu.ops import pallas_superscan
+
                 ok = pallas_superscan.supports(
                     self.agg, self.K, self.R, self.S, self.NSB, self.chunk
                 )
@@ -776,13 +998,13 @@ class FusedWindowPipeline:
         plan = self.plan_scalar(ts, wm)
         return plan if plan is not None else self.plan_masked(ts, wm)
 
-    def _take_plan(self, plan: Optional[StepPlan], ts: np.ndarray,
+    def _take_plan(self, plan: Optional[StepPlan], ts,
                    cur: "_PlanCursor", t: int, smin_pos) -> StepPlan:
         """Staging's use of a step's plan (made here if the step came
         bare): count it, and hand its span to the plan cursor — whose
         checks stay on as the assertions of a plan made elsewhere."""
         if plan is None:
-            plan = self.plan_step(ts, cur.wm)
+            plan = self.plan_step(np.asarray(ts, dtype=np.int64), cur.wm)
         self.num_late_records_dropped += plan.late
         clock = self.stage_clock
         if clock is not None:
@@ -894,240 +1116,168 @@ class FusedWindowPipeline:
         return n
 
     # ------------------------------------------------------------------
-    # host planner + dispatch
+    # staging and dispatch: one loop fills the host arrays, one method puts
+    # them on the device, one method calls the compiled program. Three
+    # decisions, each behind one thing: the payload (what a step carries:
+    # `_KeyIdPayload` / `_RecordPayload`, chosen in the constructor), the
+    # placement (`_place`: how host arrays reach devices; the mesh pipeline
+    # substitutes its own through `self.deployment`) and the program
+    # (`_program`: which compiled function runs and how its result is read)
     # ------------------------------------------------------------------
-    def process_superbatch(
-        self,
-        batches: Sequence[Tuple[np.ndarray, Optional[np.ndarray], np.ndarray]],
-        watermarks: Sequence[int],
-        *,
-        staged: Optional[tuple] = None,
-        defer: bool = False,
-    ):
-        """Run T = len(batches) steps in one dispatch.
+    def process_superbatch(self, steps, watermarks, *, defer: bool = False):
+        """Run T = len(steps) steps in one dispatch: `stage`, `dispatch`.
 
-        batches: (key_ids int32[B], values f32[B] | None, timestamps int64[B]
-        [, StepPlan]) — the normalizer's finished slice plan where it made one;
-        watermarks[i] is the watermark after batch i. Returns one
-        (window, count_row[K], {field: row[K]}) per fired window, in fire
-        order; row entries for keys with count 0 are meaningless.
+        steps: `(key_ids int32[n], values f32[n] | None, timestamps
+        int64[n][, StepPlan])`; under a traced prologue the first entry is
+        the raw record column [n, ...] BEFORE any chain transform and the
+        second is None (the chain extracts the values). The optional plan
+        is the normalizer's finished slice plan, copied as given; a bare
+        step is planned here by the same `plan_step`. watermarks[i] is the
+        watermark after step i. Returns one (window, count_row[K], {field:
+        row[K]}) per fired window, in fire order; row entries for keys with
+        count 0 are meaningless.
 
         defer=True returns a DeferredEmissions handle immediately after
         enqueuing the dispatch and starting the async device->host copy;
-        call .resolve() later. The next process_superbatch may be enqueued
-        before resolving (the state carry stays on device).
-        """
+        call .resolve() later. The next dispatch may be enqueued before
+        resolving (the state carry stays on device)."""
+        return self.dispatch(self.stage(steps, watermarks), defer=defer)
+
+    def stage(self, steps, watermarks, *, payload=None) -> Staged:
+        """Host planning + device staging of one dispatch (separable from
+        `dispatch` so a caller can overlap staging group i+1 with running
+        group i). The only stage.fill / stage.put sections of the package.
+
+        The host plans fires/purges from the timestamps alone (a traced
+        chain never changes timestamps and a filter only removes records,
+        so timestamp-derived slice bounds stay valid upper bounds; windows
+        planned over filtered-out slices fire empty rows, which emission
+        drops)."""
         import jax
-        import jax.numpy as jnp
 
-        if staged is not None:
-            idx_d, vals_d, plan = staged
-        else:
-            idx_d, vals_d, plan = self.stage_superbatch(batches, watermarks)
-        T = len(batches) if batches is not None else int(plan[0].shape[0])
-        if watermarks is not None:
-            assert T == len(watermarks)
-        (smin_pos, fire_pos, fire_valid, fire_row, purge_mask, fires) = plan
-
-        B = idx_d.shape[1] if idx_d.ndim == 2 else idx_d.shape[0] // T
-        if self._use_pallas():
-            from flink_tpu.ops import pallas_superscan as ps
-
-            self._to_kernel_layout()
-            run = ps.build_superscan(
-                self.agg, self.K, self.S, self.NSB, self.F, self.spw,
-                self.R, T, B, self.chunk, self.exact_sums,
-                self.pallas_interpret, fire_spws=self._fire_spws,
-            )
-            names = [f.name for f in self._value_fields]
-            idx_flat = idx_d if idx_d.ndim == 1 else idx_d.reshape(-1)
-            vals_flat = None
-            if self._needs_vals:
-                vals_flat = vals_d if vals_d.ndim == 1 else vals_d.reshape(-1)
-            count_state, field_states, count_out, field_outs = self._tracked(
-                "pallas_superscan", run,
-                (smin_pos, fire_pos, fire_valid, fire_row, purge_mask,
-                 self._count, tuple(self._state[n] for n in names),
-                 idx_flat, vals_flat),
-                {"T": T, "B": B},
-            )
-            self._count = count_state
-            self._state = dict(zip(names, field_states))
-            count_out = ps.rows_to_keys(count_out, self.R, self.K)
-            outs = {
-                n: ps.rows_to_keys(o, self.R, self.K)
-                for n, o in zip(names, field_outs)
-            }
-        else:
-            self._to_canonical()
-            # the backend decision can legitimately flip between staging and
-            # dispatch (ensure_key_capacity growth, restore); re-shape staged
-            # inputs to the layout this backend expects
-            if idx_d.ndim == 1:
-                idx_d = idx_d.reshape(T, B)
-            if self._needs_vals and vals_d.ndim == 1:
-                vals_d = vals_d.reshape(T, B)
-            Tg = self.readback_steps
-            if 0 < Tg < T and T % Tg == 0:
-                deferred = self._process_grouped(
-                    T, B, Tg, idx_d, vals_d, smin_pos, fire_pos,
-                    fire_valid, fire_row, purge_mask, fires)
-                return deferred if defer else deferred.resolve()
-            run = self._superscan(T, B)
-            outs0 = {
-                f.name: jnp.zeros((self.R, self.K), jnp.dtype(f.dtype))
-                for f in self._value_fields
-            }
-            count_out0 = jnp.zeros((self.R, self.K), jnp.int32)
-            out = self._tracked(
-                "fused_superscan", run,
-                (self._state, self._count, outs0, count_out0,
-                 idx_d, vals_d, smin_pos, fire_pos, fire_valid, fire_row,
-                 purge_mask),
-                {"T": T, "B": B},
-            )
-            if self.phase_counters:
-                self._state, self._count, outs, count_out, pc = out
-            else:
-                self._state, self._count, outs, count_out = out
-
-        count_out, outs = _used_fire_rows(count_out, outs, len(fires))
-
-        deferred = DeferredEmissions(
-            self, fires, count_out, outs,
-            phase_counts=(pc if self.phase_counters and not self._use_pallas()
-                          else None))
-        return deferred if defer else deferred.resolve()
-
-    def _process_grouped(self, T, B, Tg, idx_d, vals_d, smin_pos, fire_pos,
-                         fire_valid, fire_row, purge_mask, fires):
-        """Streaming fire readback (latency mode): run one T-step dispatch
-        as G = T/Tg chained (Tg, B) programs carrying state on device, so
-        each group's fired rows start their async device->host copy when
-        the group's scan is enqueued instead of at span completion. Fire
-        rows are planned with GLOBAL output-buffer indices across the span
-        — each group's fresh output buffer populates only its own fires'
-        rows — so resolving the per-group handles in order reproduces the
-        whole-span emission order and payloads byte-for-byte. Pow2 ladder
-        rungs make T % Tg == 0 whenever Tg fits; geometries that do not
-        divide fall through to the whole-span readback."""
-        import jax.numpy as jnp
-
-        run = self._superscan(Tg, B)
-        parts: List[DeferredEmissions] = []
-        done = 0
-        for g in range(T // Tg):
-            lo, hi = g * Tg, (g + 1) * Tg
-            outs0 = {
-                f.name: jnp.zeros((self.R, self.K), jnp.dtype(f.dtype))
-                for f in self._value_fields
-            }
-            count_out0 = jnp.zeros((self.R, self.K), jnp.int32)
-            out = self._tracked(
-                "fused_superscan", run,
-                (self._state, self._count, outs0, count_out0,
-                 idx_d[lo:hi], vals_d[lo:hi], smin_pos[lo:hi],
-                 fire_pos[lo:hi], fire_valid[lo:hi], fire_row[lo:hi],
-                 purge_mask[lo:hi]),
-                {"T": Tg, "B": B},
-            )
-            pc = None
-            if self.phase_counters:
-                self._state, self._count, outs, count_out, pc = out
-            else:
-                self._state, self._count, outs, count_out = out
-            g_fires = [pf for pf in fires if lo <= pf.step < hi]
-            done += len(g_fires)
-            # rows are assigned in fire order across the WHOLE span: the
-            # highest row this group can populate is the cumulative count
-            count_out, outs = _used_fire_rows(count_out, outs, done)
-            parts.append(DeferredEmissions(
-                self, g_fires, count_out, outs, phase_counts=pc))
-        return _StreamedEmissions(parts)
-
-    def stage_superbatch(self, batches, watermarks):
-        """Host planning + device staging for one dispatch (separable so
-        callers can overlap staging of superbatch i+1 with running i)."""
-        import jax
-        import jax.numpy as jnp
-
+        assert len(steps) == len(watermarks)
+        if payload is None:
+            payload = self._payload
+            if payload.record and not any(len(s[2]) for s in steps):
+                # watermark-only group: with zero rows the prologue is
+                # irrelevant, so it is staged as key ids (every lane dead)
+                # and `dispatch` runs the classic fire/purge program over
+                # the same device state — tracing the chained program would
+                # apply the user's column fns to a placeholder column
+                # (crashing any 2-D selector), and this also covers the
+                # restore-then-watermark ordering where the record geometry
+                # is still unknown but restored state must fire
+                payload = _KEY_IDS
         clock = self.stage_clock
         with dispatch_stage(clock, "stage.fill"):
-            T = len(batches)
-            B = max(max((len(b[2]) for b in batches), default=0), 1)
-            B = -(-B // self.chunk) * self.chunk
-
-            # np.empty: real lanes are filled below, only the pad tails are
-            # set to -1 (whole rows of empty or all-late steps)
-            idx_h = np.empty((T, B), dtype=np.int32)
-            # value-less aggregates (count) carry a [T,1] placeholder instead of
-            # shipping a dead [T,B] f32 column to the device
-            vals_h = np.zeros((T, B if self._needs_vals else 1), dtype=np.float32)
-            smin_pos = np.zeros(T, dtype=np.int32)
-            fire_pos = np.zeros((T, self.F), dtype=np.int32)
-            fire_valid = np.zeros((T, self.F), dtype=np.int32)
-            fire_row = np.zeros((T, self.F), dtype=np.int32)
-            purge_mask = np.ones((T, self.S), dtype=np.int32)
-            fires: List[_PlannedFire] = []
-
-            cur = self._cursor()
-            for t, batch in enumerate(batches):
-                kid, vals, ts = batch[:3]
-                n = len(ts)
-                live = 0
-                if n:
-                    plan = self._take_plan(
-                        batch[3] if len(batch) > 3 else None,
-                        np.asarray(ts, dtype=np.int64), cur, t, smin_pos)
-                    if plan.smin is not None:
-                        live = n
-                        row = idx_h[t, :n]
-                        kid = np.asarray(kid)
-                        np.multiply(kid, self.NSB, out=row, casting="unsafe")
-                        if isinstance(plan.srel, np.ndarray):   # else 0
-                            row += plan.srel
-                        keep = None
-                        if plan.masked:     # late records: srel -1
-                            keep = plan.srel >= 0
-                            row[~keep] = -1
-                        # kid -1 = a cold-routed record (state/tier_manager.py):
-                        # it rides the step so fires over its slices get PLANNED,
-                        # but it must never scatter into a hot row — mask to the
-                        # same -1 the ingest drops (pad-row semantics)
-                        if int(kid.min()) < 0:
-                            row[kid < 0] = -1
-                        if vals is not None and self._needs_vals:
-                            vals_h[t, :n] = (vals if keep is None
-                                             else np.where(keep, vals, 0.0))
-                idx_h[t, live:] = -1
-                cur.advance(t, watermarks[t], fire_pos, fire_valid, fire_row,
-                            purge_mask, fires)
-            cur.commit()
-
+            xs_h, lanes, layout, plan_np, fires = self._fill(
+                payload, steps, watermarks)
+            xs_h, shardings = self.deployment._place(payload, xs_h, lanes)
         with dispatch_stage(clock, "stage.put"):
-            if self._use_pallas():
-                # the fused kernel consumes flat [T*B] chunk streams; flatten on
-                # host (free: idx_h is contiguous) so no device reshape is needed
-                idx_d = jax.device_put(idx_h.reshape(-1))
-                vals_d = jax.device_put(
-                    vals_h.reshape(-1) if self._needs_vals else vals_h
-                )
-            else:
-                idx_d = jax.device_put(idx_h)
-                vals_d = jax.device_put(vals_h)
-            plan = (
-                jax.device_put(smin_pos),
-                jax.device_put(fire_pos),
-                jax.device_put(fire_valid),
-                jax.device_put(fire_row),
-                jax.device_put(purge_mask),
-                fires,
-            )
+            xs = jax.device_put(xs_h, shardings)
+            plan = jax.device_put(plan_np)
             if clock is not None:
-                clock.staged((idx_h, vals_h, smin_pos, fire_pos, fire_valid,
-                              fire_row, purge_mask),
-                             sum(len(b[2]) for b in batches))
-        return idx_d, vals_d, plan
+                clock.staged(xs_h + plan_np, sum(len(s[2]) for s in steps),
+                             payload.columns(self, layout))
+        return Staged(payload, xs, plan, fires, layout)
+
+    def _fill(self, payload, steps, watermarks):
+        """THE staging loop: each step's plan taken (or made), its lanes
+        written by the payload, the tail of its row marked dead, the
+        fire / purge plan advanced by the plan cursor. Returns (the
+        payload's host arrays, how many of them carry one entry per lane,
+        the record's ColumnLayout, the five plan arrays, fires)."""
+        T = len(steps)
+        xs_h, lanes, layout = payload.alloc(self, steps)
+        smin_pos = np.zeros(T, dtype=np.int32)
+        fire_pos = np.zeros((T, self.F), dtype=np.int32)
+        fire_valid = np.zeros((T, self.F), dtype=np.int32)
+        fire_row = np.zeros((T, self.F), dtype=np.int32)
+        purge_mask = np.ones((T, self.S), dtype=np.int32)
+        fires: List[_PlannedFire] = []
+
+        cur = self._cursor()
+        for t, step in enumerate(steps):
+            plan = step[3] if len(step) > 3 else None
+            n = len(step[2])
+            live = 0
+            if n or plan is not None:
+                plan = self._take_plan(plan, step[2], cur, t, smin_pos)
+                live = payload.write(self, xs_h, layout, t, n, step, plan)
+            if lanes:
+                # np.empty staging: only the pad tails (whole rows of empty
+                # or all-late steps) are set, to the -1 every consumer
+                # masks on before touching the other arrays
+                xs_h[0][t, live:] = -1
+            cur.advance(t, watermarks[t], fire_pos, fire_valid, fire_row,
+                        purge_mask, fires)
+        cur.commit()
+        return (xs_h, lanes, layout,
+                (smin_pos, fire_pos, fire_valid, fire_row, purge_mask), fires)
+
+    def _place(self, payload, xs_h, lanes):
+        """Placement: the host arrays as they are handed to
+        `jax.device_put`, and their shardings (None: all on the default
+        device). The pallas kernel consumes flat [T*B] lane streams;
+        flattening on host is free (the arrays are contiguous), so no
+        device reshape is needed."""
+        if self._program(payload).flat:
+            xs_h = tuple(a.reshape(-1) if i < lanes else a
+                         for i, a in enumerate(xs_h))
+        return xs_h, None
+
+    def _program(self, payload):
+        if payload.record:
+            return _CHAINED
+        return _PALLAS if self._use_pallas() else _SCAN
+
+    @property
+    def deployment(self):
+        """Who places staged arrays, holds the device state and picks the
+        program: this pipeline, or the mesh pipeline that plans through
+        it."""
+        return self if self._mesh is None else self._mesh()
+
+    def dispatch(self, staged: Staged, *, defer: bool = False):
+        """Enqueue one staged group on its compiled program and wrap the
+        fire rows it used in a DeferredEmissions.
+
+        Streaming fire readback (latency mode, `readback_steps`): the
+        T-step dispatch runs as T/Tg chained (Tg, B) programs carrying
+        state on device, so each group's fired rows start their async
+        device->host copy when the group's scan is enqueued instead of at
+        span completion. Fire rows are planned with GLOBAL output-buffer
+        indices across the span — each group's fresh output buffer
+        populates only its own fires' rows — so resolving the per-group
+        handles in order reproduces the whole-span emission order and
+        payloads byte-for-byte (and the per-group key_bounds check still
+        covers every surviving record: the groups partition the steps).
+        Pow2 ladder rungs make T % Tg == 0 whenever Tg fits; geometries
+        that do not divide, and the default, are one group."""
+        dev = self.deployment
+        prog = dev._program(staged.payload)
+        T = staged.T
+        B = prog.width(staged.xs[0], T)
+        Tg = self.readback_steps
+        if not (prog.grouped and 0 < Tg < T and T % Tg == 0):
+            Tg = T
+        run = prog.build(dev, Tg, B, staged.layout)
+        parts: List[DeferredEmissions] = []
+        done = 0
+        for lo in range(0, T, Tg):
+            # one group slices nothing: no op is added to the cycle
+            group = staged if Tg == T else staged.rows(lo, lo + Tg)
+            count_out, outs, key_bounds, pc = prog.call(
+                dev, run, group, Tg, B)
+            # rows are assigned in fire order across the WHOLE span: the
+            # highest row this group can populate is the cumulative count
+            done += len(group.fires)
+            count_out, outs = prog.fire_rows(dev, count_out, outs, done)
+            parts.append(DeferredEmissions(
+                self, group.fires, count_out, outs, key_bounds=key_bounds,
+                key_capacity=self.K, phase_counts=pc))
+        deferred = parts[0] if len(parts) == 1 else _StreamedEmissions(parts)
+        return deferred if defer else deferred.resolve()
 
     def plan_superbatch(self, slice_bounds, watermarks):
         """Host planning from per-step slice BOUNDS only — for callers that
@@ -1138,90 +1288,15 @@ class FusedWindowPipeline:
         slice_bounds: [(smin_abs, smax_abs)] per step — inclusive bounds on
         the absolute slices the step's records can occupy. The caller must
         guarantee no record falls outside its step's bounds and no record is
-        late (bounds below the live frontier raise here).
+        late (bounds below the live frontier trip the plan cursor's check).
 
-        Returns (plan, smin_abs[int32 T]) where plan is staged-plan
-        compatible: pass `staged=(idx_dev, vals_dev, plan)` to
-        process_superbatch.
-        """
-        import jax
-
-        clock = self.stage_clock
-        with dispatch_stage(clock, "stage.fill"):
-            T = len(slice_bounds)
-            assert T == len(watermarks)
-            smin_pos = np.zeros(T, dtype=np.int32)
-            smin_abs = np.zeros(T, dtype=np.int32)
-            fire_pos = np.zeros((T, self.F), dtype=np.int32)
-            fire_valid = np.zeros((T, self.F), dtype=np.int32)
-            fire_row = np.zeros((T, self.F), dtype=np.int32)
-            purge_mask = np.ones((T, self.S), dtype=np.int32)
-            fires: List[_PlannedFire] = []
-
-            cur = self._cursor()
-            for t, (smin, smax) in enumerate(slice_bounds):
-                if cur.wm > MIN_WATERMARK and smin < self._min_live_slice(cur.wm):
-                    raise ValueError(
-                        "plan_superbatch requires a late-free schedule: step "
-                        f"{t} smin={smin} is below the live frontier "
-                        f"{self._min_live_slice(cur.wm)}"
-                    )
-                cur.observe(smin, smax)
-                smin_pos[t] = smin % self.S
-                smin_abs[t] = smin
-                cur.advance(t, watermarks[t], fire_pos, fire_valid, fire_row,
-                            purge_mask, fires)
-            cur.commit()
-
-        with dispatch_stage(clock, "stage.put"):
-            plan = (
-                jax.device_put(smin_pos),
-                jax.device_put(fire_pos),
-                jax.device_put(fire_valid),
-                jax.device_put(fire_row),
-                jax.device_put(purge_mask),
-                fires,
-            )
-            if clock is not None:
-                clock.staged((smin_pos, fire_pos, fire_valid, fire_row,
-                              purge_mask))
-        return plan, smin_abs
-
-    # ------------------------------------------------------------------
-    # traced-chain path (whole-graph fusion): the chain prologue runs
-    # INSIDE the compiled superscan — raw source columns go to the device,
-    # filter/projection/key/value extraction never materialize on host
-    # ------------------------------------------------------------------
-    def stage_superbatch_raw(self, steps, watermarks):
-        """Host planning + device staging for one traced-chain dispatch.
-
-        steps: [(raw_column [n, ...], timestamps int64 [n][, StepPlan])] —
-        raw source values BEFORE any chain transform (the plan optional:
-        the normalizer's finished slice plan, copied as given; a bare step
-        is planned here by the same `plan_step`). The host plans
-        fires/purges from
-        the timestamps alone (the chain never changes timestamps, and a
-        filter only removes records, so timestamp-derived slice bounds stay
-        valid upper bounds; windows planned over filtered-out slices fire
-        empty rows, which emission drops). Late records are masked to
-        srel -1 by the plan (and counted here), so the traced program never
-        sees them as live."""
-        import jax
-
-        clock = self.stage_clock
-        with dispatch_stage(clock, "stage.fill"):
-            raw_h, srel_h, ts_h, plan_np, fires = self._stage_raw_host(
-                steps, watermarks)
-        with dispatch_stage(clock, "stage.put"):
-            plan = tuple(jax.device_put(a) for a in plan_np) + (fires,)
-            ts_d = jax.device_put(ts_h) if ts_h is not None else None
-            # raw_h: the record array, or a tuple of its staged fields
-            staged = jax.device_put(raw_h), jax.device_put(srel_h), ts_d, plan
-            if clock is not None:
-                fields_h, columns = self._record_fields(raw_h)
-                clock.staged(fields_h + (srel_h, ts_h) + plan_np,
-                             sum(len(step[1]) for step in steps), columns)
-        return staged
+        Returns (staged, smin_abs[int32 T]): the staging loop run over a
+        payload without lanes. Hand `dispatch` the result with the lanes
+        filled in: `staged._replace(xs=(idx_dev, vals_dev))`."""
+        steps = [(None, None, (), StepPlan(0, smin, smax))
+                 for smin, smax in slice_bounds]
+        staged = self.stage(steps, watermarks, payload=_BOUNDS)
+        return staged, np.array([b[0] for b in slice_bounds], dtype=np.int32)
 
     def _layout(self) -> Optional[ColumnLayout]:
         """The staged form of this stream's record (None: the record array
@@ -1230,256 +1305,6 @@ class FusedWindowPipeline:
             return None
         return self.prologue.column_layout(
             self._raw_shape, self._raw_dtype, self._needs_vals)
-
-    def _staged_layout(self, raw) -> Optional[ColumnLayout]:
-        """The layout a staged record (host or device side) was filled by."""
-        return self._layout() if isinstance(raw, tuple) else None
-
-    def _record_fields(self, raw) -> Tuple[tuple, Tuple[int, int]]:
-        """A staged record's arrays, and (fields staged, fields of the
-        record) for the stage clock's link row."""
-        layout = self._staged_layout(raw)
-        if layout is not None:
-            return raw, (len(raw), layout.width)
-        width = int(np.prod(self._raw_shape or ()))
-        return (raw,), (width, width)
-
-    @staticmethod
-    def _record_signature(raw_d, layout: Optional[ColumnLayout]) -> Dict[str, str]:
-        """The record's part of a chained dispatch's CompileTracker
-        signature."""
-        if layout is None:
-            return {"raw_dtype": str(raw_d.dtype), "columns": "record"}
-        return {"raw_dtype": layout.dtype, "columns": str(layout)}
-
-    def _stage_raw_host(self, steps, watermarks):
-        """The host half of stage_superbatch_raw: plan + fill the staging
-        buffers, but leave device placement to the caller — the sharded
-        pipeline (parallel/sharded_superscan.py) re-shapes the same buffers
-        onto mesh lanes and device_puts them with a NamedSharding instead.
-        Returns (raw_h, srel_h, ts_h|None, plan_arrays, fires); raw_h is a
-        tuple of [T, B] arrays, one per field of `_layout().columns`, for a
-        rank-1 record, and one [T, B, ...] array for any other."""
-        if self.prologue is None:
-            raise RuntimeError("stage_superbatch_raw requires a prologue")
-        T = len(steps)
-        B = max(max((len(step[1]) for step in steps), default=0), 1)
-        # staged width quantized to power-of-two multiples of the chunk:
-        # ragged last batches and watermark-only tail groups land on a few
-        # bounded shapes (log2 many) instead of compiling a fresh (T, B)
-        # executable per width, while tiny tails keep tiny staging buffers
-        # — pad rows are srel -1 and never touch state
-        B = self.chunk * (1 << max(0, -(-B // self.chunk) - 1).bit_length())
-
-        for raw, ts, *_rest in steps:
-            if not len(ts):
-                continue
-            arr = np.asarray(raw)
-            if self._raw_shape is None:
-                if arr.dtype == object:
-                    raise TypeError(
-                        "the fused device chain needs numeric record "
-                        "columns; this source yields Python objects — use a "
-                        "columnar source (numeric ndarray batches) or drop "
-                        "traceable=True to stay on the host chain"
-                    )
-                self._raw_shape, self._raw_dtype = arr.shape[1:], arr.dtype
-            elif arr.shape[1:] != self._raw_shape or arr.dtype != self._raw_dtype:
-                raise ValueError(
-                    f"record column geometry changed mid-stream: "
-                    f"{arr.dtype}{list(arr.shape[1:])} after "
-                    f"{self._raw_dtype}{list(self._raw_shape)} — the fused "
-                    "chain executable is shaped on a fixed column layout"
-                )
-        raw_shape, raw_dtype = self._raw_shape, self._raw_dtype
-        if raw_shape is None:
-            # all-empty superbatch before any data: a scalar placeholder
-            # column for THIS dispatch only — pinning it on the instance
-            # would make the first real batch afterwards (e.g. a watermark
-            # arriving right after restore) read as a mid-stream geometry
-            # change and crash a healthy job
-            raw_shape, raw_dtype = (), np.dtype(np.float32)
-
-        # np.empty, not zeros: pad rows are srel -1 — every traced consumer
-        # masks on that before touching raw/ts, so the 16MB+ staging memset
-        # per dispatch would be pure waste. Buffers are allocated in jax's
-        # CANONICAL dtype (x64-off: float64→float32, int64→int32): device_put
-        # of a non-canonical array re-casts the whole buffer host-side every
-        # dispatch — a full extra copy, and the garbage pad bytes overflow
-        # the narrowing float cast (RuntimeWarning). Real rows cast at fill.
-        from jax import dtypes as _jdt
-        layout = self._layout()
-        if layout is None:
-            raw_h = np.empty((T, B) + raw_shape,
-                             dtype=_jdt.canonicalize_dtype(raw_dtype))
-        else:
-            raw_h = tuple(np.empty((T, B), dtype=layout.dtype)
-                          for _c in layout.columns)
-        # np.empty too: each step's lanes take its plan's srel, only the pad
-        # tails (and whole rows of empty steps) are set to -1
-        srel_h = np.empty((T, B), dtype=np.int32)
-        ts_h = (np.empty((T, B), dtype=_jdt.canonicalize_dtype(np.int64))
-                if self.prologue.needs_ts else None)
-        smin_pos = np.zeros(T, dtype=np.int32)
-        fire_pos = np.zeros((T, self.F), dtype=np.int32)
-        fire_valid = np.zeros((T, self.F), dtype=np.int32)
-        fire_row = np.zeros((T, self.F), dtype=np.int32)
-        purge_mask = np.ones((T, self.S), dtype=np.int32)
-        fires: List[_PlannedFire] = []
-
-        cur = self._cursor()
-        for t, step in enumerate(steps):
-            raw, ts = step[0], step[1]
-            n = len(ts)
-            srel_h[t, n:] = -1
-            if n:
-                ts_arr = np.asarray(ts, dtype=np.int64)
-                plan = self._take_plan(step[2] if len(step) > 2 else None,
-                                       ts_arr, cur, t, smin_pos)
-                srel_h[t, :n] = plan.srel
-                # checked canonical cast: an int64/float64 source column
-                # narrowing into the staging dtype must not silently wrap
-                # (same contract as the timestamp guard below); the host
-                # fallback casts through the same helper, so both paths
-                # compute on identical canonical inputs. With a layout
-                # the check covers the staged fields: a field no traced
-                # function reads never reaches one
-                if layout is None:
-                    raw_h[t, :n] = canonical_column(
-                        raw, "fused chain record column")
-                else:
-                    rec = np.asarray(raw)
-                    for field_h, c in zip(raw_h, layout.columns):
-                        field_h[t, :n] = canonical_column(
-                            rec[:, c], f"fused chain record column {c}")
-                if ts_h is not None:
-                    if ts_h.dtype.itemsize < 8 and (
-                        int(ts_arr.max()) > np.iinfo(ts_h.dtype).max
-                        or int(ts_arr.min()) < np.iinfo(ts_h.dtype).min
-                    ):
-                        raise TypeError(
-                            "traceable map_with_timestamp under the fused "
-                            "chain stages timestamps in the backend's "
-                            f"canonical {ts_h.dtype} (jax x64 is disabled) "
-                            "and these event timestamps do not fit — they "
-                            "would silently wrap inside the traced UDF. "
-                            "Rebase event time near zero, enable jax x64, "
-                            "or drop traceable=True to run the host chain."
-                        )
-                    ts_h[t, :n] = ts_arr
-            cur.advance(t, watermarks[t], fire_pos, fire_valid, fire_row,
-                        purge_mask, fires)
-        cur.commit()
-
-        return (raw_h, srel_h, ts_h,
-                (smin_pos, fire_pos, fire_valid, fire_row, purge_mask), fires)
-
-    def process_superbatch_raw(self, steps, watermarks, *,
-                               staged: Optional[tuple] = None,
-                               defer: bool = False):
-        """Run T traced-chain steps in one dispatch (the prologue-bearing
-        sibling of process_superbatch; same defer contract)."""
-        import jax.numpy as jnp
-
-        if staged is None and all(len(step[1]) == 0 for step in steps):
-            # watermark-only dispatch: with zero rows the prologue is
-            # irrelevant, so run the classic (prologue-free) fire/purge
-            # program over the same device state — tracing the chained
-            # program would apply the user's column fns to a placeholder
-            # scalar column (crashing any 2-D selector), and this also
-            # covers the restore-then-watermark ordering where the record
-            # geometry is still unknown but restored state must fire
-            empty = [(np.empty(0, np.int32), None, np.empty(0, np.int64))
-                     for _ in steps]
-            return self.process_superbatch(empty, watermarks, defer=defer)
-        if staged is None:
-            staged = self.stage_superbatch_raw(steps, watermarks)
-        raw_d, srel_d, ts_d, plan = staged
-        (smin_pos, fire_pos, fire_valid, fire_row, purge_mask, fires) = plan
-        T, B = srel_d.shape
-
-        self._to_canonical()
-        Tg = self.readback_steps
-        if 0 < Tg < T and T % Tg == 0:
-            deferred = self._process_grouped_raw(
-                T, B, Tg, raw_d, srel_d, ts_d, smin_pos, fire_pos,
-                fire_valid, fire_row, purge_mask, fires)
-            return deferred if defer else deferred.resolve()
-        layout = self._staged_layout(raw_d)
-        run = self._chained_superscan(T, B, layout)
-        outs0 = {
-            f.name: jnp.zeros((self.R, self.K), jnp.dtype(f.dtype))
-            for f in self._value_fields
-        }
-        count_out0 = jnp.zeros((self.R, self.K), jnp.int32)
-        xs = (raw_d, srel_d)
-        if self.prologue.needs_ts:
-            xs = xs + (ts_d,)
-        xs = xs + (smin_pos, fire_pos, fire_valid, fire_row, purge_mask)
-        out = self._tracked(
-            "fused_chained_superscan", run,
-            (self._state, self._count, outs0, count_out0) + xs,
-            {"T": T, "B": B, **self._record_signature(raw_d, layout)},
-        )
-        pc = None
-        if self.phase_counters:
-            self._state, self._count, outs, count_out, key_bounds, pc = out
-        else:
-            self._state, self._count, outs, count_out, key_bounds = out
-
-        count_out, outs = _used_fire_rows(count_out, outs, len(fires))
-        deferred = DeferredEmissions(self, fires, count_out, outs,
-                                     key_bounds=key_bounds,
-                                     key_capacity=self.K,
-                                     phase_counts=pc)
-        return deferred if defer else deferred.resolve()
-
-    def _process_grouped_raw(self, T, B, Tg, raw_d, srel_d, ts_d, smin_pos,
-                             fire_pos, fire_valid, fire_row, purge_mask,
-                             fires):
-        """Streaming fire readback for the traced-chain path — the
-        _process_grouped contract (global fire rows, per-group async copy,
-        byte-identical resolution order) over the chained executable; the
-        per-group key_bounds check still covers every surviving record
-        because the groups partition the span's steps."""
-        import jax.numpy as jnp
-
-        layout = self._staged_layout(raw_d)
-        run = self._chained_superscan(Tg, B, layout)
-        needs_ts = self.prologue.needs_ts
-        parts: List[DeferredEmissions] = []
-        done = 0
-        for g in range(T // Tg):
-            lo, hi = g * Tg, (g + 1) * Tg
-            outs0 = {
-                f.name: jnp.zeros((self.R, self.K), jnp.dtype(f.dtype))
-                for f in self._value_fields
-            }
-            count_out0 = jnp.zeros((self.R, self.K), jnp.int32)
-            raw_g = (tuple(f[lo:hi] for f in raw_d) if layout is not None
-                     else raw_d[lo:hi])
-            xs = (raw_g, srel_d[lo:hi])
-            if needs_ts:
-                xs = xs + (ts_d[lo:hi],)
-            xs = xs + (smin_pos[lo:hi], fire_pos[lo:hi], fire_valid[lo:hi],
-                       fire_row[lo:hi], purge_mask[lo:hi])
-            out = self._tracked(
-                "fused_chained_superscan", run,
-                (self._state, self._count, outs0, count_out0) + xs,
-                {"T": Tg, "B": B, **self._record_signature(raw_d, layout)},
-            )
-            pc = None
-            if self.phase_counters:
-                self._state, self._count, outs, count_out, key_bounds, pc = out
-            else:
-                self._state, self._count, outs, count_out, key_bounds = out
-            g_fires = [pf for pf in fires if lo <= pf.step < hi]
-            done += len(g_fires)
-            count_out, outs = _used_fire_rows(count_out, outs, done)
-            parts.append(DeferredEmissions(
-                self, g_fires, count_out, outs, key_bounds=key_bounds,
-                key_capacity=self.K, phase_counts=pc))
-        return _StreamedEmissions(parts)
 
     def _chained_superscan(self, T: int, B: int,
                            layout: Optional[ColumnLayout] = None):
@@ -1616,6 +1441,108 @@ def _used_fire_rows(count_out, outs, fired: int, axis: int = 0):
         outs = {k: _slice_rows(v, used, axis) for k, v in outs.items()}
     return count_out, outs
 
+
+class _ChipProgram:
+    """One compiled window program of a single chip, as `dispatch` uses it
+    (`p` is the pipeline that holds the device state): `name` (the
+    CompileTracker program; the device module is jit_run_<name>), `flat`
+    (it reads [T*B] lane streams, not [T, B]), `grouped` (latency mode may
+    run it per step group), `build`, `call`."""
+
+    flat = False
+    grouped = False
+
+    def width(self, a, T: int) -> int:
+        """Lanes per step, read off a staged lane array."""
+        return a.shape[1] if a.ndim >= 2 else a.shape[0] // T
+
+    def _lanes(self, p, staged: Staged, T: int, B: int):
+        """The key-id lanes in this program's form. The backend decision
+        can legitimately flip between staging and dispatch
+        (ensure_key_capacity growth, restore): re-shape then."""
+        idx, vals = staged.xs
+        shape = (T * B,) if self.flat else (T, B)
+        if idx.shape != shape:
+            idx = idx.reshape(shape)
+        if p._needs_vals and vals.shape != shape:
+            vals = vals.reshape(shape)
+        return idx, vals
+
+    def fire_rows(self, p, count_out, outs, fired: int):
+        return _used_fire_rows(count_out, outs, fired)
+
+
+class _ScanProgram(_ChipProgram):
+    """The XLA superscan: `fused_superscan` over key ids, or with the
+    traced prologue inside the scan `fused_chained_superscan` over a
+    record. States in canonical [K, S] form; zeroed [R, K] fire buffers go
+    in, (state, count, outs, count_out[, key_bounds][, phase counts]) comes
+    out."""
+
+    grouped = True
+
+    def __init__(self, chained: bool):
+        self.chained = chained
+        self.name = "fused_chained_superscan" if chained else "fused_superscan"
+
+    def build(self, p, T: int, B: int, layout):
+        p._to_canonical()
+        if self.chained:
+            return p._chained_superscan(T, B, layout)
+        return p._superscan(T, B)
+
+    def call(self, p, run, staged: Staged, T: int, B: int):
+        import jax.numpy as jnp
+
+        xs, record_sig = (staged.scan_xs() if self.chained
+                          else (self._lanes(p, staged, T, B), {}))
+        outs0 = {
+            f.name: jnp.zeros((p.R, p.K), jnp.dtype(f.dtype))
+            for f in p._value_fields
+        }
+        count_out0 = jnp.zeros((p.R, p.K), jnp.int32)
+        p._state, p._count, outs, count_out, *tail = p._tracked(
+            self.name, run,
+            (p._state, p._count, outs0, count_out0) + xs + staged.plan,
+            {"T": T, "B": B, **record_sig})
+        key_bounds = tail.pop(0) if self.chained else None
+        return count_out, outs, key_bounds, tail[0] if tail else None
+
+
+class _PallasProgram(_ChipProgram):
+    """The fused pallas kernel (ops/pallas_superscan.py): key ids only,
+    states in its slice-major kernel layout, flat [T*B] lane streams, the
+    plan first; fire rows come back in kernel form (`rows_to_keys`)."""
+
+    name = "pallas_superscan"
+    flat = True
+
+    def build(self, p, T: int, B: int, layout):
+        from flink_tpu.ops import pallas_superscan as ps
+
+        p._to_kernel_layout()
+        return ps.build_superscan(
+            p.agg, p.K, p.S, p.NSB, p.F, p.spw, p.R, T, B, p.chunk,
+            p.exact_sums, p.pallas_interpret, fire_spws=p._fire_spws,
+        )
+
+    def call(self, p, run, staged: Staged, T: int, B: int):
+        from flink_tpu.ops import pallas_superscan as ps
+
+        names = [f.name for f in p._value_fields]
+        idx, vals = self._lanes(p, staged, T, B)
+        p._count, field_states, count_out, field_outs = p._tracked(
+            self.name, run,
+            staged.plan + (p._count, tuple(p._state[n] for n in names),
+                           idx, vals if p._needs_vals else None),
+            {"T": T, "B": B})
+        p._state = dict(zip(names, field_states))
+        outs = {n: ps.rows_to_keys(o, p.R, p.K)
+                for n, o in zip(names, field_outs)}
+        return ps.rows_to_keys(count_out, p.R, p.K), outs, None, None
+
+
+_SCAN, _CHAINED, _PALLAS = _ScanProgram(False), _ScanProgram(True), _PallasProgram()
 
 #: the per-step ingest/fire/purge body now lives in ops/superscan.py (a
 #: pure device-kernel builder, importable from `parallel/` without a
@@ -1980,31 +1907,28 @@ class FusedGlobalWindowPipeline:
                                        or self.pallas_interpret)
         return self._pallas
 
-    def plan_superbatch(self, slice_bounds, watermarks):
-        return self._planner.plan_superbatch(slice_bounds, watermarks)
+    def process_superbatch(self, steps, watermarks, *, defer: bool = False):
+        return self.dispatch(self._planner.stage(steps, watermarks),
+                             defer=defer)
 
-    def stage_superbatch(self, batches, watermarks):
-        return self._planner.stage_superbatch(batches, watermarks)
-
-    def process_superbatch(self, batches, watermarks, *, staged=None,
-                           defer: bool = False):
-        import jax
+    def dispatch(self, staged: Staged, *, defer: bool = False):
+        """The planner's `stage` / `plan_superbatch` (reached through
+        `__getattr__`) stage for this dispatch as for the keyed one."""
         import jax.numpy as jnp
 
         from flink_tpu.ops.aggregators import scan_identity
 
-        if staged is None:
-            staged = self._planner.stage_superbatch(batches, watermarks)
-        idx_d, vals_d, plan = staged
-        (smin_pos, fire_pos, fire_valid, fire_row, purge_mask, fires) = plan
-        T = int(smin_pos.shape[0])
+        idx_d, vals_d = staged.xs
+        smin_pos, fire_pos, fire_valid, fire_row, purge_mask = staged.plan
+        fires = staged.fires
+        T = staged.T
         B = idx_d.shape[1] if idx_d.ndim == 2 else idx_d.shape[0] // T
         names = [f.name for f in self._value_fields]
 
         use_pallas = self._use_pallas()
         CH = self.chunk
         if use_pallas:
-            # staged inputs are chunk-padded (stage_superbatch), so CH stays
+            # staged inputs are chunk-padded (the planner's `stage`), so CH stays
             # self.chunk; externally staged widths halve down to the largest
             # divisor. A width the kernel cannot chunk (below MIN_CHUNK)
             # falls back to the XLA scan for THIS dispatch — identical

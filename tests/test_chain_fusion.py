@@ -561,12 +561,12 @@ def test_all_empty_superbatch_does_not_pin_geometry():
         TumblingEventTimeWindows.of(1_000), "count",
         key_capacity=8, prologue=pro,
     )
-    empty = (np.empty((0, 2), np.float32), np.empty(0, np.int64))
-    pipe.process_superbatch_raw([empty, empty], [11_000, 12_000])
+    empty = (np.empty((0, 2), np.float32), None, np.empty(0, np.int64))
+    pipe.process_superbatch([empty, empty], [11_000, 12_000])
     assert pipe._raw_shape is None
-    data = (np.asarray([[1.0, 0.0]], np.float32),
+    data = (np.asarray([[1.0, 0.0]], np.float32), None,
             np.asarray([13_000], np.int64))
-    pipe.process_superbatch_raw([data, empty], [13_000, 13_500])  # no raise
+    pipe.process_superbatch([data, empty], [13_000, 13_500])  # no raise
     assert pipe._raw_shape == (2,)
 
 
@@ -591,8 +591,7 @@ def test_wide_integer_columns_raise_instead_of_wrapping():
         key_capacity=8, prologue=pro,
     )
     with pytest.raises(TypeError, match="would silently wrap"):
-        pipe.stage_superbatch_raw(
-            [(big, np.asarray([10_000], np.int64))], [10_000])
+        pipe.stage([(big, None, np.asarray([10_000], np.int64))], [10_000])
 
     # in-range wide columns narrow cleanly (and floats guard overflow)
     ok = canonical_column(np.asarray([[7]], np.int64), "x")
@@ -620,10 +619,10 @@ def test_epoch_scale_timestamps_with_traced_map_ts_raise_loudly():
         key_capacity=8, prologue=pro,
     )
     epoch_ms = 1_760_000_000_000  # far beyond int32
-    step = (np.asarray([[1.0, 0.0]], np.float32),
+    step = (np.asarray([[1.0, 0.0]], np.float32), None,
             np.asarray([epoch_ms], np.int64))
     with pytest.raises(TypeError, match="do not fit"):
-        pipe.stage_superbatch_raw([step], [epoch_ms])
+        pipe.stage([step], [epoch_ms])
 
 
 # ---------------------------------------------------------------------------
@@ -821,7 +820,7 @@ def _count_by_key(rec, keep, key_col=5):
 
 
 def _pipeline_counts(pipe, rec, ts):
-    rows = pipe.process_superbatch_raw([(rec, ts)], [int(ts.max()) + 5_000])
+    rows = pipe.process_superbatch([(rec, None, ts)], [int(ts.max()) + 5_000])
     total = np.zeros(pipe.K, np.int64)
     for _window, counts, _fields in rows:
         total += counts
@@ -878,7 +877,8 @@ def test_narrowing_check_covers_the_staged_fields_only():
     reads_5 = TracedPrologue(transforms=(), key_fn=_key5)
     pipe = FusedWindowPipeline(TumblingEventTimeWindows.of(1_000), "count",
                                prologue=reads_5, **geom)
-    raw_h, *_ = pipe._stage_raw_host([(rec, ts)], [10_000])
+    (_srel_h, *raw_h), *_ = pipe._fill(pipe._payload, [(rec, None, ts)],
+                                       [10_000])
     assert [a.dtype for a in raw_h] == [np.int32] and raw_h[0].shape[0] == 1
     np.testing.assert_array_equal(_pipeline_counts(
         FusedWindowPipeline(TumblingEventTimeWindows.of(1_000), "count",
@@ -889,7 +889,7 @@ def test_narrowing_check_covers_the_staged_fields_only():
     wide = FusedWindowPipeline(TumblingEventTimeWindows.of(1_000), "count",
                                prologue=reads_3, **geom)
     with pytest.raises(TypeError, match="column 3.*would silently wrap"):
-        wide.stage_superbatch_raw([(rec, ts)], [10_000])
+        wide.stage([(rec, None, ts)], [10_000])
 
 
 # ---------------------------------------------------------------------------
@@ -965,17 +965,23 @@ def _caller_latency_grouped_readback():
     op = _raw_op(latency=LatencySpec(target_ms=50, max_inflight=2,
                                      readback_steps=2))
     op._controller = Pinned()
+    from flink_tpu.runtime import fused_window_pipeline as fwp
+
     grouped = []
-    inner = op.pipe._process_grouped_raw
+    inner = fwp._CHAINED.call
 
-    def spy(T, B, Tg, raw_d, *rest):
-        grouped.append((Tg, len(raw_d), raw_d[0].shape == (T, B)))
-        return inner(T, B, Tg, raw_d, *rest)
+    def spy(pipe, run, group, Tg, B):
+        (raw_d, _srel_d), _signature = group.scan_xs()
+        grouped.append((Tg, len(raw_d), raw_d[0].shape == (Tg, B)))
+        return inner(pipe, run, group, Tg, B)
 
-    op.pipe._process_grouped_raw = spy
-    out = _feed(op, _seven_field_stream(), lambda o: o.drain_output())
-    op.process_watermark(MAX_WATERMARK - 1)
-    out.extend(op.drain_output())
+    fwp._CHAINED.call = spy      # the one chained program of the process
+    try:
+        out = _feed(op, _seven_field_stream(), lambda o: o.drain_output())
+        op.process_watermark(MAX_WATERMARK - 1)
+        out.extend(op.drain_output())
+    finally:
+        del fwp._CHAINED.call
     assert len(grouped) > 2 and set(grouped) == {(2, 3, True)}
     return _rows(out), _expected_sums(1000)
 

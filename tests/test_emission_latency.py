@@ -6,6 +6,7 @@ Prometheus summary export (observability.emission-latency.*)."""
 import math
 
 import numpy as np
+import pytest
 
 from flink_tpu.metrics.emission_latency import (
     LATENCY_SPAN_NAME,
@@ -240,6 +241,27 @@ def test_stall_attribution_largest_overlap_wins():
     assert set(rep["attributed"]) == {"recovery.JobRestart"}
     blk = rep["attributed"]["recovery.JobRestart"]
     assert blk["count"] == 1 and blk["maxLatencyMs"] == 100.0
+
+
+@pytest.mark.parametrize("checkpoint_ms", [5.0, 12.0, 40.0])
+def test_a_stall_that_overlaps_a_restart_is_the_restarts(checkpoint_ms):
+    """The join-restore race (chaos/scenarios.py): the post-restore stall
+    opens mid-restart and also spans the restored job's first checkpoint;
+    whichever of the two spans lasted longer used to own it."""
+    spans = [
+        _span("recovery", "JobRestart", 1000.0, 1013.0),
+        _span("checkpointing", "Checkpoint", 1013.5, 1013.5 + checkpoint_ms),
+        _span(LATENCY_SPAN_SCOPE, LATENCY_SPAN_NAME, 1004.0, 1060.0,
+              latencyMs=56.0),
+    ]
+    rep = stall_attribution(spans)
+    assert set(rep["attributed"]) == {"recovery.JobRestart"}
+    # a restart the stall only comes near (inside the slack) has no such
+    # claim: there the larger overlap still decides
+    spans[0] = _span("recovery", "JobRestart", 990.0, 1003.0)
+    owner = "checkpointing.Checkpoint" if checkpoint_ms > 13.0 \
+        else "recovery.JobRestart"
+    assert set(stall_attribution(spans)["attributed"]) == {owner}
 
 
 def test_stall_attribution_unattributed_and_slack():

@@ -62,6 +62,27 @@ def test_cli_gate_matches_engine():
     assert lint_main([str(PKG), "--baseline", str(BASELINE)]) == EXIT_CLEAN
 
 
+def test_the_one_staging_path_is_where_arch003_looks():
+    """ARCH003 allows at most one site per section and reads one operator
+    file: the real tree must hold exactly one of each, in the pipeline's
+    `stage`, and the operator must hand its groups to `process_superbatch`
+    — or the rule passes on a tree that lost the path it guards."""
+    import re
+
+    from flink_tpu.lint.rules_architecture import (
+        ONE_SITE_STAGES,
+        WINDOW_OPERATOR,
+    )
+
+    for name in ONE_SITE_STAGES:
+        sites = [p.relative_to(PKG).as_posix() for p in PKG.rglob("*.py")
+                 if re.search(rf'dispatch_stage\([^)]*"{name}"\)',
+                              p.read_text())]
+        assert sites == ["runtime/fused_window_pipeline.py"], (name, sites)
+    op = (PKG / WINDOW_OPERATOR).read_text()
+    assert op.count("self.pipe.process_superbatch(") == 1
+
+
 def test_path_scoped_rules_are_not_vacuous():
     """Every path the rules are configured against must exist in the real
     package — otherwise a rename silently disables the rule and it passes

@@ -3,12 +3,12 @@
 Runs every registered rule individually over the real tree (one shared
 ModuleIndex, like the engine), recording per-rule wall clock:
 
-- **Coverage**: exactly 16 rules registered, every one exercised here and
+- **Coverage**: exactly 17 rules registered, every one exercised here and
   zero ACTIVE violations per rule against the checked-in baseline (the
   per-rule split means a regression names the rule, not just "lint
   failed").
-- **Budget**: the 16-rule run must stay under a pinned multiple of the
-  13 pre-EXON rules' time on the same machine/index — the interprocedural
+- **Budget**: the 17-rule run must stay under a pinned multiple of the
+  14 pre-EXON rules' time on the same machine/index — the interprocedural
   dataflow layer (summaries + fault fixpoint, shared across the three
   EXON rules via DataflowIndex.shared) must never quietly turn the lint
   gate into the slowest test in tier-1. Failure messages carry the
@@ -28,7 +28,7 @@ from flink_tpu.lint import Baseline, all_rules
 PKG = pathlib.Path(flink_tpu.__file__).parent
 BASELINE = PKG.parent / "lint_baseline.json"
 
-#: the 16-rule run may cost at most this multiple of the 13 pre-existing
+#: the 17-rule run may cost at most this multiple of the 14 pre-existing
 #: rules' time (measured on the same index in the same process, so the
 #: ratio is machine-independent); the floor keeps a near-zero denominator
 #: from flaking the assert on very fast machines
@@ -59,9 +59,9 @@ def _timing_table(times):
         for rid, t in sorted(times.items(), key=lambda kv: -kv[1]))
 
 
-def test_registry_holds_exactly_16_rules():
+def test_registry_holds_exactly_17_rules():
     ids = sorted(r.id for r in all_rules())
-    assert len(ids) == 16, ids
+    assert len(ids) == 17, ids
     assert [i for i in ids if i.startswith("EXON")] == \
         ["EXON001", "EXON002", "EXON003"]
 
@@ -90,8 +90,8 @@ def test_full_run_within_time_budget(timed_run):
     full = sum(times.values())
     budget = max(BUDGET_MULTIPLE * pre, BUDGET_FLOOR_S)
     assert full <= budget, (
-        f"full 16-rule lint took {full:.2f}s — over budget "
-        f"({BUDGET_MULTIPLE}x the 13 pre-EXON rules' {pre:.2f}s = "
+        f"full 17-rule lint took {full:.2f}s — over budget "
+        f"({BUDGET_MULTIPLE}x the 14 pre-EXON rules' {pre:.2f}s = "
         f"{budget:.2f}s). Per-rule timing (slowest first):\n"
         f"{_timing_table(times)}")
 
@@ -104,7 +104,7 @@ def test_bench_stamp_reports_the_same_verdict():
     info = bench.lint_summary()
     assert set(info) == {"modules", "rules", "violations", "analysis_ms"}, (
         f"lint stamp shape drifted (or the run errored): {info}")
-    assert info["rules"] == 16
+    assert info["rules"] == 17
     assert info["violations"] == 0, (
         f"bench stamp sees active violations the gate missed: {info}")
     assert info["modules"] > 100

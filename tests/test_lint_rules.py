@@ -811,6 +811,59 @@ def test_arch002_clean_callback_flow(tmp_path):
 
 
 # ---------------------------------------------------------------------------
+# ARCH003 one-staging-path
+# ---------------------------------------------------------------------------
+
+_ONE_STAGE = """
+    def stage(pipe, clock, steps):
+        with dispatch_stage(clock, "stage.fill"):
+            host = pipe.fill(steps)
+        with task_io.dispatch_stage(clock, "stage.put"):
+            return pipe.put(host)
+"""
+
+
+def test_arch003_flags_a_second_staging_site_and_a_prologue_branch(tmp_path):
+    vs = run_rule("ARCH003", tmp_path, {
+        "runtime/pipeline.py": _ONE_STAGE,
+        "parallel/sharded.py": """
+            def stage_on_mesh(pipe, clock, steps):
+                with dispatch_stage(clock, "stage.put"):
+                    return pipe.put(pipe.deal(steps))
+        """,
+        "runtime/fused_window_operator.py": """
+            class Op:
+                def _dispatch(self, group):
+                    d = (self.pipe.run_records(group) if self.prologue
+                         else self.pipe.run_key_ids(group))
+                    return d
+        """})
+    assert sorted(v.symbol for v in vs) == [
+        "pipe.run_key_ids", "pipe.run_records", "stage:stage.put"]
+    second = next(v for v in vs if v.symbol == "stage:stage.put")
+    # the first site in path order stays; the other is the violation
+    assert second.path.endswith("runtime/pipeline.py")
+    assert "parallel/sharded.py" in second.message
+
+
+def test_arch003_clean_one_loop_and_other_prologue_branches(tmp_path):
+    vs = run_rule("ARCH003", tmp_path, {
+        "runtime/pipeline.py": _ONE_STAGE,
+        "runtime/fused_window_operator.py": """
+            class Op:
+                def _dispatch(self, group):
+                    with dispatch_stage(self.clock, "dispatch"):
+                        return self.pipe.process_superbatch(group)
+
+                def _emit(self, window, rows):
+                    if self.prologue is not None:
+                        return self._emit_dense_rows(window, rows)
+                    return self._emit_keydict_rows(window, rows)
+        """})
+    assert vs == []
+
+
+# ---------------------------------------------------------------------------
 # DOC001 config-docs-complete
 # ---------------------------------------------------------------------------
 

@@ -98,7 +98,7 @@ GEOM = dict(key_capacity=K, num_slices=128, nsb=4, fires_per_step=4,
 
 
 def _dispatches(seed=3):
-    """[(records [BATCH, 3] f32: key, value, flag; ts)] per step, per
+    """[(records [BATCH, 3] f32: key, value, flag; None; ts)] per step, per
     dispatch; every record lies ahead of every watermark."""
     rng = np.random.default_rng(seed)
     out = []
@@ -110,7 +110,7 @@ def _dispatches(seed=3):
                             rng.integers(0, 2, BATCH)], axis=1)
             t0 = (d * STEPS + s) * STEP_MS
             ts = (t0 + rng.integers(0, STEP_MS, BATCH)).astype(np.int64)
-            steps.append((rec.astype(np.float32), ts))
+            steps.append((rec.astype(np.float32), None, ts))
         out.append(steps)
     return out
 
@@ -125,9 +125,9 @@ def _prologue(aggregate):
 def _feed(pipe, steps, wms, raw: bool):
     """One deferred dispatch through the path under test."""
     if raw:
-        return pipe.process_superbatch_raw(steps, wms, defer=True)
+        return pipe.process_superbatch(steps, wms, defer=True)
     batches = [(rec[:, 0].astype(np.int32), rec[:, 1], ts)
-               for rec, ts in steps]
+               for rec, _none, ts in steps]
     return pipe.process_superbatch(batches, wms, defer=True)
 
 
@@ -184,7 +184,7 @@ def test_the_deal_has_a_stage_of_its_own_and_the_link_counts_used_rows():
     clock = StageClock()
     sharded.attach_stage_clock(clock)
     for steps, wms in zip(_dispatches(), WATERMARKS):
-        d = sharded.process_superbatch_raw(steps, wms, defer=True)
+        d = sharded.process_superbatch(steps, wms, defer=True)
         d.resolve()
         clock.d2h_bytes += d.nbytes         # as `_resolve_oldest` counts it
     n = len(FIRES)

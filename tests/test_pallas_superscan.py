@@ -186,7 +186,7 @@ def test_pipeline_snapshot_crosses_backends():
 
 def test_plan_superbatch_matches_staged():
     """The analytic planner + caller-staged idx produce the same emissions as
-    the data-driven stage_superbatch on an identical stream."""
+    the data-driven `stage` on an identical stream."""
     import jax
     import jax.numpy as jnp
 
@@ -215,7 +215,7 @@ def test_plan_superbatch_matches_staged():
     ref_pipe, gen_pipe = mk(), mk()
     ref = ref_pipe.process_superbatch(batches, wms)
 
-    plan, smin_abs = gen_pipe.plan_superbatch(bounds, wms)
+    staged, smin_abs = gen_pipe.plan_superbatch(bounds, wms)
     idx_rows = []
     for t, (keys, _v, ts) in enumerate(batches):
         srel = (ts // SLIDE - smin_abs[t]).astype(np.int32)
@@ -223,7 +223,7 @@ def test_plan_superbatch_matches_staged():
         idx_rows.append(keys.astype(np.int32) * 4 + srel)
     idx_flat = jax.device_put(np.concatenate(idx_rows))
     vals_d = jnp.zeros((steps, 1), jnp.float32)
-    got = gen_pipe.process_superbatch(None, None, staged=(idx_flat, vals_d, plan))
+    got = gen_pipe.dispatch(staged._replace(xs=(idx_flat, vals_d)))
 
     assert len(ref) == len(got) and len(ref) > 0
     for (rw, rc, _), (gw, gc, _) in zip(ref, got):

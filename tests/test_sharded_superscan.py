@@ -247,10 +247,11 @@ def test_sharded_traced_chain_stages_per_field(n_dev, num_keys, aggregate,
     sharded = ShardedFusedPipeline(_mesh(n_dev), assigner, aggregate,
                                    local_combine=combine, **geom)
     steps, wms = seven_field_stream(8, 600, num_keys, seed=5)
+    steps = [(rec, None, ts) for rec, ts in steps]
     read = (1, 2, 5) if aggregate == "sum" else (2, 5)
 
-    staged = sharded.stage_superbatch_raw(steps[:4], wms[:4])
-    fields_d, srel_d = staged[0], staged[1]
+    staged = sharded.stage(steps[:4], wms[:4])
+    (fields_d, srel_d), _signature = staged.scan_xs()
     Bs = -(-1024 // n_dev)
     assert sharded._planner._layout().columns == read
     assert [f.shape for f in fields_d] == [(n_dev, 4, Bs)] * len(read)
@@ -261,10 +262,10 @@ def test_sharded_traced_chain_stages_per_field(n_dev, num_keys, aggregate,
     assert (np.asarray(srel_d).transpose(1, 0, 2).reshape(4, -1)[:, 600:]
             == -1).all()
 
-    got = sharded.process_superbatch_raw(steps[:4], wms[:4], staged=staged)
-    got += sharded.process_superbatch_raw(steps[4:], wms[4:])
-    ref = single.process_superbatch_raw(steps[:4], wms[:4])
-    ref += single.process_superbatch_raw(steps[4:], wms[4:])
+    got = sharded.dispatch(staged)
+    got += sharded.process_superbatch(steps[4:], wms[4:])
+    ref = single.process_superbatch(steps[:4], wms[:4])
+    ref += single.process_superbatch(steps[4:], wms[4:])
     ref, got = _norm(ref), _norm(got)
     assert len(ref) == len(got) > 0
     for (rs, rc, rf), (gs, gc, gf) in zip(ref, got):

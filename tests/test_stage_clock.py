@@ -384,7 +384,7 @@ def test_link_row_counts_the_columns_staged(mesh, reads_all, staged):
     rec = rng.randint(0, 2, (n, 7)).astype(np.float32)
     rec[:, 5] = rng.randint(0, 64, n)
     ts = np.sort(rng.randint(0, 900, n)).astype(np.int64)
-    rows = pipe.process_superbatch_raw([(rec, ts)], [2_000])
+    rows = pipe.process_superbatch([(rec, None, ts)], [2_000])
     link = clock.link()
     assert (link["columnsStaged"], link["recordColumns"]) == (staged, 7)
     B = 512          # 300 lanes staged at the next power-of-two of chunks
@@ -451,7 +451,7 @@ def module_names():
     prologue = TracedPrologue(
         transforms=(), key_fn=lambda col: col[:, 0].astype(jnp.int32),
         value_fn=None)
-    raw = [(np.stack([kid, kid], axis=1).astype(np.float32), ts)]
+    raw = [(np.stack([kid, kid], axis=1).astype(np.float32), None, ts)]
     mesh = Mesh(np.array(jax.devices()[:4]), ("shards",))
 
     pipes = [
@@ -471,10 +471,7 @@ def module_names():
     ]
     for pipe, is_raw in pipes:
         pipe.attach_device_stats(rec, phase_counters=False)
-        if is_raw:
-            pipe.process_superbatch_raw(raw, wms)
-        else:
-            pipe.process_superbatch(batches, wms)
+        pipe.process_superbatch(raw if is_raw else batches, wms)
     return rec.modules
 
 

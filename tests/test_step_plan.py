@@ -183,28 +183,17 @@ def _drive(flavour: str, stream: str, seed: int):
     def fires_of(fires):
         return [dataclasses.astuple(f) for f in fires]
 
-    raw_host = planner._stage_raw_host
+    fill = planner._fill
 
-    def record_raw(steps, wms):
-        raw_h, srel_h, ts_h, plan_np, fires = raw_host(steps, wms)
-        live = srel_h >= 0
-        staged.append(("raw", srel_h.copy(), [a.copy() for a in plan_np],
-                       fires_of(fires), [f[live] for f in raw_h]))
-        return raw_h, srel_h, ts_h, plan_np, fires
+    def record_fill(payload, steps, wms):
+        xs_h, lanes, layout, plan_np, fires = fill(payload, steps, wms)
+        live = xs_h[0] >= 0         # srel_h / idx_h
+        staged.append(("raw" if payload.record else "keyed", xs_h[0].copy(),
+                       [a.copy() for a in plan_np], fires_of(fires),
+                       [a[live] for a in xs_h[1:lanes]]))
+        return xs_h, lanes, layout, plan_np, fires
 
-    keyed = planner.stage_superbatch
-
-    def record_keyed(batches, wms):
-        idx_d, vals_d, plan = keyed(batches, wms)
-        idx_h = np.asarray(idx_d)
-        staged.append(("keyed", idx_h, [np.asarray(a) for a in plan[:-1]],
-                       fires_of(plan[-1]),
-                       [np.asarray(vals_d)[idx_h >= 0]
-                        if planner._needs_vals and len(batches[0][2]) else 0]))
-        return idx_d, vals_d, plan
-
-    planner._stage_raw_host = record_raw
-    planner.stage_superbatch = record_keyed
+    planner._fill = record_fill
     push = op._push_steps
 
     def count_pushed(steps):
@@ -232,7 +221,7 @@ def _drive(flavour: str, stream: str, seed: int):
                  [op.drain_spec_output(i) for i in range(len(op.spec_outputs))])
         rows.extend((i, k, w.start, v) for i, lane in enumerate(lanes)
                     for k, w, v, _ts in lane)
-    return dict(staged=staged, pushed=pushed, counters=counters, rows=rows,
+    return dict(pushed=pushed, fills=staged, counters=counters, rows=rows,
                 late=op.num_late_records_dropped, link=clock.link())
 
 
@@ -243,8 +232,8 @@ def _force_masked(monkeypatch):
 
 
 def _assert_same_staging(got, ref):
-    assert len(got["staged"]) == len(ref["staged"]) > 0
-    for g, r in zip(got["staged"], ref["staged"]):
+    assert len(got["fills"]) == len(ref["fills"]) > 0
+    for g, r in zip(got["fills"], ref["fills"]):
         assert g[0] == r[0]
         np.testing.assert_array_equal(g[1], r[1])       # srel_h / idx_h
         assert g[1].dtype == r[1].dtype == np.int32
@@ -385,8 +374,9 @@ def test_a_bare_step_is_planned_at_staging_and_a_wrong_plan_is_caught():
     pipe.attach_stage_clock(clock)
     rec = np.zeros((3, 3), np.float32)
     ts = np.array([5100, 5900, 6100], np.int64)
-    _raw, srel_h, _ts, plan_np, _fires = pipe._stage_raw_host(
-        [(rec, ts), (rec[:0], ts[:0])], [5999, 5999])
+    (srel_h, *_raw), _lanes, _layout, plan_np, _fires = pipe._fill(
+        pipe._payload, [(rec, None, ts), (rec[:0], None, ts[:0])],
+        [5999, 5999])
     np.testing.assert_array_equal(srel_h[0, :3], [0, 0, 1])
     assert (srel_h[0, 3:] == -1).all() and (srel_h[1] == -1).all()
     assert plan_np[0][0] == 5 % 16
@@ -394,7 +384,7 @@ def test_a_bare_step_is_planned_at_staging_and_a_wrong_plan_is_caught():
     # slice 5 was purged by the watermark above: a plan that still claims it
     stale = StepPlan(0, 5, 5)
     with pytest.raises(AssertionError, match="late-drop"):
-        pipe._stage_raw_host([(rec, ts, stale)], [6000])
+        pipe._fill(pipe._payload, [(rec, None, ts, stale)], [6000])
 
 
 # ---------------------------------------------------------------------------
