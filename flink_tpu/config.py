@@ -271,9 +271,13 @@ class ExecutionOptions:
     COLUMNAR_OUTPUT = (
         ConfigOptions.key("execution.window.columnar-output").bool_type().default_value(False)
     ).with_description(
-        "Emit window fires as packed (window, key-ids, values) rows instead of "
-        "one (key, value) row per key — emission cost becomes independent of "
-        "key cardinality (high-cardinality analytics sinks)."
+        "The shape downstream sees of a window fire: one packed (window, "
+        "key-ids, values) row per fire instead of one (key, value) row per "
+        "key, for sinks that take columns (high-cardinality analytics "
+        "sinks). It no longer decides what emission costs: the fused "
+        "operator hands every fire over as one block of columns either way "
+        "(runtime/fire_block.py), and rows are built from it once, by "
+        "whole-column calls, at the edge of the downstream hand-over."
     )
     MINI_BATCH_GROUP_AGG = (
         ConfigOptions.key("execution.group-agg.mini-batch").bool_type().default_value(True)
@@ -754,10 +758,13 @@ class ObservabilityOptions:
         "(source.poll .. sink.write, docs/observability.md) is a "
         "flink_tpu.<stage> span in any profiler capture and a count + self "
         "time in the per-operator stages table, with the link counters "
-        "(h2dBytes, d2hBytes, eventsStaged, rowsEmitted, dispatches, and "
+        "(h2dBytes, d2hBytes, eventsStaged, rowsEmitted, fireBlocks, "
+        "dispatches, and "
         "stepsPlannedScalar / stepsPlannedMasked: data steps whose slice "
         "plan came from the batch's two timestamp extremes, or per record "
-        "under a late mask). "
+        "under a late mask). flink_tpu.emit appends one block of columns "
+        "per fire (fireBlocks; rowsEmitted / fireBlocks = rows per fire), "
+        "flink_tpu.drain builds the downstream batch from the blocks. "
         "deviceDispatchMs / deviceTimeMsTotal / deviceDispatches are derived "
         "from its outer sections: HOST time in the dispatch and resolve "
         "sections, not device time. Host clock round already-synchronous "

@@ -234,6 +234,9 @@ class StageClock:
         self.columns_staged = 0
         self.record_columns = 0
         self.rows_emitted = 0
+        # fires appended to an output lane, one block each (fire_block.py):
+        # rowsEmitted / fireBlocks = rows per fire
+        self.fire_blocks = 0
         # data steps staged, by how their slice plan was made: from the
         # step's two timestamp extremes, or per record under a late mask
         self.steps_planned_scalar = 0
@@ -288,7 +291,8 @@ class StageClock:
                 "eventsStaged": self.events_staged,
                 "columnsStaged": self.columns_staged,
                 "recordColumns": self.record_columns,
-                "rowsEmitted": self.rows_emitted, "dispatches": self.seq,
+                "rowsEmitted": self.rows_emitted,
+                "fireBlocks": self.fire_blocks, "dispatches": self.seq,
                 "stepsPlannedScalar": self.steps_planned_scalar,
                 "stepsPlannedMasked": self.steps_planned_masked}
 

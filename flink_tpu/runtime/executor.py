@@ -39,6 +39,7 @@ from flink_tpu.core.time import MAX_WATERMARK, MIN_TIMESTAMP, MIN_WATERMARK
 from flink_tpu.core.watermarks import WatermarkStrategy
 from flink_tpu.graph.transformation import Step, StepGraph, Transformation
 from flink_tpu.ops.aggregators import resolve
+from flink_tpu.runtime.fire_block import downstream_batch, fires_of
 from flink_tpu.runtime.oracle_window_operator import OracleWindowOperator
 from flink_tpu.runtime.tpu_window_operator import TpuWindowOperator
 from flink_tpu.runtime.timers import InternalTimerService
@@ -632,10 +633,6 @@ class WindowStepRunner(StepRunner):
             from flink_tpu.runtime.fused_window_operator import FusedWindowOperator
 
             batch_size = config.get(ExecutionOptions.BATCH_SIZE)
-            # only the fused operator's drain is a blocking device readback
-            # (deferred superbatch resolution); everywhere else drain is a
-            # host list swap and timing it would inflate deviceDispatches
-            self._drain_resolves_device = True
             # start small, grow by doubling with the key dictionary —
             # superscan cost scales with key capacity, so tiny jobs must
             # not pay for the configured maximum up front. With the state
@@ -705,11 +702,21 @@ class WindowStepRunner(StepRunner):
         # marked so the job can report which execution path SQL selected
         # (job.sqlFusedSelected gauge + /jobs/:id visibility)
         self.sql_origin = bool(cfg.get("sql_origin"))
-        self._drain_resolves_device = getattr(
-            self, "_drain_resolves_device", False)
+        self._init_drain()
         self._init_stage_clock(config)
         self._init_device_stats(config)
         self._init_emission_plane(config)
+
+    def _init_drain(self) -> None:
+        """What `_drain` drains: the fused operator hands its fires over as
+        blocks of columns (runtime/fire_block.py), every other window
+        operator as rows; `downstream_batch` takes either. Only the fused
+        operator's drain is a blocking device readback (deferred superbatch
+        resolution); everywhere else drain is a host list swap and timing
+        it would inflate deviceDispatches."""
+        blocks = getattr(self.op, "drain_blocks", None)
+        self._drain_resolves_device = blocks is not None
+        self._drain_fires = blocks or self.op.drain_output
 
     def _init_stage_clock(self, config: Configuration) -> None:
         """The operator's stage clock (metrics/task_io.py): named stages of
@@ -953,24 +960,16 @@ class WindowStepRunner(StepRunner):
         # deviceDispatches counts what it always counted); other
         # operators' drain is a host list swap and is not timed
         with section(clock if self._drain_resolves_device else None):
-            out = self.op.drain_output()
+            out = self._drain_fires()
         if out and self._emission_at_drain:
             tr, lateness = self.emission_tracker, self._emission_lateness
-            for _k, w, _r, t in out:
+            for w, t in fires_of(out):
                 tr.record_fire(getattr(w, "end", int(t) + 1),
                                lateness_ms=lateness)
         if out and self.downstream:
             with stage(clock, "drain"):
-                vals = obj_array(
-                    [
-                        r if (self.window_fn is not None or k is None)
-                        else (k, r)
-                        for (k, _w, r, _t) in out
-                    ]
-                )
-                ts = np.asarray([t for (_k, _w, _r, t) in out],
-                                dtype=np.int64)
-                self.downstream.on_batch(vals, ts)
+                self.downstream.on_batch(
+                    *downstream_batch(out, bare=self.window_fn is not None))
 
     def register_metrics(self, group) -> None:
         super().register_metrics(group)
@@ -1131,7 +1130,7 @@ class DeviceChainRunner(WindowStepRunner):
         self.processing_time = False
         self.uid = t.uid
         self.sql_origin = bool(cfg.get("sql_origin"))
-        self._drain_resolves_device = True
+        self._init_drain()
         self._init_stage_clock(config)
         self._init_device_stats(config)
         self._init_emission_plane(config)
@@ -1212,20 +1211,15 @@ class SharedWindowRunner(DeviceChainRunner):
     def _drain(self) -> None:
         clock = self.stage_clock
         with section(clock if self._drain_resolves_device else None):
-            drained = [self.op.drain_spec_output(s)
+            drained = [self.op.drain_spec_blocks(s)
                        for s in range(len(self.member_runners))]
         for spec, fan, _sides in self._spec_fanouts():
             out = drained[spec]
             if out and fan:
                 with stage(clock, "drain"):
-                    # same record shape as the base _drain: columnar-output
-                    # entries (k is None) forward the bare device triple —
-                    # sharing must never change what downstream receives
-                    vals = obj_array([r if k is None else (k, r)
-                                      for (k, _w, r, _t) in out])
-                    ts = np.asarray([t for (_k, _w, _r, t) in out],
-                                    dtype=np.int64)
-                    fan.on_batch(vals, ts)
+                    # the base _drain's builder: sharing must never change
+                    # what downstream receives
+                    fan.on_batch(*downstream_batch(out, bare=False))
 
     def _forward_watermark(self, watermark: int) -> None:
         for _spec, fan, sides in self._spec_fanouts():

@@ -44,6 +44,7 @@ from flink_tpu.core.time import MAX_WATERMARK, MIN_WATERMARK
 from flink_tpu.lint.contracts import inflight_ring
 from flink_tpu.metrics.task_io import dispatch_stage, stage
 from flink_tpu.ops.aggregators import ONE, VALUE, resolve
+from flink_tpu.runtime.fire_block import FireBlock, blocks_of, rows_of
 from flink_tpu.runtime.fused_window_pipeline import (
     FusedWindowPipeline,
     StepPlan,
@@ -601,7 +602,8 @@ class FusedWindowOperator:
                 # all-to-all collective count, so the mesh keeps
                 # span-granular readback (docs/latency.md)
                 self.pipe.readback_steps = int(latency.readback_steps)
-        self.output: List[Tuple[Any, Any, Any, int]] = []
+        # one FireBlock per fire (runtime/fire_block.py), never rows
+        self.output: List[FireBlock] = []
         self.emitted_watermark = MIN_WATERMARK
         self.current_watermark = MIN_WATERMARK
         self.columnar_output = columnar_output
@@ -884,35 +886,24 @@ class FusedWindowOperator:
             return
         self._emit_keydict_rows(window, counts, fields, live)
 
-    def _emit_dense_rows(self, window, counts, fields, sink: list) -> None:
+    def _emit_dense_rows(self, window, counts, fields, lane: list) -> None:
         """Dense-device-keying emission (traced prologue): the emitted key
         IS the id the traced selector produced — every capacity row may be
-        live. `sink` selects the output lane (shared partials route per
+        live. `lane` selects the output lane (shared partials route per
         member window spec)."""
         counts = np.asarray(counts)
         live = np.flatnonzero(counts > 0)
         if live.size == 0:
             return
-        self._count_rows(live.size)
         fdict: Dict[str, Any] = {
             f.name: (counts if f.source == ONE
                      else np.asarray(fields[f.name]))
             for f in self.agg.fields
         }
         result = np.asarray(self.agg.extract(fdict))
-        ts = window.max_timestamp()
-        if self.columnar_output:
-            sink.append((None, window, (window, live, result[live]), ts))
-            return
-        for i in live:
-            sink.append((int(i), window, result[i].item(), ts))
-
-    def _count_rows(self, n: int) -> None:
-        if self.stage_clock is not None:
-            self.stage_clock.rows_emitted += int(n)
+        self._append_fire(lane, window, live, result[live])
 
     def _emit_keydict_rows(self, window, counts, fields, live) -> None:
-        self._count_rows(live.size)
         fdict: Dict[str, Any] = {}
         for f in self.agg.fields:
             if f.source == ONE:
@@ -920,23 +911,41 @@ class FusedWindowOperator:
             else:
                 fdict[f.name] = np.asarray(fields[f.name])[: len(self.keydict)]
         result = np.asarray(self.agg.extract(fdict))
+        self._append_fire(self.output, window, live, result[live],
+                          self.keydict.keys_for)
+
+    def _append_fire(self, lane: list, window, live, results,
+                     keys_of=None) -> None:
+        """One fire onto its output lane as ONE block of columns
+        (runtime/fire_block.py): `live` are the dense ids that fired,
+        ascending, `results` their column; `keys_of` maps ids to the
+        emitted keys where the id is not the key itself. Nothing here runs
+        per row: whoever needs rows builds them from the block."""
         ts = window.max_timestamp()
         if self.columnar_output:
             # one packed row per fire: (window, dense key ids, values) —
-            # emission cost stays O(1) rows regardless of key cardinality
+            # downstream sees O(1) rows regardless of key cardinality
             # (map ids back through .keydict when raw keys are needed)
-            self.output.append((None, window, (window, live, result[live]), ts))
-            return
-        keys = self.keydict.keys_for(live)
-        for k, i in zip(keys, live):
-            self.output.append((k, window, result[i].item(), ts))
+            block = FireBlock(window, None, [(window, live, results)], ts)
+        else:
+            block = FireBlock(
+                window, live if keys_of is None else keys_of(live), results,
+                ts)
+        lane.append(block)
+        self._count_fire(live.size)
+
+    def _count_fire(self, rows: int) -> None:
+        if self.stage_clock is not None:
+            self.stage_clock.rows_emitted += int(rows)
+            self.stage_clock.fire_blocks += 1
 
     def _emit_tiered(self, window, counts, fields) -> None:
-        """Row-mode emission merging both tiers: resident keys fire from
-        the device rows, cold keys from the cold store. A key whose data
-        is SPLIT across tiers for this window (partial promotion left
-        far-future rows cold) combines per the field scatter ops before
-        extraction, so placement can never change a result."""
+        """Emission merging both tiers: resident keys fire from the device
+        rows, cold keys from the cold store. A key whose data is SPLIT
+        across tiers for this window (partial promotion left far-future
+        rows cold) combines per the field scatter ops before extraction,
+        so placement can never change a result. Resident rows first, in
+        ascending id order, then the cold-only keys, as one block."""
         p = self.pipe
         j = (window.start - p.offset) // p.slide_ms
         slice_range = range(j * p.sl, j * p.sl + p.spw)
@@ -963,17 +972,15 @@ class FusedWindowOperator:
                 elif key is not None:
                     extras.append((key, int(ccounts[i]),
                                    {n: cfields[n][i] for n in cfields}))
-        ts = window.max_timestamp()
         live = np.flatnonzero(counts > 0)
-        self._count_rows(live.size + len(extras))
+        keys: List[Any] = []
+        results: List[Any] = []
         if live.size:
             fdict = {f.name: (counts if f.source == ONE else vals[f.name])
                      for f in self.agg.fields}
             result = np.asarray(self.agg.extract(fdict))
-            vocab = self.tier.vocab
-            for i in live:
-                self.output.append((vocab.key_of_id(int(i)), window,
-                                    result[i].item(), ts))
+            keys += map(self.tier.vocab.key_of_id, live.tolist())
+            results += result[live].tolist()
         if extras:
             e_counts = np.asarray([e[1] for e in extras], np.int64)
             fdict_e = {
@@ -982,21 +989,34 @@ class FusedWindowOperator:
                                          np.dtype(f.dtype)))
                 for f in self.agg.fields
             }
-            result_e = np.asarray(self.agg.extract(fdict_e))
-            for i, (key, _c, _f) in enumerate(extras):
-                self.output.append((key, window, result_e[i].item(), ts))
+            keys += [e[0] for e in extras]
+            results += np.asarray(self.agg.extract(fdict_e)).tolist()
+        if keys:
+            self.output.append(
+                FireBlock(window, keys, results, window.max_timestamp()))
+            self._count_fire(len(keys))
 
-    def drain_output(self) -> List[Tuple[Any, Any, Any, int]]:
+    def drain_blocks(self) -> List[FireBlock]:
+        """The fires since the last drain, one block each: the runner's
+        hand-over (executor.py builds the downstream batch from them)."""
         out = self.output
         self.output = []
         return out
 
-    def drain_spec_output(self, spec: int) -> List[Tuple[Any, Any, Any, int]]:
+    def drain_spec_blocks(self, spec: int) -> List[FireBlock]:
         """Shared partials: drain one member window's output lane (the
         shared runner routes lane i to member i's downstream edges)."""
         out = self.spec_outputs[spec]
         self.spec_outputs[spec] = []
         return out
+
+    def drain_output(self) -> List[Tuple[Any, Any, Any, int]]:
+        """The same fires as `(key, window, result, ts)` rows of Python
+        scalars, for callers that want rows."""
+        return rows_of(self.drain_blocks())
+
+    def drain_spec_output(self, spec: int) -> List[Tuple[Any, Any, Any, int]]:
+        return rows_of(self.drain_spec_blocks(spec))
 
     def query_state_for(self, key) -> Dict[int, Dict[str, Any]]:
         """Point lookup (queryable state): {abs_slice: {field..., count}}
@@ -1185,7 +1205,7 @@ class FusedWindowOperator:
         numeric rows pack columnar (~3x smaller pickled than a list of
         (key, TimeWindow, value, ts) tuples). Non-scalar rows fall back
         to the raw list."""
-        rows = self.output
+        rows = rows_of(self.output)
         from flink_tpu.core.time import TimeWindow as _TW
 
         if rows and all(
@@ -1244,7 +1264,7 @@ class FusedWindowOperator:
         self.norm.restore(meta["norm"])
         self._steps = []
         self._reset_dispatch_ring()
-        self.output = self._unpack_output(envelope["output"])
+        self.output = blocks_of(self._unpack_output(envelope["output"]))
         self.emitted_watermark = envelope["emitted_watermark"]
         self.current_watermark = envelope["current_watermark"]
 
@@ -1270,7 +1290,7 @@ class FusedWindowOperator:
         if self.spec_outputs is not None:
             # shared partials: undrained per-member lanes ride the
             # checkpoint like the plain output list
-            snap_extra["spec_outputs"] = [list(x) for x in self.spec_outputs]
+            snap_extra["spec_outputs"] = [rows_of(x) for x in self.spec_outputs]
         return {
             **snap_extra,
             "pipe": self.pipe.snapshot(),
@@ -1286,7 +1306,8 @@ class FusedWindowOperator:
             # resolved-but-undrained emissions: their fires are already
             # committed in device state, so dropping them at restore would
             # lose output — they ride the checkpoint instead
-            "output": list(self.output),
+            # (as rows: the checkpoint's format, state_processor reads it)
+            "output": rows_of(self.output),
             "emitted_watermark": self.emitted_watermark,
             "current_watermark": self.current_watermark,
         }
@@ -1328,6 +1349,6 @@ class FusedWindowOperator:
         self.emitted_watermark = snap["emitted_watermark"]
         self.current_watermark = snap["current_watermark"]
         self._reset_dispatch_ring()
-        self.output = list(snap["output"])
+        self.output = blocks_of(snap["output"])
         if self.spec_outputs is not None:
-            self.spec_outputs = [list(x) for x in snap["spec_outputs"]]
+            self.spec_outputs = [blocks_of(x) for x in snap["spec_outputs"]]

@@ -144,8 +144,7 @@ class KeyDictionary:
         return kid
 
     def keys_for(self, kids: np.ndarray) -> List:
-        ks = self._keys
-        return [ks[int(i)] for i in kids]
+        return list(map(self._keys.__getitem__, kids.tolist()))
 
     def snapshot(self) -> dict:
         return {"dense_int": self.dense_int, "keys": list(self._keys)}

@@ -114,6 +114,7 @@ def test_link_counters_and_seq():
     assert clock.link() == {"h2dBytes": 4 * 8 * 4 + 12, "d2hBytes": 0,
                             "eventsStaged": 4, "columnsStaged": 0,
                             "recordColumns": 0, "rowsEmitted": 0,
+                            "fireBlocks": 0,
                             "dispatches": 1, "stepsPlannedScalar": 0,
                             "stepsPlannedMasked": 0}
     # a traced chain's dispatch says how much of the record it shipped;
@@ -302,6 +303,8 @@ def test_stage_and_link_tables_agree_with_the_job(traced_job):
     assert link["eventsStaged"] == (passed if traced_job["host_keyed"] else N)
     rows = traced_job["sink"].results
     assert link["rowsEmitted"] == len(rows) > 0
+    # one block per fire: as many blocks as emit spans, every row in one
+    assert link["fireBlocks"] == entry["stages"]["emit"]["count"] > 0
     assert sum(v for _k, v in rows) == passed
     assert link["h2dBytes"] > 0 and link["d2hBytes"] > 0
     # the 2-field record: the traced chain reads both (filter, key); the
