@@ -95,7 +95,8 @@ class KeyStatsCollector:
                  ready_fn: Optional[Callable[[], bool]] = None,
                  interval_ms: int = 1000,
                  clock: Callable[[], float] = time.monotonic,
-                 mesh_loads_fn: Optional[Callable[[], Any]] = None):
+                 mesh_loads_fn: Optional[Callable[[], Any]] = None,
+                 mesh_exchange_fn: Optional[Callable[[], Any]] = None):
         self._loads_fn = loads_fn
         # multichip (parallel/sharded_superscan.py): [n, K_local] per-device
         # local loads. The GLOBAL histogram cannot see device imbalance —
@@ -104,6 +105,12 @@ class KeyStatsCollector:
         # keeps per-device load/skew and the scalar gauges take the MAX
         # across devices (never device 0's view)
         self._mesh_loads_fn = mesh_loads_fn
+        # [n, 2] host counters of the keyBy exchange, or None: records
+        # DELIVERED to each device since the job started and lanes its
+        # ingest read for them. The resident loads above forget a record
+        # when its window is purged and say nothing of the ingest's width;
+        # skew shows in what the exchange hands each device
+        self._mesh_exchange_fn = mesh_exchange_fn
         self.num_key_groups = max(int(num_key_groups), 1)
         self.top_k = max(int(top_k), 1)
         self._row_bytes_fn = row_bytes_fn
@@ -126,7 +133,8 @@ class KeyStatsCollector:
         self._hot: List[List[int]] = []          # [[kid, count], ...]
         self._group_load: Dict[str, float] = {"count": 0}
         self._group_state_bytes: Dict[str, float] = {"count": 0}
-        # per-mesh-device view: [{device, records, activeKeys, keySkew}]
+        # per-mesh-device view: [{device, records, activeKeys, hotKeyLoad,
+        # keySkew}] of the fold; `per_device()` adds routed and lanes
         self._per_device: List[Dict[str, float]] = []
         self._mesh_load_skew: Optional[float] = None
 
@@ -273,8 +281,18 @@ class KeyStatsCollector:
             return self._mesh_load_skew
 
     def per_device(self) -> List[Dict[str, Any]]:
+        """The newest fold's per-device entries, each with the exchange's
+        `routed` and `lanes` as they stand NOW (host counters of resolved
+        dispatches: no device read, so they are not held to the fold's
+        interval and are whole once the job's last dispatch has resolved)."""
         with self._lock:
-            return [dict(e) for e in self._per_device]
+            entries = [dict(e) for e in self._per_device]
+        totals = (self._mesh_exchange_fn()
+                  if entries and self._mesh_exchange_fn is not None else None)
+        if totals is not None:
+            for e, (routed, lanes) in zip(entries, totals):
+                e["routed"], e["lanes"] = int(routed), int(lanes)
+        return entries
 
     def _per_device_map(self, field: str) -> Dict[str, float]:
         with self._lock:
@@ -312,6 +330,7 @@ class KeyStatsCollector:
 
     # -- exposure ----------------------------------------------------------
     def payload(self) -> Dict[str, Any]:
+        per_device = self.per_device()
         with self._lock:
             return {
                 "keySkew": (None if self._skew is None
@@ -323,6 +342,6 @@ class KeyStatsCollector:
                 "hotKeys": [list(e) for e in self._hot],
                 "keyGroupLoad": dict(self._group_load),
                 "keyGroupStateBytes": dict(self._group_state_bytes),
-                "perDevice": [dict(e) for e in self._per_device],
+                "perDevice": per_device,
                 "meshLoadSkew": self._mesh_load_skew,
             }

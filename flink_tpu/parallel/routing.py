@@ -1,9 +1,11 @@
 """Key-group routing for the sharded superscan (parallel.mesh.skew-rebalance).
 
 The static mesh owner function — ``dst = kid // K_local``, contiguous key
-ranges per device — is what makes zipf-skewed traffic slow: whichever
-device owns the hot key range absorbs the hot keys' full mass while the
-rest of the mesh idles. This module replaces it with a ROUTING TABLE over
+ranges per device — gives whichever device owns the hot key range most of
+the records. On four v5e chips that cost a zipf(1.0) stream nothing (the
+exchange and the ingest are positional), while this table's lookups made
+the device program 2.6 times longer (docs/multichip.md, PERF.md PR 30).
+This module replaces the owner function with a ROUTING TABLE over
 key-groups (the same contiguous ``kid * G // K`` ranges the key-stats fold
 and the reference's KeyGroupRangeAssignment partition by, here exact
 ``kid // Kg`` because G divides K): ``assign[g]`` names the device that

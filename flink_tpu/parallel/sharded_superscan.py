@@ -19,9 +19,10 @@ INVALID, so the all-to-all needs no data-dependent compaction; each shard
 then ingests n*B lanes per step (mostly INVALID, dropped for free by the
 one-hot/scatter semantics).
 
-Two skew-adaptive layers compose on top (both pure perf switches —
-docs/multichip.md "Pre-exchange local combine" / "Skew-aware key-group
-routing"): `local_combine` segment-reduces each shard's lanes by
+Two skew-adaptive layers compose on top (both pure perf switches, off by
+default, measured under zipf keys in PR 30 — docs/multichip.md
+"Pre-exchange local combine" / "Skew-aware key-group routing"):
+`local_combine` segment-reduces each shard's lanes by
 (destination, key, rel-slice) BEFORE the all-to-all, so only dense
 partials cross ICI (exact for decomposable aggregates; others route raw
 transparently), and `skew_routing` replaces the static owner function
@@ -39,7 +40,11 @@ bench kernel — at the mesh.
 
 Fire/purge control is replicated (all shards fire the same window rows);
 each shard writes its own [R, K_local] slab and the host concatenates along
-the key axis at resolve. Snapshots are canonical [K, S] global arrays,
+the key axis at resolve. The traced-chain program also counts, per shard,
+the records the exchange delivered and the lanes its ingest read for them;
+the two ride the key-bounds vector the dispatch reads back
+(`per_device_exchange`, `perDevice[i].routed` / `.lanes`). Snapshots are
+canonical [K, S] global arrays,
 interchangeable with single-chip `FusedWindowPipeline` snapshots — which
 makes n -> m shard rescaling a restore.
 
@@ -177,6 +182,7 @@ class ShardedFusedPipeline:
         if self.routing is not None:
             self._refresh_route_tables()
         self._init_state()
+        self.exchange_totals = np.zeros((self.n, 2), np.int64)
         self._fn_cache: Dict[tuple, Any] = {}
         # latency mode (scheduler/latency_controller.py): donate the
         # sharded [n, Kl, S] scan carry to the executable. Streaming fire
@@ -290,6 +296,20 @@ class ShardedFusedPipeline:
     def key_stats_ready(self) -> bool:
         return self._planner.max_seen_slice is not None
 
+    def note_exchange(self, per_shard) -> None:
+        """Fold one resolved dispatch's exchange counts (the tail of its
+        key-bounds vector: `routed`, `lanes` per shard) into the totals."""
+        self.exchange_totals += np.asarray(
+            per_shard, np.int64).reshape(self.n, 2)
+
+    def per_device_exchange(self):
+        """[n, 2] int64 since this pipeline was built, resolved dispatches
+        only: records the keyBy exchange delivered to each device, and
+        lanes that device's ingest read for them. None until a dispatch of
+        the traced-chain program has resolved: the key-id program reads no
+        key bounds back, so it has nothing to carry the counts."""
+        return self.exchange_totals if self.exchange_totals.any() else None
+
     # ------------------------------------------------------------------
     def _shard_spec(self, *tail):
         return NamedSharding(self.mesh, P(self.axis, *tail))
@@ -375,7 +395,8 @@ class ShardedFusedPipeline:
         """fn(carry, pidx, vals, plan_row): segment-reduce this shard's
         lanes into flat [n*Kl*NSB] per-destination partials, ONE
         all-to-all per channel (count + each value field), fold across
-        source shards by the field's own combiner, ingest pre-reduced."""
+        source shards by the field's own combiner, ingest pre-reduced.
+        Returns (carry, records delivered to this shard)."""
         n, Kl, NSB, axis = self.n, self.K_local, self.NSB, self.axis
 
         def fn(carry, pidx, vals, plan_row):
@@ -392,7 +413,10 @@ class ShardedFusedPipeline:
                         concat_axis=0, tiled=False)
                     parts_l.append(
                         combine_reduce(sc)(rp, 0).reshape(Kl, NSB))
-            return step(carry, (cpart_l, tuple(parts_l)) + tuple(plan_row))
+            carry, _ = step(
+                carry, (cpart_l, tuple(parts_l)) + tuple(plan_row))
+            # the records this shard was handed: its partial counts' sum
+            return carry, cpart_l.sum()
         return fn
 
     def _make_step(self, lanes: int, phases: bool):
@@ -461,7 +485,9 @@ class ShardedFusedPipeline:
                 if combine:
                     dst, lidx = owner(valid, kid, idx_row % NSB)
                     pidx = jnp.where(valid, dst * (Kl * NSB) + lidx, -1)
-                    return exchange(carry, pidx, vals_row, plan_row)
+                    carry, _delivered = exchange(
+                        carry, pidx, vals_row, plan_row)
+                    return carry, None
                 with jax.named_scope("exchange"):
                     if routed:
                         # route-raw under a table: the sender localizes (the
@@ -640,6 +666,7 @@ class ShardedFusedPipeline:
         scatters = [f.scatter for f in self._value_fields]
         pro = self.prologue
         needs_ts = pro.needs_ts
+        ingest_width = n * Kl * NSB if combine else n * B
 
         def per_shard(count, state_t, raw, srel, *rest):
             if routed:
@@ -665,7 +692,7 @@ class ShardedFusedPipeline:
                     partials_fn, step, scatters)
 
             def routed_step(carry, args):
-                inner, key_bounds = carry
+                inner, key_bounds, handed = carry
                 if needs_ts:
                     raw_row, srel_row, ts_row = args[0], args[1], args[2]
                     plan_row = args[3:]
@@ -687,8 +714,8 @@ class ShardedFusedPipeline:
                     # key costs n partials per slice, not its tuple mass
                     dst, lidx = owner(live, keys, srel_row)
                     pidx = jnp.where(live, dst * (Kl * NSB) + lidx, -1)
-                    inner, _ = exchange(inner, pidx, vals, plan_row)
-                    return (inner, key_bounds), None
+                    inner, delivered = exchange(inner, pidx, vals, plan_row)
+                    return (inner, key_bounds, handed + delivered), None
                 # the keyBy exchange: bin by owning key range, one
                 # all-to-all over the mesh interconnect per step
                 with jax.named_scope("exchange"):
@@ -717,7 +744,8 @@ class ShardedFusedPipeline:
                     else:
                         recv_v = jnp.zeros((1,), jnp.float32)
                 inner, _ = step(inner, (local_idx, recv_v) + plan_row)
-                return (inner, key_bounds), None
+                delivered = jnp.sum((local_idx >= 0).astype(jnp.int32))
+                return (inner, key_bounds, handed + delivered), None
 
             outs0 = {
                 f.name: jnp.zeros((R, Kl), jnp.dtype(f.dtype))
@@ -732,8 +760,8 @@ class ShardedFusedPipeline:
             if needs_ts:
                 xs = xs + (ts,)
             xs = xs + (smin_pos, fire_pos, fire_valid, fire_row, purge_mask)
-            (inner, key_bounds), _ = jax.lax.scan(
-                routed_step, (inner0, kb0), xs)
+            (inner, key_bounds, handed), _ = jax.lax.scan(
+                routed_step, (inner0, kb0, jnp.int32(0)), xs)
             if phases:
                 state, count, outs, count_out, pc = inner
             else:
@@ -743,6 +771,10 @@ class ShardedFusedPipeline:
                 count[None], tuple(state[nm][None] for nm in names),
                 count_out[None], tuple(outs[nm][None] for nm in names),
                 key_bounds[None],                         # [1, 2] per shard
+                # what the exchange handed this shard over the dispatch
+                # beside what its ingest read: n*B lanes a step, or the
+                # n*Kl*NSB partial cells of the map-side combiner
+                jnp.stack([handed, jnp.int32(T * ingest_width)])[None],
             )
             if phases:
                 out = out + (pc[None],)
@@ -759,6 +791,7 @@ class ShardedFusedPipeline:
             P(axis, None, None),
             (P(axis, None, None),) * nf,
             P(axis, None),                                # key bounds [n,2]
+            P(axis, None),                    # exchange (routed, lanes) [n,2]
         )
         if phases:
             out_specs = out_specs + (P(axis, None),)
@@ -784,13 +817,16 @@ class ShardedFusedPipeline:
         def run_sharded_chained_superscan(*args):
             out = sharded(*args)
             if phases:
-                count, states, count_out, outs, kb, pc = out
+                count, states, count_out, outs, kb, xc, pc = out
             else:
-                count, states, count_out, outs, kb = out
+                count, states, count_out, outs, kb, xc = out
                 pc = None
             # global key bounds: worst over shards (each shard saw only
-            # its own pre-shuffle lanes)
-            kb_g = jnp.stack([kb[:, 0].max(), kb[:, 1].min()])
+            # its own pre-shuffle lanes); after them, in the one vector the
+            # dispatch reads back anyway, every shard's exchange counts
+            kb_g = jnp.concatenate([
+                jnp.stack([kb[:, 0].max(), kb[:, 1].min()]),
+                xc.reshape(-1)])
             if phases:
                 return count, states, count_out, outs, kb_g, pc
             return count, states, count_out, outs, kb_g

@@ -2232,6 +2232,8 @@ class _ShardTask:
                         getattr(op, "per_device_key_loads", None)
                         if getattr(op, "mesh_devices", lambda: 1)() > 1
                         else None),
+                    mesh_exchange_fn=getattr(
+                        op, "per_device_exchange", None),
                 )
                 key_stats.register(op_group)
                 # the job-level gauge the autoscaler's signal extractor

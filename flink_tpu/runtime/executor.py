@@ -806,6 +806,8 @@ class WindowStepRunner(StepRunner):
                     getattr(self.op, "per_device_key_loads", None)
                     if getattr(self.op, "mesh_devices", lambda: 1)() > 1
                     else None),
+                mesh_exchange_fn=getattr(
+                    self.op, "per_device_exchange", None),
             )
 
     def _device_stats_tick(self) -> None:

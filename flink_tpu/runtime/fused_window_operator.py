@@ -1102,6 +1102,13 @@ class FusedWindowOperator:
         fn = getattr(self.pipe, "per_device_key_loads", None)
         return fn() if fn is not None else None
 
+    def per_device_exchange(self):
+        """[n, 2] records the mesh exchange delivered to each device and
+        lanes it ingested for them (None on a single chip, and on the mesh
+        until a traced-chain dispatch has resolved)."""
+        fn = getattr(self.pipe, "per_device_exchange", None)
+        return fn() if fn is not None else None
+
     def mesh_devices(self) -> int:
         """Devices this operator's state is sharded over (1 = single chip)."""
         return int(getattr(self.pipe, "n", 1))

@@ -239,7 +239,8 @@ class DeferredEmissions:
         self._fires = fires
         self._count_out = count_out
         self._outs = outs
-        self._key_bounds = key_bounds    # int32[2]: [max_seen, min_seen]
+        # int32: [max_seen, min_seen], then on a mesh (routed, lanes) per shard
+        self._key_bounds = key_bounds
         self._key_capacity = key_capacity
         # int32[3] per-phase step counters of this dispatch (device-plane
         # observability); folded into the pipeline's totals at resolve so
@@ -267,7 +268,10 @@ class DeferredEmissions:
                 self._phase_counts, dtype=np.int64)
             self._phase_counts = None
         if self._key_bounds is not None:
-            hi, lo = (int(v) for v in np.asarray(self._key_bounds))
+            bounds = np.asarray(self._key_bounds)
+            hi, lo = int(bounds[0]), int(bounds[1])
+            if bounds.size > 2:     # a mesh dispatch: its exchange counts
+                self._pipe.deployment.note_exchange(bounds[2:])
             if hi >= self._key_capacity or lo < 0:
                 raise ValueError(
                     f"traced key selector produced keys in [{lo}, {hi}] "
@@ -278,6 +282,7 @@ class DeferredEmissions:
                     "keys non-negative), or drop traceable=True on key_by "
                     "to use the host key dictionary."
                 )
+            self._key_bounds = None     # checked and folded once
         count_np = np.asarray(self._count_out)
         outs_np = {k: np.asarray(v) for k, v in self._outs.items()}
         return [

@@ -1,9 +1,13 @@
-"""The keyed job at parallelism n: the deployment `ysb_keys64k_mesh4` of the
-benchmark (`benchmarks/configs/ysb_keys64k_mesh4.json`) at small sizes on the
-virtual CPU mesh.
+"""The keyed job at parallelism n: the deployments `ysb_keys64k_mesh4` and
+`ysb_keys64k_zipf_mesh4` of the benchmark (`benchmarks/configs/`) at small
+sizes on the virtual CPU mesh.
 
 - the configuration's job through `env.execute()` with the two mesh options,
-  rows equal to the configuration's plain reference cell for cell;
+  rows equal to the configuration's plain reference cell for cell, on uniform
+  and on zipf keys (rank = id: the first key range owns most of the records),
+  and under zipf with each skew switch on;
+- what the exchange delivered to each device (`perDevice[i].routed`) and what
+  that device's ingest read for it (`.lanes`), against a numpy count;
 - the fire rows a mesh dispatch hands to the deferred readback: only the rows
   its fires used, on both dispatch paths, with and without a routing table;
 - the stage clock on the mesh: the deal's own stage, the link's bytes back;
@@ -18,7 +22,9 @@ import jax.numpy as jnp
 from jax.sharding import Mesh
 
 from benchmarks import harness
+from benchmarks.stream import build_cycle
 from flink_tpu.api.windowing.assigners import SlidingEventTimeWindows
+from flink_tpu.metrics.key_stats import KeyStatsCollector
 from flink_tpu.metrics.task_io import StageClock
 from flink_tpu.parallel.sharded_superscan import ShardedFusedPipeline
 from flink_tpu.runtime.fused_window_pipeline import (
@@ -26,7 +32,8 @@ from flink_tpu.runtime.fused_window_pipeline import (
     TracedPrologue,
 )
 
-CONFIG = "ysb_keys64k_mesh4"
+#: the key draw -> the shipped configuration that has it
+CONFIGS = {"uniform": "ysb_keys64k_mesh4", "zipf": "ysb_keys64k_zipf_mesh4"}
 KEYS = 4096
 
 
@@ -38,44 +45,110 @@ def _mesh(n):
 # the configuration's job through env.execute(), against its plain reference
 # ---------------------------------------------------------------------------
 
-def _small_spec(n: int):
+def _small_spec(n: int, keys: str = "uniform", **options):
     """The shipped configuration with the key space and the key capacity cut
-    to test size and the mesh over `n` devices; the job, the record, the
-    window, the jitter and the reference are the file's."""
-    cfg = harness.load_json("configs", CONFIG + ".json")
-    for col in cfg["stream"]["columns"]:
-        if col["name"] == "campaign_id":
-            col["mod"] = KEYS
+    to test size and the mesh over `n` devices; the job, the record, the key
+    draw, the window, the jitter and the reference are the file's."""
+    cfg = harness.load_json("configs", CONFIGS[keys] + ".json")
+    (key_col,) = [c for c in cfg["stream"]["columns"]
+                  if c["name"] == "campaign_id"]
+    key_col["mod"] = KEYS
+    assert key_col.get("dist") == (
+        {"kind": "zipf", "s": 1.0} if keys == "zipf" else None)
     cfg["reference"]["keys"] = KEYS
     cfg["options"] = dict(cfg["options"], **{
-        "parallel.mesh.devices": n, "execution.state.key-capacity": KEYS})
+        "parallel.mesh.devices": n, "execution.state.key-capacity": KEYS},
+        **options)
     cfg["expect"] = ({"mesh_devices": n, "devices_with_records": n}
                      if n > 1 else {})
-    return {"cell": {"name": f"test_keys4k_mesh{n}", "config": CONFIG,
-                     "traffic": "catchup", "chips": n},
+    return {"cell": {"name": f"test_keys4k_{keys}_mesh{n}",
+                     "config": CONFIGS[keys], "traffic": "catchup",
+                     "chips": n},
             "cfg": cfg, "traffic": harness.load_json("traffic", "catchup.json"),
             "end_to_end": [], "per_layer": []}
 
 
+def _run(spec, seed):
+    return harness.run_cell(spec["cell"]["name"], seed, 1.0, False,
+                            rehearse=True, spec=spec, log=lambda _m: None)
+
+
+def _views_per_key_range(spec, seed, events: int, n: int) -> np.ndarray:
+    """numpy's count of the records the exchange has to deliver to each of n
+    contiguous key ranges: of the first `events` events of the run's stream
+    (the cycle, lap after lap), those that pass the view filter."""
+    cfg = spec["cfg"]
+    cycle = build_cycle(cfg["stream"], harness.rehearsal_traffic(
+        spec["traffic"]), seed, wrap=harness.batch_size_of(cfg["options"]))
+    keys = cycle.column("campaign_id").astype(np.int64)
+    view = cycle.column("event_type") < cfg["reference"]["filter"]["keep_below"]
+    laps, rest = divmod(events, cycle.events)
+    per_key = laps * np.bincount(keys[view], minlength=KEYS) + np.bincount(
+        keys[:rest][view[:rest]], minlength=KEYS)
+    return per_key.reshape(n, KEYS // n).sum(axis=1)
+
+
+@pytest.mark.parametrize("keys", ["uniform", "zipf"])
 @pytest.mark.parametrize("n", [1, 2, 4])
-def test_the_job_on_n_devices_equals_the_plain_reference(n):
-    spec = _small_spec(n)
+def test_the_job_on_n_devices_equals_the_plain_reference(n, keys):
+    spec = _small_spec(n, keys)
     assert spec["cfg"]["reference"]["module"] == "keyed_window_count"
-    out = harness.run_cell(spec["cell"]["name"], 2600000000 + n, 1.0, False,
-                           rehearse=True, spec=spec, log=lambda _m: None)
+    seed = 2600000000 + n
+    out = _run(spec, seed)
     assert out["correct"] is True, out["compared"]
-    assert out["attempted"] > 2 * KEYS and out["failed"] == 0   # > 2 windows
+    # > 2 windows; under zipf the coldest keys see no view in some windows
+    assert out["attempted"] > (2 * KEYS if keys == "uniform" else KEYS)
+    assert out["failed"] == 0
     compared = {k: c["value"] for k, c in out["compared"].items()}
     counters = out["_detail"]["counters"]
-    if n > 1:
-        assert compared["mesh_devices"] == n
-        assert compared["devices_with_records"] == n
-        assert counters["programs"]["sharded_chained_superscan"][
-            "dispatches"] >= 1
-    else:       # one device: no mesh applies, the one-chip program runs
+    if n == 1:  # one device: no mesh applies, the one-chip program runs
         assert counters["mesh_devices"] == 1
         assert counters["programs"]["fused_chained_superscan"][
             "dispatches"] >= 1
+        assert counters["per_device"] == []
+        return
+    assert compared["mesh_devices"] == n
+    assert compared["devices_with_records"] == n
+    program = counters["programs"]["sharded_chained_superscan"]
+    assert program["dispatches"] >= 1
+    # the exchange's counters: every view reached the owner of its key range
+    # once, and every device's ingest read the same lanes for them
+    per_device = counters["per_device"]
+    assert [e["device"] for e in per_device] == list(range(n))
+    want = _views_per_key_range(spec, seed, out["_detail"]["events"], n)
+    np.testing.assert_array_equal([e["routed"] for e in per_device], want)
+    assert want.sum() > out["_detail"]["events"] // 4
+    assert len({e["lanes"] for e in per_device}) == 1
+    assert per_device[0]["lanes"] >= out["_detail"]["events"]
+    share = want / want.sum()
+    if keys == "zipf":      # rank = id: the first range owns the hot keys
+        assert share[0] > (0.9 if n == 2 else 0.8) and list(
+            np.argsort(-share)) == list(range(n))
+    else:
+        assert abs(share - 1 / n).max() < 0.01
+
+
+@pytest.mark.parametrize("switch", [None, "parallel.mesh.local-combine",
+                                    "parallel.mesh.skew-rebalance"])
+def test_under_zipf_each_skew_switch_gives_the_reference_s_rows(switch):
+    """Neither switch is on in a shipped configuration; each is a choice of
+    placement or of exchange, never of result. (Through `env.execute()` the
+    second turns the owner function into a routing table and nothing more:
+    the rebalancer that would remap it is the MiniCluster's.)"""
+    spec = _small_spec(4, "zipf", **({switch: True} if switch else {}))
+    seed = 2600000014
+    out = _run(spec, seed)
+    assert out["correct"] is True, out["compared"]
+    assert out["attempted"] > KEYS and out["failed"] == 0
+    per_device = out["_detail"]["counters"]["per_device"]
+    want = _views_per_key_range(spec, seed, out["_detail"]["events"], 4)
+    np.testing.assert_array_equal([e["routed"] for e in per_device], want)
+    lanes = per_device[0]["lanes"]
+    if switch == "parallel.mesh.local-combine":
+        # the combiner's ingest reads partial cells, n * K_local * NSB a step
+        assert lanes % (4 * (KEYS // 4) * 4) == 0
+    else:
+        assert lanes >= out["_detail"]["events"]
 
 
 # ---------------------------------------------------------------------------
@@ -156,9 +229,10 @@ def test_mesh_dispatch_reads_back_only_the_fire_rows_it_used(
         handed = [got._count_out, *got._outs.values()]
         assert len(handed) == fields
         assert [a.shape for a in handed] == [(rows, K)] * fields
-        # what resolve() reads back: the used rows (+ the key bounds, i32[2])
-        assert got.nbytes == rows * K * 4 * fields + (8 if raw else 0)
-        assert got.nbytes == want.nbytes
+        # what resolve() reads back: the used rows (+ the key bounds, i32[2],
+        # and on the mesh the exchange's two counters of each shard behind them)
+        assert want.nbytes == rows * K * 4 * fields + (8 if raw else 0)
+        assert got.nbytes == want.nbytes + (8 * n if raw else 0)
         want, got = want.resolve(), got.resolve()
         assert len(got) == len(want) == fires
         for (ww, wc, wf), (gw, gc, gf) in zip(want, got):
@@ -170,6 +244,59 @@ def test_mesh_dispatch_reads_back_only_the_fire_rows_it_used(
                     np.asarray(gf[name])[live], np.asarray(wf[name])[live])
         if fires:
             assert sum(int(np.asarray(c).sum()) for _w, c, _f in got) > 0
+
+
+# ---------------------------------------------------------------------------
+# what the exchange delivered to each device, and what its ingest read
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("raw", [False, True], ids=["keyed", "traced_chain"])
+@pytest.mark.parametrize("routed", [False, True], ids=["static", "table"])
+@pytest.mark.parametrize("n", [2, 4])
+def test_per_device_routed_and_lanes_equal_a_numpy_count(n, routed, raw):
+    """`perDevice[i].routed`: the records whose key device i owns (its key
+    range, or the groups the routing table gives it) that passed the chain's
+    filter, summed over every resolved dispatch; `.lanes`: n source shards x
+    their lanes x steps, the same on every device. Both ride the key-bounds
+    vector, which only the traced-chain program reads back: the key-id program
+    (`sharded_superscan`) reports neither, and `perDevice` then lacks them."""
+    geom = dict(GEOM, prologue=_prologue("count")) if raw else GEOM
+    pipe = ShardedFusedPipeline(_mesh(n), ASSIGNER, "count",
+                                skew_routing=routed, **geom)
+    owner = np.arange(K) // (K // n)                # static: contiguous ranges
+    if routed:      # a table that is no identity: the ranges in reverse
+        assign = np.repeat(np.arange(n)[::-1], pipe.routing.G // n)
+        pipe.set_routing_assignment(assign)
+        owner = assign[np.arange(K) // pipe.routing.Kg]
+    stats = KeyStatsCollector(
+        pipe.key_loads, mesh_loads_fn=pipe.per_device_key_loads,
+        mesh_exchange_fn=pipe.per_device_exchange, interval_ms=0)
+    want = np.zeros(n, np.int64)
+    assert pipe.per_device_exchange() is None       # nothing resolved yet
+    for steps, wms in zip(_dispatches(), WATERMARKS):
+        deferred = _feed(pipe, steps, wms, raw)
+        before = pipe.exchange_totals.copy()
+        deferred.resolve()                          # counted at resolve, once
+        for rec, _none, _ts in steps:
+            keep = rec[:, 2] < 0.5
+            want += np.bincount(owner[rec[keep, 0].astype(np.int64)],
+                                minlength=n)
+        if raw:
+            assert (pipe.exchange_totals > before).all()
+    assert stats.collect()
+    per_device = stats.payload()["perDevice"]
+    assert [e["device"] for e in per_device] == list(range(n))
+    assert all(e["records"] > 0 for e in per_device)
+    if not raw:
+        assert pipe.per_device_exchange() is None
+        assert not any("routed" in e or "lanes" in e for e in per_device)
+        return
+    assert [e["routed"] for e in per_device] == list(want)
+    # the staging loop pads a step of 300 lanes to its bucket of 512, dealt
+    # over the n source shards; every device ingests all n shards' lanes
+    lanes = n * (512 // n) * STEPS * len(FIRES)
+    assert [e["lanes"] for e in per_device] == [lanes] * n
+    assert 0 < want.sum() < lanes
 
 
 # ---------------------------------------------------------------------------
@@ -194,7 +321,8 @@ def test_the_deal_has_a_stage_of_its_own_and_the_link_counts_used_rows():
     assert table["stage.shard"]["ms"] < table["stage.fill"]["ms"]
     link = clock.link()
     assert link["eventsStaged"] == n * STEPS * BATCH
-    assert link["d2hBytes"] == sum(_ceil16(f) * K * 4 + 8 for f in FIRES)
+    assert link["d2hBytes"] == sum(
+        _ceil16(f) * K * 4 + 8 + 8 * 4 for f in FIRES)
     # at the cell's shape, 2^21 events and at most 16 rows of 65 536 keys a
     # dispatch: under 4 B per event where all R = 256 rows read 32
     assert _ceil16(1) * 65_536 * 4 / 2 ** 21 < 4 < 256 * 65_536 * 4 / 2 ** 21
