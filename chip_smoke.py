@@ -349,9 +349,12 @@ def leg3(leg1_got, leg1_stream):
         raise AssertionError(f"leg 3: job reports mesh {facts['mesh_devices']}")
     # only the fire rows a dispatch used come back from the four shards
     K = op.pipe.K
+    n = op.mesh_devices()
     for fires, rows, nbytes in readbacks:
         used = -(-max(fires, 1) // 16) * 16
-        if rows > used or nbytes > used * K * 4 + 64:
+        # beside the rows: the key bounds (8 B) and, per shard, the
+        # exchange's two counters and the three phase counters (20 B)
+        if rows > used or nbytes > used * K * 4 + 8 + n * 20:
             raise AssertionError(
                 f"leg 3: a dispatch with {fires} fires reads back {rows} "
                 f"rows, {nbytes} B: more than {used} rows of {K} keys")

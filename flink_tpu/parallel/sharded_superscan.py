@@ -62,6 +62,7 @@ ICI.
 
 from __future__ import annotations
 
+import functools
 import weakref
 from typing import Any, Dict, Optional
 
@@ -124,6 +125,7 @@ class ShardedFusedPipeline:
 
         self.mesh = mesh
         self.axis = axis
+        self._replicated = NamedSharding(mesh, P())
         self.n = mesh.shape[axis]
         if key_capacity % self.n != 0:
             raise ValueError(
@@ -235,10 +237,12 @@ class ShardedFusedPipeline:
     # dispatch hot path (callers resolve in-flight dispatches first).
     # ------------------------------------------------------------------
     def _refresh_route_tables(self) -> None:
+        # replicated over the mesh, as the programs that gather from them
+        # take them: a dispatch finds them where it reads them
         r = self.routing
-        self._g_dst = jnp.asarray(r.assign, jnp.int32)
-        self._g_slot = jnp.asarray(r.slot, jnp.int32)
-        self._perm_dev = jnp.asarray(r.perm, jnp.int32)
+        self._g_dst, self._g_slot, self._perm_dev = jax.device_put(
+            tuple(np.asarray(a, np.int32)
+                  for a in (r.assign, r.slot, r.perm)), self._replicated)
 
     def routing_version(self) -> Optional[int]:
         return None if self.routing is None else self.routing.version
@@ -463,7 +467,9 @@ class ShardedFusedPipeline:
                 owner = self._dst_and_local((g_dst, g_slot))
             else:
                 owner = self._dst_and_local(None)
-            smin_pos, fire_pos, fire_valid, fire_row, purge_mask = rest
+            (plan,) = rest
+            smin_pos, fire_pos, fire_valid, fire_row, purge_mask = \
+                self._split_plan(plan)
             # leading mesh dim is 1 inside the shard
             count = count[0]
             idx = idx[0]
@@ -558,8 +564,7 @@ class ShardedFusedPipeline:
             (P(axis, None, None),) * nf,              # field states
             P(axis, None, None),                      # idx [n,T,B]
             P(axis, None, None) if nf else P(None, None),  # vals
-            P(None), P(None, None), P(None, None), P(None, None),
-            P(None, None),                            # plan (replicated)
+            P(None, None),                 # plan [T, 1+3F+S] (replicated)
         )
         if routed:
             in_specs = in_specs + (P(None), P(None))  # routing tables [G]
@@ -581,23 +586,39 @@ class ShardedFusedPipeline:
         return fn
 
     # ------------------------------------------------------------------
-    def _place(self, payload, xs_h, lanes):
+    def _place(self, payload, xs_h, lanes, plan_np):
         """Placement on the mesh (`FusedWindowPipeline.stage` calls it
         inside stage.fill): a step's lanes are dealt contiguously over the
         n source shards (any split works — the in-scan all-to-all
         re-routes every record to its key owner), and `device_put` hands
         each device its own lanes: nothing is first committed whole to
         device 0. Arrays without lanes (a value-less aggregate's [T, 1]
-        placeholder) are replicated."""
+        placeholder) and the plan are replicated over the mesh, as the two
+        programs' `in_specs` name them: every argument of a mesh dispatch
+        arrives committed where the program reads it, and the call copies
+        nothing from device to device. The five plan arrays go side by side
+        as ONE [T, 1 + 3F + S] array (`_split_plan` inside the program): a
+        replicated array is a transfer per device, and five of them cost
+        `stage.put` 1.5 ms a dispatch on four chips (PERF.md, PR 31)."""
         with dispatch_stage(self.stage_clock, "stage.shard"):
             # the first array's -1 marks a dead lane: pad lanes are dead
             xs_h = tuple(
                 self._deal_lanes(a, 0 if i else -1) if i < lanes else a
                 for i, a in enumerate(xs_h))
-        return xs_h, tuple(
+            smin_pos, *rest = plan_np
+            plan = np.concatenate([smin_pos[:, None], *rest], axis=1)
+        return xs_h, (plan,), (tuple(
             self._shard_spec(*([None] * (a.ndim - 1))) if i < lanes
-            else NamedSharding(self.mesh, P())
-            for i, a in enumerate(xs_h))
+            else self._replicated
+            for i, a in enumerate(xs_h)), self._replicated)
+
+    def _split_plan(self, plan):
+        """[T, 1 + 3F + S] -> smin_pos [T], fire_pos / fire_valid /
+        fire_row [T, F], purge_mask [T, S]: `_place`'s packing undone,
+        inside the program."""
+        F = self.F
+        return (plan[:, 0], plan[:, 1:1 + F], plan[:, 1 + F:1 + 2 * F],
+                plan[:, 1 + 2 * F:1 + 3 * F], plan[:, 1 + 3 * F:])
 
     def _deal_lanes(self, a: np.ndarray, fill) -> np.ndarray:
         """[T, B, ...] -> [n, T, Bs, ...]: every step's lanes dealt
@@ -617,26 +638,15 @@ class ShardedFusedPipeline:
 
     def _canonical_fire_rows(self, count_out, outs, fired):
         """[n, R, K_local] per-shard fire slabs -> [used, K] canonical key
-        order, `used` the rows the dispatch's `fired` fires filled (sliced
-        on each shard first: what follows, and the deferred readback, move
-        only those): contiguous ranges concatenate; a routing table
-        additionally permutes columns (one deferred device gather — the
-        rows ride the same async readback either way)."""
-        from flink_tpu.runtime.fused_window_pipeline import _used_fire_rows
+        order, `used` the rows the dispatch's `fired` fires filled: one
+        enqueue of `_fire_shaper`'s program for the count slab and every
+        field's (the deferred readback moves only those rows)."""
+        from flink_tpu.runtime.fused_window_pipeline import _used_rows
 
-        count_out, outs = _used_fire_rows(count_out, outs, fired, axis=1)
-        used = count_out.shape[1]
-        count_rows = jnp.transpose(count_out, (1, 0, 2)).reshape(
-            used, self.K)
-        out_rows = {
-            nm: jnp.transpose(o, (1, 0, 2)).reshape(used, self.K)
-            for nm, o in outs.items()
-        }
-        if self.routing is not None:
-            count_rows = jnp.take(count_rows, self._perm_dev, axis=1)
-            out_rows = {nm: jnp.take(o, self._perm_dev, axis=1)
-                        for nm, o in out_rows.items()}
-        return count_rows, out_rows
+        used = min(_used_rows(fired), count_out.shape[1])
+        rows = _fire_shaper(used)(
+            (count_out, *outs.values()), self._perm_dev)
+        return rows[0], dict(zip(outs, rows[1:]))
 
     # ------------------------------------------------------------------
     # traced-chain path (whole-graph fusion over the mesh): every shard
@@ -681,7 +691,9 @@ class ShardedFusedPipeline:
                 ts, rest = rest[0][0], rest[1:]
             else:
                 ts = None
-            smin_pos, fire_pos, fire_valid, fire_row, purge_mask = rest
+            (plan,) = rest
+            smin_pos, fire_pos, fire_valid, fire_row, purge_mask = \
+                self._split_plan(plan)
             state = {
                 f.name: state_t[i][0]
                 for i, f in enumerate(self._value_fields)
@@ -804,8 +816,7 @@ class ShardedFusedPipeline:
         if needs_ts:
             in_specs = in_specs + (P(axis, None, None),)  # ts [n,T,Bs]
         in_specs = in_specs + (
-            P(None), P(None, None), P(None, None), P(None, None),
-            P(None, None),                                # plan (replicated)
+            P(None, None),                 # plan [T, 1+3F+S] (replicated)
         )
         if routed:
             in_specs = in_specs + (P(None), P(None))      # routing tables
@@ -979,6 +990,28 @@ class ShardedFusedPipeline:
             self._planner.fire_cursors = list(snap["fire_cursors"])
 
 
+@functools.lru_cache(maxsize=None)
+def _fire_shaper(used: int):
+    """The program that shapes a mesh dispatch's fire slabs for the
+    readback, one per `used` rows as `_row_slicer` is one per slice (and,
+    inside jit's own cache, one with a routing table and one without):
+    each [n, R, K_local] slab is cut to its first `used` rows on every
+    shard, the contiguous key ranges are concatenated to [used, K], and
+    under a routing table the columns are permuted back to canonical key
+    order (`perm`; None without a table)."""
+
+    def shape_fire_rows(slabs, perm):
+        def canonical(slab):
+            n, _, k_local = slab.shape
+            rows = jnp.transpose(slab[:, :used], (1, 0, 2)).reshape(
+                used, n * k_local)
+            return rows if perm is None else jnp.take(rows, perm, axis=1)
+
+        return tuple(canonical(slab) for slab in slabs)
+
+    return jax.jit(shape_fire_rows)
+
+
 class _MeshProgram:
     """One of the two sharded window programs as
     `FusedWindowPipeline.dispatch` uses it (the contract of its
@@ -1014,9 +1047,9 @@ class _MeshProgram:
             self.name, run, args, {"T": T, "B": B, "n": p.n, **record_sig})
         p._state = dict(zip(names, states))
         key_bounds = tail.pop(0) if self.chained else None
-        # phase counters [n, 3]: fold the shard axis on device
+        # phase counters [n, 3]: read back per shard, folded at resolve
         return (count_out, dict(zip(names, field_outs)), key_bounds,
-                tail[0].sum(axis=0) if tail else None)
+                tail[0] if tail else None)
 
     def fire_rows(self, p, count_out, outs, fired: int):
         return p._canonical_fire_rows(count_out, outs, fired)

@@ -242,9 +242,10 @@ class DeferredEmissions:
         # int32: [max_seen, min_seen], then on a mesh (routed, lanes) per shard
         self._key_bounds = key_bounds
         self._key_capacity = key_capacity
-        # int32[3] per-phase step counters of this dispatch (device-plane
-        # observability); folded into the pipeline's totals at resolve so
-        # the readback rides the same async copy as the fire rows
+        # int32[3] per-phase step counters of this dispatch, [n, 3] from a
+        # mesh (device-plane observability); folded into the pipeline's
+        # totals at resolve so the readback rides the same async copy as
+        # the fire rows
         self._phase_counts = phase_counts
         #: bytes resolve() reads back (the stage clock's d2hBytes)
         self.nbytes = sum(
@@ -265,7 +266,7 @@ class DeferredEmissions:
     def resolve(self):
         if self._phase_counts is not None:
             self._pipe.phase_totals += np.asarray(
-                self._phase_counts, dtype=np.int64)
+                self._phase_counts, dtype=np.int64).reshape(-1, 3).sum(axis=0)
             self._phase_counts = None
         if self._key_bounds is not None:
             bounds = np.asarray(self._key_bounds)
@@ -432,7 +433,8 @@ class Staged(NamedTuple):
     xs: its arrays; the first is the one whose -1 marks a dead lane: key
       ids (idx, vals); a record (srel, its staged fields or the record
       array[, ts]).
-    plan: smin_pos, fire_pos, fire_valid, fire_row, purge_mask.
+    plan: smin_pos, fire_pos, fire_valid, fire_row, purge_mask; on a mesh
+      the five side by side in one [T, 1 + 3F + S] array (`_place`).
     layout: how a rank-1 record was split into fields (None: it was not)."""
 
     payload: Any
@@ -1177,10 +1179,10 @@ class FusedWindowPipeline:
         with dispatch_stage(clock, "stage.fill"):
             xs_h, lanes, layout, plan_np, fires = self._fill(
                 payload, steps, watermarks)
-            xs_h, shardings = self.deployment._place(payload, xs_h, lanes)
+            xs_h, plan_h, shardings = self.deployment._place(
+                payload, xs_h, lanes, plan_np)
         with dispatch_stage(clock, "stage.put"):
-            xs = jax.device_put(xs_h, shardings)
-            plan = jax.device_put(plan_np)
+            xs, plan = jax.device_put((xs_h, plan_h), shardings)
             if clock is not None:
                 clock.staged(xs_h + plan_np, sum(len(s[2]) for s in steps),
                              payload.columns(self, layout))
@@ -1220,16 +1222,16 @@ class FusedWindowPipeline:
         return (xs_h, lanes, layout,
                 (smin_pos, fire_pos, fire_valid, fire_row, purge_mask), fires)
 
-    def _place(self, payload, xs_h, lanes):
-        """Placement: the host arrays as they are handed to
-        `jax.device_put`, and their shardings (None: all on the default
-        device). The pallas kernel consumes flat [T*B] lane streams;
-        flattening on host is free (the arrays are contiguous), so no
-        device reshape is needed."""
+    def _place(self, payload, xs_h, lanes, plan_np):
+        """Placement: the payload's host arrays and the plan as they are
+        handed to `jax.device_put`, and the shardings of the two (None: all
+        on the default device). The pallas kernel consumes flat [T*B] lane
+        streams; flattening on host is free (the arrays are contiguous), so
+        no device reshape is needed."""
         if self._program(payload).flat:
             xs_h = tuple(a.reshape(-1) if i < lanes else a
                          for i, a in enumerate(xs_h))
-        return xs_h, None
+        return xs_h, plan_np, None
 
     def _program(self, payload):
         if payload.record:
@@ -1424,26 +1426,32 @@ class FusedWindowPipeline:
 
 
 @functools.lru_cache(maxsize=None)
-def _row_slicer(n: int, axis: int):
+def _row_slicer(n: int):
     import jax
 
-    return jax.jit(lambda b: b[(slice(None),) * axis + (slice(n),)])
+    return jax.jit(lambda b: b[:n])
 
 
-def _slice_rows(buf, n: int, axis: int = 0):
-    return _row_slicer(n, axis)(buf)
+def _slice_rows(buf, n: int):
+    return _row_slicer(n)(buf)
 
 
-def _used_fire_rows(count_out, outs, fired: int, axis: int = 0):
+def _used_rows(fired: int) -> int:
+    """Rows a dispatch of `fired` fires is read back at: padded to a few
+    stable shapes, so the program that cuts them is reused across
+    dispatches."""
+    return -(-max(fired, 1) // 16) * 16
+
+
+def _used_fire_rows(count_out, outs, fired: int):
     """Read back only the fire rows a dispatch used: rows are assigned in
-    fire order, so `fired` fires fill the first `fired` of the R rows along
-    `axis` (padded to a few stable shapes so the slice executable is reused
-    across dispatches). The mesh's per-shard slabs [n, R, K_local] carry
-    their rows on axis 1."""
-    used = -(-max(fired, 1) // 16) * 16
-    if used < count_out.shape[axis]:
-        count_out = _slice_rows(count_out, used, axis)
-        outs = {k: _slice_rows(v, used, axis) for k, v in outs.items()}
+    fire order, so `fired` fires fill the first `fired` of the R rows. (The
+    mesh cuts its per-shard slabs inside its own fire-shape program:
+    `sharded_superscan._fire_shaper`.)"""
+    used = _used_rows(fired)
+    if used < count_out.shape[0]:
+        count_out = _slice_rows(count_out, used)
+        outs = {k: _slice_rows(v, used) for k, v in outs.items()}
     return count_out, outs
 
 

@@ -10,6 +10,9 @@ sizes on the virtual CPU mesh.
   that device's ingest read for it (`.lanes`), against a numpy count;
 - the fire rows a mesh dispatch hands to the deferred readback: only the rows
   its fires used, on both dispatch paths, with and without a routing table;
+- the mesh's enqueue: every argument of a warm dispatch sits where the program
+  reads it (no device-to-device copy, the plan replicated over the mesh), and
+  one program per fire shape cuts, concatenates and permutes the fire slabs;
 - the stage clock on the mesh: the deal's own stage, the link's bytes back;
 - the lane deal over shards that do not divide the batch.
 """
@@ -19,14 +22,20 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from benchmarks import harness
 from benchmarks.stream import build_cycle
 from flink_tpu.api.windowing.assigners import SlidingEventTimeWindows
 from flink_tpu.metrics.key_stats import KeyStatsCollector
 from flink_tpu.metrics.task_io import StageClock
-from flink_tpu.parallel.sharded_superscan import ShardedFusedPipeline
+from flink_tpu.metrics.device_stats import CompileTracker
+from flink_tpu.parallel.sharded_superscan import (
+    _MESH_CHAINED,
+    _MESH_CLASSIC,
+    ShardedFusedPipeline,
+    _fire_shaper,
+)
 from flink_tpu.runtime.fused_window_pipeline import (
     FusedWindowPipeline,
     TracedPrologue,
@@ -170,12 +179,12 @@ GEOM = dict(key_capacity=K, num_slices=128, nsb=4, fires_per_step=4,
             out_rows=R, chunk=512)
 
 
-def _dispatches(seed=3):
+def _dispatches(seed=3, count=len(FIRES)):
     """[(records [BATCH, 3] f32: key, value, flag; None; ts)] per step, per
     dispatch; every record lies ahead of every watermark."""
     rng = np.random.default_rng(seed)
     out = []
-    for d in range(len(FIRES)):
+    for d in range(count):
         steps = []
         for s in range(STEPS):
             rec = np.stack([rng.integers(0, K, BATCH),
@@ -195,13 +204,23 @@ def _prologue(aggregate):
         value_fn=(lambda col: col[:, 1]) if aggregate == "sum" else None)
 
 
+def _steps_for(steps, raw: bool):
+    """The steps as the path under test takes them: records, or key ids
+    and values."""
+    if raw:
+        return steps
+    return [(rec[:, 0].astype(np.int32), rec[:, 1], ts)
+            for rec, _none, ts in steps]
+
+
+def _reversed_ranges(pipe):
+    """A routing table that is no identity: the key ranges in reverse."""
+    return np.repeat(np.arange(pipe.n)[::-1], pipe.routing.G // pipe.n)
+
+
 def _feed(pipe, steps, wms, raw: bool):
     """One deferred dispatch through the path under test."""
-    if raw:
-        return pipe.process_superbatch(steps, wms, defer=True)
-    batches = [(rec[:, 0].astype(np.int32), rec[:, 1], ts)
-               for rec, _none, ts in steps]
-    return pipe.process_superbatch(batches, wms, defer=True)
+    return pipe.process_superbatch(_steps_for(steps, raw), wms, defer=True)
 
 
 def _ceil16(fires):
@@ -218,9 +237,8 @@ def test_mesh_dispatch_reads_back_only_the_fire_rows_it_used(
     single = FusedWindowPipeline(ASSIGNER, aggregate, backend="xla", **geom)
     sharded = ShardedFusedPipeline(_mesh(n), ASSIGNER, aggregate,
                                    skew_routing=routed, **geom)
-    if routed:      # a table that is no identity: the columns are permuted
-        sharded.set_routing_assignment(
-            np.repeat(np.arange(n)[::-1], sharded.routing.G // n))
+    if routed:      # the columns are permuted
+        sharded.set_routing_assignment(_reversed_ranges(sharded))
     fields = 1 + (aggregate == "sum")
     for steps, wms, fires in zip(_dispatches(), WATERMARKS, FIRES):
         want, got = (_feed(p, steps, wms, raw) for p in (single, sharded))
@@ -247,6 +265,127 @@ def test_mesh_dispatch_reads_back_only_the_fire_rows_it_used(
 
 
 # ---------------------------------------------------------------------------
+# the mesh's enqueue: where a dispatch's arguments sit, and the one program
+# that shapes its fire rows
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("aggregate", ["count", "sum"])
+@pytest.mark.parametrize("routed", [False, True], ids=["static", "table"])
+@pytest.mark.parametrize("raw", [False, True], ids=["keyed", "traced_chain"])
+def test_a_warm_mesh_dispatch_copies_nothing_from_device_to_device(
+        raw, routed, aggregate):
+    """An explicit `device_put` passes the guard; what it catches is the
+    implicit reshard of an argument that was put somewhere else than the
+    program's `in_specs` say (the plan on device 0, as it was, or a routing
+    table)."""
+    n = 4
+    mesh = _mesh(n)
+    geom = dict(GEOM, prologue=_prologue(aggregate)) if raw else GEOM
+    single = FusedWindowPipeline(ASSIGNER, aggregate, backend="xla", **geom)
+    pipe = ShardedFusedPipeline(mesh, ASSIGNER, aggregate,
+                                skew_routing=routed, **geom)
+    if routed:
+        pipe.set_routing_assignment(_reversed_ranges(pipe))
+    replicated = NamedSharding(mesh, P())
+    (warm, warm_wms), *rest = zip(_dispatches(), WATERMARKS)
+    for p in (single, pipe):
+        _feed(p, warm, warm_wms, raw).resolve()
+    for steps, wms in rest:
+        with jax.transfer_guard_device_to_device("disallow"):
+            staged = pipe.stage(_steps_for(steps, raw), wms)
+            got = pipe.dispatch(staged, defer=True)
+        assert pipe._program(staged.payload) is (
+            _MESH_CHAINED if raw else _MESH_CLASSIC)
+        # the five plan arrays side by side, one replicated array
+        (plan,) = staged.plan
+        assert plan.shape == (STEPS, 1 + 3 * pipe.F + pipe.S)
+        assert plan.committed
+        assert plan.sharding.is_equivalent_to(replicated, plan.ndim)
+        assert len(plan.addressable_shards) == n
+        # the lanes: dealt over the source shards; a count's [T, 1]
+        # placeholder of values has none and is replicated with the plan
+        lanes = len(staged.xs) if raw or aggregate == "sum" else 1
+        for i, a in enumerate(staged.xs):
+            assert a.committed
+            want = NamedSharding(mesh, P("shards")) if i < lanes else replicated
+            assert a.sharding.is_equivalent_to(want, a.ndim)
+        want = _feed(single, steps, wms, raw).resolve()
+        got = got.resolve()
+        assert len(got) == len(want)
+        for (_ww, wc, wf), (_gw, gc, gf) in zip(want, got):
+            np.testing.assert_array_equal(gc, wc)
+            live = np.asarray(wc) > 0
+            for name in wf:
+                np.testing.assert_array_equal(
+                    np.asarray(gf[name])[live], np.asarray(wf[name])[live])
+
+
+@pytest.mark.parametrize("routed", [False, True], ids=["static", "table"])
+@pytest.mark.parametrize("field", [False, True], ids=["count", "sum"])
+@pytest.mark.parametrize("fired", FIRES)
+@pytest.mark.parametrize("n", [2, 4])
+def test_the_fire_shape_program_equals_numpy_on_the_per_shard_slabs(
+        n, fired, field, routed):
+    pipe = ShardedFusedPipeline(_mesh(n), ASSIGNER,
+                                "sum" if field else "count",
+                                skew_routing=routed, **GEOM)
+    if routed:
+        pipe.set_routing_assignment(_reversed_ranges(pipe))
+    rng = np.random.default_rng(31 + fired)
+    names = [f.name for f in pipe._value_fields]
+    assert len(names) == int(field)
+    slabs = [rng.integers(0, 1 << 20, (n, R, K // n)).astype(np.int32)] + [
+        rng.normal(size=(n, R, K // n)).astype(np.float32) for _ in names]
+    on_mesh = jax.device_put(slabs, pipe._shard_spec(None, None))
+    with jax.transfer_guard_device_to_device("disallow"):
+        count_rows, out_rows = pipe._canonical_fire_rows(
+            on_mesh[0], dict(zip(names, on_mesh[1:])), fired)
+    assert list(out_rows) == names
+    used = _ceil16(fired)
+    for got, slab in zip([count_rows, *out_rows.values()], slabs):
+        want = np.transpose(slab[:, :used], (1, 0, 2)).reshape(used, K)
+        if routed:
+            want = np.take(want, pipe.routing.perm, axis=1)
+        got = np.asarray(got)
+        assert (got.shape, got.dtype) == (want.shape, want.dtype)
+        assert got.tobytes() == want.tobytes()
+
+
+@pytest.mark.parametrize("routed", [False, True], ids=["static", "table"])
+def test_one_fire_shape_program_per_used_rows_over_a_steady_run(routed):
+    """The first four dispatches fire 0, 1, 16 and 17 windows (16 and 32 rows
+    read back), every later one 16: nothing is built after them, neither a
+    fire-shape program nor a window program."""
+    pipe = ShardedFusedPipeline(_mesh(4), ASSIGNER, "count",
+                                skew_routing=routed,
+                                **dict(GEOM, prologue=_prologue("count")))
+    tracker = CompileTracker()
+    pipe.attach_device_stats(tracker)
+    dispatches = _dispatches(count=12)
+    watermarks = list(WATERMARKS)
+    while len(watermarks) < len(dispatches):
+        top = watermarks[-1][-1]
+        watermarks.append([top + 1000 * min(s + 1, 4) for s in range(STEPS)])
+    fires = []
+    for d, (steps, wms) in enumerate(zip(dispatches, watermarks)):
+        got = pipe.process_superbatch(steps, wms, defer=True)
+        fires.append(len(got._fires))
+        got.resolve()
+        if d == len(FIRES) - 1:
+            built = _fire_shaper.cache_info().misses
+            compiles = tracker.num_compiles
+            sizes = [_fire_shaper(u)._cache_size() for u in (16, 32)]
+    assert tuple(fires) == FIRES + (16,) * (len(dispatches) - len(FIRES))
+    assert _fire_shaper.cache_info().misses == built
+    assert tracker.num_compiles == compiles
+    # jit's own cache under each `used`: one entry per slab shape, with a
+    # table or without
+    assert [_fire_shaper(u)._cache_size() for u in (16, 32)] == sizes
+    assert min(sizes) >= 1
+    assert pipe.phase_totals[0] > 0     # folded over the shards at resolve
+
+
+# ---------------------------------------------------------------------------
 # what the exchange delivered to each device, and what its ingest read
 # ---------------------------------------------------------------------------
 
@@ -264,8 +403,8 @@ def test_per_device_routed_and_lanes_equal_a_numpy_count(n, routed, raw):
     pipe = ShardedFusedPipeline(_mesh(n), ASSIGNER, "count",
                                 skew_routing=routed, **geom)
     owner = np.arange(K) // (K // n)                # static: contiguous ranges
-    if routed:      # a table that is no identity: the ranges in reverse
-        assign = np.repeat(np.arange(n)[::-1], pipe.routing.G // n)
+    if routed:
+        assign = _reversed_ranges(pipe)
         pipe.set_routing_assignment(assign)
         owner = assign[np.arange(K) // pipe.routing.Kg]
     stats = KeyStatsCollector(

@@ -216,9 +216,16 @@ def test_every_program_is_staged_by_one_loop_and_fires_at_parity(program):
         np.testing.assert_array_equal(
             _host_view(program, staged.xs[0], xs_h[0].shape), xs_h[0])
         # the plan reads timestamps alone: equal under every payload,
-        # placement and program
+        # placement and program (the mesh's placement lays the five side
+        # by side in one array, which its programs split again)
         ref = plain.stage(_steps_for("fused_superscan", steps), wms)
-        for a, b in zip(staged.plan, ref.plan):
+        plan = staged.plan
+        if program in MESH:
+            (packed,) = plan
+            assert packed.shape == (T, 1 + 3 * pipe.F + pipe.S)
+            plan = pipe._split_plan(np.asarray(packed))
+        assert len(plan) == len(ref.plan) == 5
+        for a, b in zip(plan, ref.plan):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
         assert [dataclasses.astuple(f) for f in staged.fires] \
             == [dataclasses.astuple(f) for f in ref.fires]

@@ -307,9 +307,38 @@ def test_sharded_chained_superscan_compiles_on_the_2x2_mesh(v5e, monkeypatch):
         on_mesh((n, K // n, S), i32, "shards"), (),
         (on_mesh((n, T, B // n), jnp.float32, "shards"),) * 2,
         on_mesh((n, T, B // n), i32, "shards"),
-        on_mesh((T,), i32), on_mesh((T, F), i32), on_mesh((T, F), i32),
-        on_mesh((T, F), i32), on_mesh((T, S), i32),
+        on_mesh((T, 1 + 3 * F + S), i32),       # the plan, side by side
     ).lower().compile()
+
+
+@pytest.mark.parametrize("routed", [False, True], ids=["static", "table"])
+def test_fire_shape_program_compiles_on_the_2x2_mesh(v5e, routed):
+    """The program a mesh dispatch enqueues after its window program, at the
+    mesh cells' shape: four [256, 16 384] slabs cut to 16 rows and laid side
+    by side. Without a routing table every chip keeps its own columns: no
+    collective, the readback's [16, 65 536] sharded over the key axis."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from flink_tpu.parallel.sharded_superscan import _fire_shaper
+
+    mesh = Mesh(np.array(v5e.devices), ("shards",))
+    n, K, R, used = 4, 1 << 16, EXEC["R"], 16
+
+    def on_mesh(shape, *spec):
+        return jax.ShapeDtypeStruct(
+            shape, jnp.int32, sharding=NamedSharding(mesh, P(*spec)))
+
+    compiled = _fire_shaper(used).trace(
+        (on_mesh((n, R, K // n), "shards"),),
+        on_mesh((K,)) if routed else None).lower().compile()
+    (rows,) = compiled.output_shardings
+    if not routed:
+        assert rows.is_equivalent_to(NamedSharding(mesh, P(None, "shards")), 2)
+        hlo = compiled.as_text()
+        for op in ("all-gather", "all-reduce", "all-to-all",
+                   "collective-permute"):
+            assert op not in hlo, op
 
 
 def test_compile_cache_placement(monkeypatch, tmp_path):
