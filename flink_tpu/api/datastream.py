@@ -16,6 +16,7 @@ from typing import Any, Callable, List, Optional, Sequence, Union
 from flink_tpu.api.functions import (
     AggregateFunction,
     as_key_selector,
+    null_key,
 )
 from flink_tpu.api.windowing.assigners import WindowAssigner
 from flink_tpu.api.windowing.triggers import Trigger
@@ -25,6 +26,7 @@ from flink_tpu.core.watermarks import WatermarkStrategy
 from flink_tpu.graph.transformation import Transformation, plan
 from flink_tpu.connectors.source import CollectionSource, Source
 from flink_tpu.connectors.sink import CollectSink, Sink
+from flink_tpu.ops.aggregators import max_by_agg, min_by_agg
 
 
 class StreamExecutionEnvironment:
@@ -318,6 +320,16 @@ class DataStream:
              "traceable": traceable},
         )
         return KeyedStream(self.env, t)
+
+    def window_all(self, assigner: WindowAssigner) -> "AllWindowedStream":
+        """A window over the WHOLE stream (DataStream.windowAll /
+        AllWindowedStream.java): every record falls under one null key, at
+        parallelism 1, and the window's rows are emitted bare (no key in
+        front). It is `key_by(<null key>).window(assigner)`; where the
+        stream is a fused window's fires and the aggregate is `max_by` /
+        `min_by`, each fire is reduced as columns on its way in
+        (docs/windows.md)."""
+        return AllWindowedStream(self, assigner)
 
     # -- sinks -------------------------------------------------------------
     def sink_to(self, sink: Sink, name: str = "sink") -> "DataStreamSink":
@@ -633,6 +645,26 @@ class WindowedStream:
     def min(self, value_fn: Optional[Callable] = None) -> DataStream:
         return self.aggregate("min", value_fn, name="window_min")
 
+    def max_by(self, position: int) -> DataStream:
+        """The window's row whose field `position` is largest, whole
+        (WindowedStream.maxBy(int)); the first to arrive among equals."""
+        return self.aggregate(max_by_agg(position), name="window_max_by")
+
+    def min_by(self, position: int) -> DataStream:
+        """The window's row whose field `position` is smallest, whole
+        (WindowedStream.minBy(int)); the first to arrive among equals."""
+        return self.aggregate(min_by_agg(position), name="window_min_by")
+
     def process(self, window_fn, name: str = "window_process") -> DataStream:
         """Buffered window with ProcessWindowFunction (no pre-aggregation)."""
         return self._agg_transform(None, None, window_fn, name)
+
+
+class AllWindowedStream(WindowedStream):
+    """`DataStream.window_all`: the windowed stream of one null key. Every
+    builder of WindowedStream applies; what the window emits has no key,
+    so downstream receives the bare result."""
+
+    def __init__(self, stream: DataStream, assigner: WindowAssigner):
+        super().__init__(stream.key_by(null_key, name="window_all"),
+                         assigner)

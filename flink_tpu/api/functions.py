@@ -24,4 +24,5 @@ from flink_tpu.core.functions import (  # noqa: F401
     ReduceFunction,
     as_key_selector,
     as_reduce_function,
+    null_key,
 )

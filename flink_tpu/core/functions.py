@@ -138,6 +138,14 @@ class KeySelector(Generic[IN, KEY]):
         raise NotImplementedError
 
 
+def null_key(_value) -> None:
+    """The key selector of a window over the whole stream
+    (NullByteKeySelector.java): every record has the one key None. A window
+    step keyed by THIS function knows its rows share a key without looking
+    at them (runtime/executor.py)."""
+    return None
+
+
 def as_key_selector(fn) -> Callable[[Any], Any]:
     if isinstance(fn, KeySelector):
         return fn.get_key

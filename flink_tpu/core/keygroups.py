@@ -61,6 +61,8 @@ def java_hash_string(s: Union[str, bytes]) -> int:
 def key_hash(key) -> int:
     """hashCode-equivalent for supported key types; tuples combine like
     java.util.Arrays.hashCode."""
+    if key is None:
+        return 0    # Objects.hashCode(null): the one key of a window_all
     if isinstance(key, bool):
         return 1231 if key else 1237
     if isinstance(key, (int, np.integer)):

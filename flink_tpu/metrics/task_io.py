@@ -128,7 +128,7 @@ class TaskIOMetrics:
 STAGES = (
     "source.poll", "source.watermark", "chain.host", "keys.lookup",
     "normalize", "stage.fill", "stage.shard", "stage.put", "dispatch",
-    "resolve", "emit", "drain", "sink.write", "keys.stats",
+    "resolve", "emit", "drain", "fire.reduce", "sink.write", "keys.stats",
 )
 SPAN_PREFIX = "flink_tpu."
 
@@ -237,6 +237,10 @@ class StageClock:
         # fires appended to an output lane, one block each (fire_block.py):
         # rowsEmitted / fireBlocks = rows per fire
         self.fire_blocks = 0
+        # rows of fire blocks that a null-key window behind the operator
+        # reduced as columns (stage fire.reduce), and rows that came out
+        self.fire_rows_reduced = 0
+        self.fire_rows_kept = 0
         # data steps staged, by how their slice plan was made: from the
         # step's two timestamp extremes, or per record under a late mask
         self.steps_planned_scalar = 0
@@ -292,7 +296,9 @@ class StageClock:
                 "columnsStaged": self.columns_staged,
                 "recordColumns": self.record_columns,
                 "rowsEmitted": self.rows_emitted,
-                "fireBlocks": self.fire_blocks, "dispatches": self.seq,
+                "fireBlocks": self.fire_blocks,
+                "fireRowsReduced": self.fire_rows_reduced,
+                "fireRowsKept": self.fire_rows_kept, "dispatches": self.seq,
                 "stepsPlannedScalar": self.steps_planned_scalar,
                 "stepsPlannedMasked": self.steps_planned_masked}
 

@@ -20,6 +20,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
+import operator
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import numpy as np
@@ -265,9 +266,62 @@ def decomposable(agg: DeviceAggregator) -> bool:
     )
 
 
+class PositionalAggregate(AggregateFunction):
+    """`max_by(position)` / `min_by(position)` (Flink's `maxBy(int)` /
+    `minBy(int)`): keeps the WHOLE row whose field at `position` is the
+    window's extreme. Tie rule, stated: **the first row to arrive among
+    equals stays** (Flink's `first = true`). A fused fire hands its rows
+    over in ascending key id, so among equal counts of one fire the lowest
+    id wins.
+
+    Not a `DeviceAggregator` (its result is a row, not a numeric field):
+    as an `AggregateFunction` it runs on the oracle window operator row by
+    row, and `pick` is the same rule over a whole column, which is how a
+    null-key window reduces a `FireBlock` without building its rows
+    (runtime/fire_block.reduce_block)."""
+
+    def __init__(self, name: str, position: int, better: Callable,
+                 arg_best: Callable):
+        self.name = name
+        self.position = int(position)
+        self._better = better         # strict: an equal row never replaces
+        self._arg_best = arg_best     # first occurrence of the extreme
+
+    def create_accumulator(self):
+        return None                   # no row yet (a row is never None)
+
+    def add(self, value, acc):
+        if acc is None or self._better(value[self.position],
+                                       acc[self.position]):
+            return value
+        return acc
+
+    def get_result(self, acc):
+        return acc
+
+    def merge(self, a, b):
+        """`a` holds the earlier rows."""
+        if a is None:
+            return b
+        return a if b is None else self.add(b, a)
+
+    def pick(self, column: np.ndarray) -> int:
+        """Index of the row `add` would keep, rows taken in column order."""
+        return int(self._arg_best(column))
+
+
+def max_by_agg(position: int) -> PositionalAggregate:
+    return PositionalAggregate("max_by", position, operator.gt, np.argmax)
+
+
+def min_by_agg(position: int) -> PositionalAggregate:
+    return PositionalAggregate("min_by", position, operator.lt, np.argmin)
+
+
 def resolve(agg) -> Optional[DeviceAggregator]:
     """Resolve a user-provided aggregate spec to a DeviceAggregator if it can
-    run on the device path; None means fall back to the oracle operator."""
+    run on the device path; None means fall back to the oracle operator
+    (a `PositionalAggregate` among them: no device program holds rows)."""
     if isinstance(agg, DeviceAggregator):
         return agg
     if isinstance(agg, str) and agg in BUILTINS:

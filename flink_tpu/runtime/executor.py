@@ -22,11 +22,16 @@ import dataclasses
 import logging
 import time
 from collections import deque
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional, Sequence
 
 import numpy as np
 
-from flink_tpu.api.functions import AggregateFunction, ProcessFunction, ReduceAggregate
+from flink_tpu.api.functions import (
+    AggregateFunction,
+    ProcessFunction,
+    ReduceAggregate,
+    null_key,
+)
 from flink_tpu.chaos import plan as _chaos
 from flink_tpu.config import (
     Configuration,
@@ -38,8 +43,13 @@ from flink_tpu.config import (
 from flink_tpu.core.time import MAX_WATERMARK, MIN_TIMESTAMP, MIN_WATERMARK
 from flink_tpu.core.watermarks import WatermarkStrategy
 from flink_tpu.graph.transformation import Step, StepGraph, Transformation
-from flink_tpu.ops.aggregators import resolve
-from flink_tpu.runtime.fire_block import downstream_batch, fires_of
+from flink_tpu.ops.aggregators import PositionalAggregate, resolve
+from flink_tpu.runtime.fire_block import (
+    FireBlock,
+    downstream_batch,
+    fires_of,
+    reduce_block,
+)
 from flink_tpu.runtime.oracle_window_operator import OracleWindowOperator
 from flink_tpu.runtime.tpu_window_operator import TpuWindowOperator
 from flink_tpu.runtime.timers import InternalTimerService
@@ -94,6 +104,21 @@ class _FanOut:
         for r, o in self.edges:
             r.on_batch_n(o, values, timestamps)
 
+    def on_fires(self, drained: Sequence, bare: bool,
+                 clock: Optional[StageClock] = None) -> None:
+        """One window operator's drain (fire_block.py: blocks of columns or
+        rows) to every consumer. A consumer that can take the fires as they
+        are does (`StepRunner.on_fires_n`); every other gets the `(vals,
+        ts)` batch `downstream_batch` builds, once. `clock` is the draining
+        operator's: the stages of the hand-over are its thread's time."""
+        batch = None
+        for r, o in self.edges:
+            if r.on_fires_n(o, drained, bare, clock):
+                continue
+            if batch is None:
+                batch = downstream_batch(drained, bare)
+            r.on_batch_n(o, *batch)
+
     def on_watermark(self, watermark: int) -> None:
         for r, o in self.edges:
             r.on_watermark_n(o, watermark)
@@ -139,6 +164,12 @@ class StepRunner:
     def on_batch_n(self, ordinal: int, values: np.ndarray,
                    timestamps: np.ndarray) -> None:
         self.on_batch(values, timestamps)
+
+    def on_fires_n(self, ordinal: int, drained: Sequence, bare: bool,
+                   clock: Optional[StageClock]) -> bool:
+        """An upstream window operator's drain before any row is built
+        (`_FanOut.on_fires`). True = taken; False = hand me the rows."""
+        return False
 
     def on_watermark_n(self, ordinal: int, watermark: int) -> None:
         """Per-gate watermark: min-combine across gates before processing
@@ -696,6 +727,18 @@ class WindowStepRunner(StepRunner):
                 emit_late_to_side_output=cfg["side_output_late"],
             )
             self.device = False
+        # a window over the null key with a builtin positional aggregate
+        # takes an upstream fire as columns (on_fires_n): one row of a block
+        # stands for all of them only where nothing counts or keeps the
+        # elements themselves
+        self._block_agg = (
+            aggregate
+            if isinstance(aggregate, PositionalAggregate)
+            and self.key_selector is null_key
+            and cfg.get("trigger") is None
+            and cfg.get("evictor") is None
+            else None
+        )
         self.processing_time = not assigner.is_event_time
         self.uid = t.uid
         # SQL-originated window steps (flink_tpu/planner lowering) are
@@ -887,6 +930,36 @@ class WindowStepRunner(StepRunner):
                 self.op.advance_processing_time(int(time.time() * 1000))
                 self._drain()
 
+    def on_fires_n(self, ordinal: int, drained: Sequence, bare: bool,
+                   clock: Optional[StageClock]) -> bool:
+        """The fast path of `window_all(...).max_by / min_by` behind a fused
+        window: each FireBlock is reduced over its keys by whole-column
+        calls (stage `fire.reduce`, on the emitting operator's clock, with
+        the `seq=` of the dispatch that fired the block) and the window
+        operator gets one partial row per block. The rows of a block share
+        one timestamp, hence every window, and the aggregate keeps the
+        first among equals, so the partial row is the row the window would
+        have kept of them. Anything else (rows, a block only its rows can
+        judge, a block at or behind the watermark, whose rows the operator
+        has to count as late one by one) goes the row way: False."""
+        agg = self._block_agg
+        if agg is None or bare or type(drained[0]) is not FireBlock:
+            return False
+        wm = self.op.timer_service.current_watermark
+        rows = []
+        for b in drained:
+            with stage(clock, "fire.reduce", b.seq):
+                row = reduce_block(b, agg) if b.ts > wm else None
+            if row is None:
+                return False
+            rows.append(row)
+        if clock is not None:
+            clock.fire_rows_reduced += sum(map(len, drained))
+            clock.fire_rows_kept += len(rows)
+        self.on_batch_n(ordinal, obj_array(rows), np.fromiter(
+            (b.ts for b in drained), dtype=np.int64, count=len(drained)))
+        return True
+
     def _keys_and_values(self, values: np.ndarray):
         """The key column and the f32 value column of one batch (the key
         selector and value function of the host-keyed path)."""
@@ -970,8 +1043,8 @@ class WindowStepRunner(StepRunner):
                                lateness_ms=lateness)
         if out and self.downstream:
             with stage(clock, "drain"):
-                self.downstream.on_batch(
-                    *downstream_batch(out, bare=self.window_fn is not None))
+                self.downstream.on_fires(
+                    out, self.window_fn is not None, clock)
 
     def register_metrics(self, group) -> None:
         super().register_metrics(group)
@@ -1219,9 +1292,9 @@ class SharedWindowRunner(DeviceChainRunner):
             out = drained[spec]
             if out and fan:
                 with stage(clock, "drain"):
-                    # the base _drain's builder: sharing must never change
-                    # what downstream receives
-                    fan.on_batch(*downstream_batch(out, bare=False))
+                    # the base _drain's hand-over: sharing must never
+                    # change what downstream receives
+                    fan.on_fires(out, False, clock)
 
     def _forward_watermark(self, watermark: int) -> None:
         for _spec, fan, sides in self._spec_fanouts():

@@ -6,7 +6,9 @@ key ids, their results) and two scalars (the window, its timestamp) before
 any row does. `FireBlock` carries it in that form; rows are built from a
 block at most once, by whole-column calls (`tolist` + `zip`), by whoever
 needs rows: `rows_of` for `drain_output()`'s callers, `downstream_batch`
-for the runner's hand-over. The other window operators (oracle,
+for the runner's hand-over; a null-key window behind the operator takes
+one row of a block without building the others (`reduce_block`). The other
+window operators (oracle,
 TpuWindowOperator, session, global) drain `(key, window, result, ts)` rows;
 `downstream_batch` and `fires_of` take those too.
 """
@@ -34,14 +36,15 @@ class FireBlock:
     says the rows carry no key and downstream takes the bare result
     (`execution.window.columnar-output`: one packed row per fire)."""
 
-    __slots__ = ("window", "keys", "results", "ts")
+    __slots__ = ("window", "keys", "results", "ts", "seq")
 
     def __init__(self, window, keys: Optional[Sequence], results: Sequence,
-                 ts: int):
+                 ts: int, seq: Optional[int] = None):
         self.window = window
         self.keys = keys
         self.results = results
         self.ts = ts
+        self.seq = seq      # the dispatch that fired it (the stage clock's)
 
     def __len__(self) -> int:
         return len(self.results)
@@ -73,6 +76,28 @@ def fires_of(drained: Sequence) -> Iterator[Tuple[Any, int]]:
     rows."""
     for d in drained:
         yield (d.window, d.ts) if type(d) is FireBlock else (d[1], d[3])
+
+
+def reduce_block(block: FireBlock, agg) -> Optional[Tuple[Any, Any]]:
+    """The one `(key, result)` row of `block` that `agg` (a
+    `PositionalAggregate`: `max_by` / `min_by` over position 0, the key, or
+    1, the result) keeps of the block's rows, by whole-column calls; None
+    where only the rows themselves can say (no key column, a column that is
+    no plain numeric ndarray, a NaN, another position): the caller then
+    takes `downstream_batch`. All rows of a block share one timestamp, so
+    for a window that holds them all the row returned stands for them."""
+    if block.keys is None or agg.position not in (0, 1) or not len(block):
+        return None
+    column = block.keys if agg.position == 0 else block.results
+    if not isinstance(column, np.ndarray) or column.dtype.kind not in "iuf" \
+            or (column.dtype.kind == "f" and np.isnan(column).any()):
+        return None
+    i = agg.pick(column)
+    return _scalar(block.keys[i]), _scalar(block.results[i])
+
+
+def _scalar(v):
+    return v.item() if isinstance(v, np.generic) else v
 
 
 def downstream_batch(drained: Sequence,

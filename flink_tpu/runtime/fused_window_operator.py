@@ -829,6 +829,9 @@ class FusedWindowOperator:
 
     #: the runner's stage clock (metrics/task_io.py); None = off
     stage_clock = None
+    #: the dispatch whose fires are being emitted: every FireBlock carries
+    #: it on, so the stages a block meets downstream share its `seq=`
+    _firing_seq = 0
 
     def attach_stage_clock(self, clock) -> None:
         self.stage_clock = clock
@@ -855,6 +858,7 @@ class FusedWindowOperator:
             fired = d.resolve()
         if clock is not None:
             clock.d2h_bytes += d.nbytes
+        self._firing_seq = seq
         for window, counts, fields in fired:
             if tracker is not None:
                 w = window[1] if type(window) is tuple else window
@@ -926,11 +930,12 @@ class FusedWindowOperator:
             # one packed row per fire: (window, dense key ids, values) —
             # downstream sees O(1) rows regardless of key cardinality
             # (map ids back through .keydict when raw keys are needed)
-            block = FireBlock(window, None, [(window, live, results)], ts)
+            block = FireBlock(window, None, [(window, live, results)], ts,
+                              self._firing_seq)
         else:
             block = FireBlock(
                 window, live if keys_of is None else keys_of(live), results,
-                ts)
+                ts, self._firing_seq)
         lane.append(block)
         self._count_fire(live.size)
 
@@ -993,7 +998,8 @@ class FusedWindowOperator:
             results += np.asarray(self.agg.extract(fdict_e)).tolist()
         if keys:
             self.output.append(
-                FireBlock(window, keys, results, window.max_timestamp()))
+                FireBlock(window, keys, results, window.max_timestamp(),
+                          self._firing_seq))
             self._count_fire(len(keys))
 
     def drain_blocks(self) -> List[FireBlock]:

@@ -38,7 +38,9 @@ from flink_tpu.graph.transformation import plan
 from flink_tpu.runtime.executor import (
     ChainRunner,
     DeviceChainRunner,
+    StepRunner,
     WindowStepRunner,
+    _FanOut,
     build_runners,
 )
 from flink_tpu.utils.arrays import as_device_column
@@ -463,10 +465,14 @@ def test_fused_runner_snapshot_restore_parity():
         entry = runners[0]
         assert isinstance(entry, DeviceChainRunner)
         out = []
-        entry.downstream = _Collect(out)
+        # wired as build_runners wires a consumer: the fused runner hands
+        # its fires to the fan-out, which builds the rows for a step that
+        # takes rows
+        entry.downstream = _FanOut()
+        entry.downstream.add(_Collect(out), 0)
         return entry, out
 
-    class _Collect:
+    class _Collect(StepRunner):
         def __init__(self, out):
             self.out = out
 

@@ -114,7 +114,8 @@ def test_link_counters_and_seq():
     assert clock.link() == {"h2dBytes": 4 * 8 * 4 + 12, "d2hBytes": 0,
                             "eventsStaged": 4, "columnsStaged": 0,
                             "recordColumns": 0, "rowsEmitted": 0,
-                            "fireBlocks": 0,
+                            "fireBlocks": 0, "fireRowsReduced": 0,
+                            "fireRowsKept": 0,
                             "dispatches": 1, "stepsPlannedScalar": 0,
                             "stepsPlannedMasked": 0}
     # a traced chain's dispatch says how much of the record it shipped;
