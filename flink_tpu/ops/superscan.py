@@ -29,6 +29,30 @@ def default_ingest() -> str:
     return "matmul" if jax.default_backend() == "tpu" else "scatter"
 
 
+def read_window(ring, first, spw, scatter):
+    """Fold one window out of a slice ring whose LAST axis is the ring
+    ([K, S] keyed, [S] global): the `spw` slices from ring position `first`
+    on, wrapping at S. One dense pass: the cells outside the window are
+    masked to the combiner's neutral element and the whole ring axis goes
+    through the field's `combine_reduce` — the same cells in the same
+    dtype as indexing them out, so counts, integer sums, min and max are
+    bit-equal to it (a float sum adds its cells in ring order). Never
+    `ring[..., pos]` with a position vector: along the minor axis that is
+    one single-element gather per cell once a window has several slices.
+    Nor `spw` unrolled slices: the program then grows with the window, and
+    the shared-partials program of tests/test_bench_correlated.py fell to
+    0.18x its independent plans on the CPU backend (that test's floor: 0.3)."""
+    import jax.numpy as jnp
+
+    from flink_tpu.ops.aggregators import combine_reduce, scan_identity
+
+    S = ring.shape[-1]
+    inside = (jnp.arange(S, dtype=jnp.int32) - first) % S < spw
+    neutral = jnp.asarray(scan_identity(ring.dtype, scatter), ring.dtype)
+    return combine_reduce(scatter)(
+        jnp.where(inside, ring, neutral), ring.ndim - 1)
+
+
 def make_superscan_step(agg, K, S, NSB, F, R, SPW, chunk, exact,
                         ingest: str = "matmul", phase_counters: bool = False,
                         fire_spws=None):
@@ -61,6 +85,12 @@ def make_superscan_step(agg, K, S, NSB, F, R, SPW, chunk, exact,
     ride the same async readback as the fire rows). The carry becomes a
     5-tuple; callers opt in, so the default executable shape is unchanged.
 
+    A fire reads the ring through `read_window`, whatever the window's
+    length: one masked dense pass over the [K, S] ring per field, folded
+    along S by the field's combiner, written as one [K] row of the [R, K]
+    fire buffer. The purge's conditional works on the ring's transpose, so
+    that the scan carries the ring as ingest and fire use it (key-minor).
+
     `fire_spws` (shared-partials, graph/window_sharing.py): per-fire-slot
     window lengths in slices, length F, replacing the uniform SPW — one
     ring of gcd-granule partials serves several correlated window shapes
@@ -70,7 +100,7 @@ def make_superscan_step(agg, K, S, NSB, F, R, SPW, chunk, exact,
     import jax.numpy as jnp
 
     from flink_tpu.ops import matmul_hist
-    from flink_tpu.ops.aggregators import VALUE, combine_reduce
+    from flink_tpu.ops.aggregators import VALUE
 
     spws = tuple(fire_spws) if fire_spws is not None else (SPW,) * F
     if len(spws) != F:
@@ -173,24 +203,24 @@ def make_superscan_step(agg, K, S, NSB, F, R, SPW, chunk, exact,
 
         with jax.named_scope("fire"):
             # fire: combine the window's slice columns, write compact rows.
-            # The WHOLE fire body sits under the cond, gathers included: most
-            # steps fire nothing, and the K*SPW column gather+combine per fire
-            # slot is the dominant per-step fixed cost when computed eagerly
-            # (at K=8192, SPW=10, F=2 that is 20x the ingest work of an 8k
-            # batch) — identical results, the eager crow was discarded unless
+            # The WHOLE fire body sits under the cond, the ring read included:
+            # most steps fire nothing, and the per-slot read+combine is the
+            # dominant per-step fixed cost when computed eagerly (at K=8192,
+            # SPW=10, F=2 that is 20x the ingest work of an 8k batch) —
+            # identical results, the eager crow was discarded unless
             # fire_valid was set anyway
             def write_fire(f, bufs):
-                pos = (fire_pos[f] + jnp.arange(spws[f], dtype=jnp.int32)) % S
                 row = jnp.clip(fire_row[f], 0, R - 1)
 
                 def do_fire(b):
                     outs, count_out = b
-                    crow = count[:, pos].sum(axis=1)
+                    crow = read_window(count, fire_pos[f], spws[f], "add")
                     count_out = jax.lax.dynamic_update_index_in_dim(
                         count_out, crow, row, 0)
                     new_outs = {}
                     for name, _dt, scatter, _ident in vfields:
-                        vrow = combine_reduce(scatter)(state[name][:, pos], 1)
+                        vrow = read_window(
+                            state[name], fire_pos[f], spws[f], scatter)
                         new_outs[name] = jax.lax.dynamic_update_index_in_dim(
                             outs[name], vrow, row, 0)
                     return (new_outs if vfields else outs), count_out
@@ -205,24 +235,34 @@ def make_superscan_step(agg, K, S, NSB, F, R, SPW, chunk, exact,
         with jax.named_scope("purge"):
             # purge expired ring columns (reset to the field's identity); under
             # a cond for the same reason — the S*K multiply/where is pure
-            # identity on the all-ones masks most steps carry
+            # identity on the all-ones masks most steps carry.
+            # The conditional takes the ring TRANSPOSED, [S, K]: XLA:TPU gives
+            # a conditional's operands their default layout, and [K, 32]'s
+            # is slice-minor where ingest and fire work key-minor, so the
+            # scan carried the ring slice-minor (32 lanes padded to 128) and
+            # turned all of it over twice in EVERY step, ~4.4 ms a dispatch
+            # at K = 65536 (PERF.md section 6, PR 33). [S, K]'s default layout
+            # is key-minor, and there the transposes are layout changes that
+            # move nothing (tests/test_tpu_compile.py holds the carry to it).
             def do_purge(sc):
-                state, count = sc
-                count = count * purge_mask[None, :]
-                if vfields:
-                    state = {
-                        name: jnp.where(
-                            purge_mask[None, :] > 0,
-                            state[name],
-                            jnp.asarray(ident, dt),
-                        )
-                        for name, dt, _scatter, ident in vfields
-                    }
-                return state, count
+                state_t, count_t = sc
+                count_t = count_t * purge_mask[:, None]
+                state_t = {
+                    name: jnp.where(
+                        purge_mask[:, None] > 0,
+                        state_t[name],
+                        jnp.asarray(ident, dt),
+                    )
+                    for name, dt, _scatter, ident in vfields
+                }
+                return state_t, count_t
 
             purged = jnp.any(purge_mask == 0)
-            state, count = jax.lax.cond(
-                purged, do_purge, lambda sc: sc, (state, count))
+            state_t, count_t = jax.lax.cond(
+                purged, do_purge, lambda sc: sc,
+                ({name: v.T for name, v in state.items()}, count.T))
+            state = {name: v.T for name, v in state_t.items()}
+            count = count_t.T
         if phase_counters:
             phase_c = phase_c + jnp.stack([
                 ingested.astype(jnp.int32),
@@ -314,7 +354,7 @@ def make_global_scan_step(agg, S, NSB, F, R, SPW, fire_spws=None,
     import jax
     import jax.numpy as jnp
 
-    from flink_tpu.ops.aggregators import VALUE, combine_reduce, scan_identity
+    from flink_tpu.ops.aggregators import VALUE, scan_identity
     from flink_tpu.ops.segment_ops import bounded_segment_fold
 
     spws = tuple(fire_spws) if fire_spws is not None else (SPW,) * F
@@ -350,15 +390,16 @@ def make_global_scan_step(agg, S, NSB, F, R, SPW, fire_spws=None,
 
         # fire: fold the window's slice cells into one scalar per slot
         def write_fire(f, bufs):
-            pos = (fire_pos[f] + jnp.arange(spws[f], dtype=jnp.int32)) % S
             row = jnp.clip(fire_row[f], 0, R - 1)
 
             def do_fire(b):
                 outs, count_out = b
-                count_out = count_out.at[row].set(count[pos].sum())
+                count_out = count_out.at[row].set(
+                    read_window(count, fire_pos[f], spws[f], "add"))
                 new_outs = {}
                 for name, _dt, scatter, _ident in vfields:
-                    folded = combine_reduce(scatter)(state[name][pos], 0)
+                    folded = read_window(
+                        state[name], fire_pos[f], spws[f], scatter)
                     new_outs[name] = outs[name].at[row].set(folded)
                 return (new_outs if vfields else outs), count_out
 

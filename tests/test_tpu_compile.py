@@ -225,8 +225,20 @@ def test_chained_superscan_compiles_at_served_defaults(v5e, monkeypatch):
     args = ({}, _on(dev, (K, pipe.S), i32), {}, _on(dev, (pipe.R, K), i32),
             (_on(dev, (T, B), jnp.float32),) * 2, _on(dev, (T, B), i32),
             ) + _plan_specs(dev, T, pipe.F, pipe.S)
-    mem = run.trace(*args).lower().compile().memory_analysis()
+    compiled = run.trace(*args).lower().compile()
+    mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 8 << 30
+    # the scan carries the [K, 32] ring as ingest and fire use it, key-minor:
+    # carried slice-minor (32 lanes padded to 128, 33.5 MB of temporaries) it
+    # was turned over twice in every step, ~4 ms a dispatch on the chip
+    # (PERF.md section 6, PR 33); and a ten-slice fire reads the ring by a
+    # mask, not by a gather along its minor axis
+    hlo = compiled.as_text()
+    carried = [ln for ln in hlo.splitlines() if " while(" in ln
+               and f"s32[{K},{pipe.S}]" in ln]
+    assert carried and not any(f"s32[{K},{pipe.S}]{{1,0" in ln for ln in carried)
+    assert mem.temp_size_in_bytes < 8 << 20
+    assert " gather(" not in hlo
 
 
 def test_ysb_chain_compiles_per_column_and_builds_no_record(v5e, monkeypatch):
