@@ -99,6 +99,8 @@ def make_superscan_step(agg, K, S, NSB, F, R, SPW, chunk, exact,
     import jax
     import jax.numpy as jnp
 
+    from flink_tpu.metrics.device_phases import (
+        FIRE, FOLD, HIST, INGEST, PURGE, SCATTER)
     from flink_tpu.ops import matmul_hist
     from flink_tpu.ops.aggregators import VALUE
 
@@ -127,7 +129,7 @@ def make_superscan_step(agg, K, S, NSB, F, R, SPW, chunk, exact,
             # idx is the step's [K, NSB] count partial, vals the tuple of
             # per-VALUE-field [K, NSB] partials — one dense column combine
             # per field, same add/min/max semantics as the lane scatter
-            with jax.named_scope("ingest"):
+            with jax.named_scope(INGEST), jax.named_scope(FOLD):
                 cpart = idx
                 count = count.at[:, cols].add(cpart)
                 new_state = {}
@@ -140,12 +142,15 @@ def make_superscan_step(agg, K, S, NSB, F, R, SPW, chunk, exact,
                 else None, cpart.sum(),
                 (fire_pos, fire_valid, fire_row, purge_mask))
 
-        with jax.named_scope("ingest"):
+        with jax.named_scope(INGEST):
             # ingest: MXU histograms over (key, rel-slice) segments for
             # add-combining fields (or direct scatter-adds on CPU backends);
             # min/max fields always scatter-combine (no matmul form exists for
             # order statistics — the scatter unit is the cost of supporting
-            # them on the fused path at all)
+            # them on the fused path at all). Nested scopes name its pieces
+            # for a capture's phase table (metrics/device_phases.py): HIST
+            # the step's [K, NSB] partial, FOLD that partial added into the
+            # ring's columns, SCATTER a per-record scatter into the ring
             kid = idx // NSB
             srel = idx % NSB
             col = (smin_pos + srel) % S
@@ -157,37 +162,50 @@ def make_superscan_step(agg, K, S, NSB, F, R, SPW, chunk, exact,
             # the batch, so huge-K geometries keep the direct scatter
             flat_adds = ingest != "matmul" and nseg <= 16 * idx.shape[0]
             if ingest == "matmul":
-                pc = matmul_hist.count_hist(idx, nseg, chunk=chunk).reshape(K, NSB)
-                count = count.at[:, cols].add(pc)
+                with jax.named_scope(HIST):
+                    pc = matmul_hist.count_hist(
+                        idx, nseg, chunk=chunk).reshape(K, NSB)
+                with jax.named_scope(FOLD):
+                    count = count.at[:, cols].add(pc)
             elif flat_adds:
                 # dead rows carry idx -1, which jax would WRAP to the last
                 # segment (numpy negative indexing; mode="drop" only drops
                 # past-the-end) — remap them to nseg so the drop is real
-                safe_idx = jnp.where(idx >= 0, idx, nseg)
-                pc = jnp.zeros((nseg,), jnp.int32).at[safe_idx].add(
-                    jnp.int32(1), mode="drop").reshape(K, NSB)
-                count = count.at[:, cols].add(pc)
+                with jax.named_scope(HIST):
+                    safe_idx = jnp.where(idx >= 0, idx, nseg)
+                    pc = jnp.zeros((nseg,), jnp.int32).at[safe_idx].add(
+                        jnp.int32(1), mode="drop").reshape(K, NSB)
+                with jax.named_scope(FOLD):
+                    count = count.at[:, cols].add(pc)
             else:
-                count = count.at[safe_kid, col].add(jnp.int32(1), mode="drop")
+                with jax.named_scope(SCATTER):
+                    count = count.at[safe_kid, col].add(
+                        jnp.int32(1), mode="drop")
             new_state = {}
             for name, dt, scatter, ident in vfields:
-                if scatter == "add":
-                    if ingest == "matmul":
+                if scatter == "add" and ingest == "matmul":
+                    with jax.named_scope(HIST):
                         ph = matmul_hist.weighted_hist(
                             idx, vals, nseg, chunk=chunk, exact=exact
                         ).reshape(K, NSB)
-                        new_state[name] = state[name].at[:, cols].add(ph.astype(dt))
-                    elif flat_adds:
+                    with jax.named_scope(FOLD):
+                        new_state[name] = state[name].at[:, cols].add(
+                            ph.astype(dt))
+                elif scatter == "add" and flat_adds:
+                    with jax.named_scope(HIST):
                         ph = jnp.zeros((nseg,), dt).at[
                             jnp.where(idx >= 0, idx, nseg)].add(
                             vals.astype(dt), mode="drop").reshape(K, NSB)
+                    with jax.named_scope(FOLD):
                         new_state[name] = state[name].at[:, cols].add(ph)
-                    else:
+                elif scatter == "add":
+                    with jax.named_scope(SCATTER):
                         new_state[name] = state[name].at[safe_kid, col].add(
                             vals.astype(dt), mode="drop")
                 else:
-                    upd = getattr(state[name].at[safe_kid, col], scatter)
-                    new_state[name] = upd(vals.astype(dt), mode="drop")
+                    with jax.named_scope(SCATTER):
+                        upd = getattr(state[name].at[safe_kid, col], scatter)
+                        new_state[name] = upd(vals.astype(dt), mode="drop")
             state = new_state if vfields else state
         return _fire_purge(
             state, count, outs, count_out,
@@ -201,7 +219,7 @@ def make_superscan_step(agg, K, S, NSB, F, R, SPW, chunk, exact,
         different INGEST, never a different fire/purge."""
         fire_pos, fire_valid, fire_row, purge_mask = plan
 
-        with jax.named_scope("fire"):
+        with jax.named_scope(FIRE):
             # fire: combine the window's slice columns, write compact rows.
             # The WHOLE fire body sits under the cond, the ring read included:
             # most steps fire nothing, and the per-slot read+combine is the
@@ -232,7 +250,7 @@ def make_superscan_step(agg, K, S, NSB, F, R, SPW, chunk, exact,
                 bufs = write_fire(f, bufs)
             outs, count_out = bufs
 
-        with jax.named_scope("purge"):
+        with jax.named_scope(PURGE):
             # purge expired ring columns (reset to the field's identity); under
             # a cond for the same reason — the S*K multiply/where is pure
             # identity on the all-ones masks most steps carry.

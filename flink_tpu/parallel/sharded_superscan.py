@@ -72,6 +72,7 @@ import jax.numpy as jnp
 from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from flink_tpu.metrics.device_phases import EXCHANGE, PROLOGUE
 from flink_tpu.metrics.task_io import dispatch_stage
 from flink_tpu.ops.aggregators import VALUE, combine_reduce, decomposable
 from flink_tpu.ops.superscan import (
@@ -404,7 +405,7 @@ class ShardedFusedPipeline:
         n, Kl, NSB, axis = self.n, self.K_local, self.NSB, self.axis
 
         def fn(carry, pidx, vals, plan_row):
-            with jax.named_scope("exchange"):
+            with jax.named_scope(EXCHANGE):
                 cpart, parts = partials_fn(pidx, vals)
                 rc = jax.lax.all_to_all(
                     cpart.reshape(n, Kl * NSB), axis, split_axis=0,
@@ -494,7 +495,7 @@ class ShardedFusedPipeline:
                     carry, _delivered = exchange(
                         carry, pidx, vals_row, plan_row)
                     return carry, None
-                with jax.named_scope("exchange"):
+                with jax.named_scope(EXCHANGE):
                     if routed:
                         # route-raw under a table: the sender localizes (the
                         # receiver cannot invert an arbitrary table from a
@@ -715,7 +716,7 @@ class ShardedFusedPipeline:
                 # the traced chain runs on THIS shard's raw lanes, before
                 # any routing: filter/projection/keying happen where the
                 # data landed, only survivors cross the interconnect
-                with jax.named_scope("prologue"):
+                with jax.named_scope(PROLOGUE):
                     live, keys, idx, vals, key_bounds = pro.apply(
                         raw_row, srel_row, ts_row, key_bounds, K=K, NSB=NSB,
                         needs_vals=bool(nf), layout=layout)
@@ -730,7 +731,7 @@ class ShardedFusedPipeline:
                     return (inner, key_bounds, handed + delivered), None
                 # the keyBy exchange: bin by owning key range, one
                 # all-to-all over the mesh interconnect per step
-                with jax.named_scope("exchange"):
+                with jax.named_scope(EXCHANGE):
                     if routed:
                         # route-raw under a table: sender-side localization
                         dst, send_payload = owner(live, keys, srel_row)

@@ -2265,6 +2265,9 @@ class JobRuntime:
         # per-attempt jax.profiler trace used to be write-only
         self.profiler_captures = 0
         self.last_profiler_capture_dir: Optional[str] = None
+        # the newest capture read back: ms per execution of each window
+        # program under each of its phases (metrics/device_phases.py)
+        self.profiler_phase_ms: Optional[Dict[str, Any]] = None
         self._marker_interval = config.get(ObservabilityOptions.MARKER_INTERVAL_MS)
         self._sampling_interval = config.get(ObservabilityOptions.SAMPLING_INTERVAL_MS)
 
@@ -2446,6 +2449,9 @@ class JobRuntime:
             "captures": self.profiler_captures,
             "last_capture_dir": self.last_profiler_capture_dir,
         }
+        if self.profiler_phase_ms is not None:
+            # the time counterpart of the operators' `phases` step counts
+            payload["profiler"]["phaseMs"] = self.profiler_phase_ms
         return payload
 
     # -- the loop ---------------------------------------------------------
@@ -2490,6 +2496,22 @@ class JobRuntime:
                 except Exception as e:   # observability never fails the job
                     logging.getLogger(__name__).debug(
                         "jax.profiler stop_trace failed: %r", e)
+                else:
+                    self._read_capture(profile_dir)
+
+    def _read_capture(self, profile_dir: str) -> None:
+        """The capture this attempt just closed, read back once: the device
+        programs' time under each of their phases, for `/jobs/:id/device`."""
+        from flink_tpu.metrics import device_phases
+
+        try:
+            self.profiler_phase_ms = device_phases.per_execution(
+                device_phases.phase_table(profile_dir))
+        except _chaos.InjectedCrash:
+            raise
+        except Exception as e:   # noqa: BLE001 — observability never fails
+            logging.getLogger(__name__).debug(   # the job
+                "the profiler's capture could not be read: %r", e)
 
     def _run_loop(
         self,

@@ -39,6 +39,7 @@ import numpy as np
 
 from flink_tpu.api.windowing.assigners import WindowAssigner
 from flink_tpu.core.time import MIN_WATERMARK, TimeWindow
+from flink_tpu.metrics import device_phases
 from flink_tpu.metrics.task_io import dispatch_stage
 from flink_tpu.ops.aggregators import DeviceAggregator, ONE, VALUE, resolve
 from flink_tpu.utils.arrays import canonical_column
@@ -128,6 +129,7 @@ class TracedPrologue:
         equation of this very trace reads; XLA folds each static slice of
         the stack to its operand, so no [B, width] array exists on the
         device."""
+        import jax
         import jax.numpy as jnp
 
         if layout is None:
@@ -138,38 +140,45 @@ class TracedPrologue:
             col = jnp.stack([staged.get(c, unread)
                              for c in range(layout.width)], axis=1)
         mask = srel >= 0
-        for kind, fn in self.transforms:
-            if kind == "map":
-                col = fn(col)
-            elif kind == "map_ts":
-                col = fn(col, ts)
-            else:  # filter
-                mask = mask & jnp.asarray(fn(col)).astype(bool)
-        keys = jnp.asarray(self.key_fn(col)).astype(jnp.int32)
-        live = mask & (keys >= 0) & (keys < K)
-        idx = jnp.where(live, keys * NSB + srel, jnp.int32(-1))
-        idx = idx.astype(jnp.int32)
+        # one nested scope per element of the chain: a capture's phase table
+        # (metrics/device_phases.py) then has a row per transform
+        for i, (kind, fn) in enumerate(self.transforms):
+            with jax.named_scope(device_phases.transform_scope(i, kind)):
+                if kind == "map":
+                    col = fn(col)
+                elif kind == "map_ts":
+                    col = fn(col, ts)
+                else:  # filter
+                    mask = mask & jnp.asarray(fn(col)).astype(bool)
+        with jax.named_scope(device_phases.KEY):
+            keys = jnp.asarray(self.key_fn(col)).astype(jnp.int32)
+            live = mask & (keys >= 0) & (keys < K)
+            idx = jnp.where(live, keys * NSB + srel, jnp.int32(-1))
+            idx = idx.astype(jnp.int32)
         if needs_vals:
-            vcol = self.value_fn(col) if self.value_fn is not None else col
-            # dead/pad rows hold uninitialized staging bytes that can
-            # decode as NaN/inf; zero them BEFORE ingest or any shuffle —
-            # the matmul histogram multiplies the zero one-hot by the raw
-            # value, and 0 * NaN = NaN would poison every sum in the chunk
-            # (the scatter path drops by index, but identical inputs keep
-            # both ingest forms bit-identical)
-            vals = jnp.where(
-                live, jnp.asarray(vcol).astype(jnp.float32), 0.0)
+            with jax.named_scope(device_phases.VALUE):
+                vcol = (self.value_fn(col) if self.value_fn is not None
+                        else col)
+                # dead/pad rows hold uninitialized staging bytes that can
+                # decode as NaN/inf; zero them BEFORE ingest or any shuffle —
+                # the matmul histogram multiplies the zero one-hot by the raw
+                # value, and 0 * NaN = NaN would poison every sum in the chunk
+                # (the scatter path drops by index, but identical inputs keep
+                # both ingest forms bit-identical)
+                vals = jnp.where(
+                    live, jnp.asarray(vcol).astype(jnp.float32), 0.0)
         else:
             vals = jnp.zeros((1,), jnp.float32)
         # key range observed over every SURVIVING record (pre range clamp):
         # an out-of-range key is a hard error at resolve, never a silent
         # drop or a silent alias of another key's (or shard's) row
-        key_bounds = jnp.stack([
-            jnp.maximum(key_bounds[0],
-                        jnp.max(jnp.where(mask, keys, jnp.int32(-1)))),
-            jnp.minimum(key_bounds[1],
-                        jnp.min(jnp.where(mask, keys, jnp.int32(0)))),
-        ])
+        with jax.named_scope(device_phases.BOUNDS):
+            key_bounds = jnp.stack([
+                jnp.maximum(key_bounds[0],
+                            jnp.max(jnp.where(mask, keys, jnp.int32(-1)))),
+                jnp.minimum(key_bounds[1],
+                            jnp.min(jnp.where(mask, keys, jnp.int32(0)))),
+            ])
         return live, keys, idx, vals, key_bounds
 
 
@@ -1365,7 +1374,7 @@ class FusedWindowPipeline:
                 raw, srel = args[0], args[1]
                 ts = None
                 rest = args[2:]
-            with jax.named_scope("prologue"):
+            with jax.named_scope(device_phases.PROLOGUE):
                 _live, _keys, idx, vals, key_bounds = pro.apply(
                     raw, srel, ts, key_bounds, K=K, NSB=NSB,
                     needs_vals=needs_vals, layout=layout)

@@ -198,16 +198,31 @@ def test_refused_geometry_is_never_selected(monkeypatch):
     assert g._use_pallas() is False
 
 
-def test_chained_superscan_compiles_at_served_defaults(v5e, monkeypatch):
-    """The program a default-config `env.execute()` job dispatches
-    (chip_smoke.py leg 1): traced filter + key + sliding count, key
+_COMPILED = {}     # what this file compiled, by name: each program once
+
+
+def _compile_chained(v5e, pipe, run, T, B):
+    """A chained program over two staged f32 fields, for the first chip."""
+    dev, i32, K = v5e.devices[0], jnp.int32, pipe.K
+    args = ({}, _on(dev, (K, pipe.S), i32), {}, _on(dev, (pipe.R, K), i32),
+            (_on(dev, (T, B), jnp.float32),) * 2, _on(dev, (T, B), i32),
+            ) + _plan_specs(dev, T, pipe.F, pipe.S)
+    return run.trace(*args).lower().compile()
+
+
+def _served_chain(v5e, monkeypatch, slide_ms):
+    """The program a default-config `env.execute()` job dispatches: traced
+    filter + key + count over a 10 s window hopping by `slide_ms`, key
     capacity 65536, 32 steps of 65536 two-column f32 records."""
+    name = f"chain.{slide_ms}"
+    if name in _COMPILED:
+        return _COMPILED[name]
     # ops/superscan.default_ingest picks the matmul-histogram ingest by
     # backend: build the program the chip would build
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     K, T, B = 1 << 16, 32, 1 << 16
     pipe = FusedWindowPipeline(
-        SlidingEventTimeWindows.of(10_000, 1_000), "count", key_capacity=K,
+        SlidingEventTimeWindows.of(10_000, slide_ms), "count", key_capacity=K,
         fires_per_step=EXEC["F"], out_rows=EXEC["R"], chunk=EXEC["CH"],
         plan_only=True,
         prologue=TracedPrologue(
@@ -220,12 +235,14 @@ def test_chained_superscan_compiles_at_served_defaults(v5e, monkeypatch):
     layout = pipe._layout()
     assert layout.columns == (0, 1)
     run = pipe._build_chained_superscan(T, B, layout)
-    dev = v5e.devices[0]
-    i32 = jnp.int32
-    args = ({}, _on(dev, (K, pipe.S), i32), {}, _on(dev, (pipe.R, K), i32),
-            (_on(dev, (T, B), jnp.float32),) * 2, _on(dev, (T, B), i32),
-            ) + _plan_specs(dev, T, pipe.F, pipe.S)
-    compiled = run.trace(*args).lower().compile()
+    _COMPILED[name] = pipe, _compile_chained(v5e, pipe, run, T, B)
+    return _COMPILED[name]
+
+
+def test_chained_superscan_compiles_at_served_defaults(v5e, monkeypatch):
+    """chip_smoke.py leg 1's program (a 10 s window sliding by 1 s)."""
+    pipe, compiled = _served_chain(v5e, monkeypatch, 1_000)
+    K = pipe.K
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 8 << 30
     # the scan carries the [K, 32] ring as ingest and fire use it, key-minor:
@@ -241,14 +258,14 @@ def test_chained_superscan_compiles_at_served_defaults(v5e, monkeypatch):
     assert " gather(" not in hlo
 
 
-def test_ysb_chain_compiles_per_column_and_builds_no_record(v5e, monkeypatch):
+def _ysb_chain(v5e, monkeypatch):
     """The benchmark's advertising chain (benchmarks/jobs/ysb_traced.py:
     view filter, ad -> campaign join as a table gather, count per campaign
-    per 10 s window) over the 7-field event: two fields are staged, and the
-    compiled program reads them as they are — the record the user's
-    functions are handed is never built on the device."""
+    per 10 s window) over the 7-field event."""
     import numpy as np
 
+    if "ysb" in _COMPILED:
+        return _COMPILED["ysb"]
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     table = jnp.asarray(np.arange(1000, dtype=np.int32) % 100)
 
@@ -270,12 +287,16 @@ def test_ysb_chain_compiles_per_column_and_builds_no_record(v5e, monkeypatch):
     layout = pipe._layout()
     assert (layout.columns, layout.width) == ((2, 4), 7)
     run = pipe._build_chained_superscan(T, B, layout)
-    dev = v5e.devices[0]
-    i32 = jnp.int32
-    args = ({}, _on(dev, (K, pipe.S), i32), {}, _on(dev, (pipe.R, K), i32),
-            (_on(dev, (T, B), jnp.float32),) * 2, _on(dev, (T, B), i32),
-            ) + _plan_specs(dev, T, pipe.F, pipe.S)
-    compiled = run.trace(*args).lower().compile()
+    _COMPILED["ysb"] = pipe, _compile_chained(v5e, pipe, run, T, B)
+    return _COMPILED["ysb"]
+
+
+def test_ysb_chain_compiles_per_column_and_builds_no_record(v5e, monkeypatch):
+    """Two of the event's seven fields are staged, and the compiled program
+    reads them as they are — the record the user's functions are handed is
+    never built on the device."""
+    pipe, compiled = _ysb_chain(v5e, monkeypatch)
+    K, T, B = pipe.K, 32, 1 << 16
     hlo = compiled.as_text()
     assert f"f32[{B},7]" not in hlo and f"f32[{T},{B},7]" not in hlo
     mem = compiled.memory_analysis()
@@ -284,14 +305,16 @@ def test_ysb_chain_compiles_per_column_and_builds_no_record(v5e, monkeypatch):
         + 3 * T * B * 4 + (1 << 20)
 
 
-def test_sharded_chained_superscan_compiles_on_the_2x2_mesh(v5e, monkeypatch):
-    """chip_smoke.py leg 3's program: the same job sharded over the four
+def _sharded_chain(v5e, monkeypatch):
+    """chip_smoke.py leg 3's program: the served job sharded over the four
     chips of one host, the keyBy exchange as an in-scan all-to-all."""
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from flink_tpu.parallel.sharded_superscan import ShardedFusedPipeline
 
+    if "sharded" in _COMPILED:
+        return _COMPILED["sharded"]
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     # the topology's devices cannot hold arrays: plan and compile only
     monkeypatch.setattr(ShardedFusedPipeline, "_init_state",
@@ -315,12 +338,70 @@ def test_sharded_chained_superscan_compiles_on_the_2x2_mesh(v5e, monkeypatch):
             shape, dtype, sharding=NamedSharding(mesh, P(*spec)))
 
     i32, F, S = jnp.int32, pipe.F, pipe.S
-    run.trace(
+    _COMPILED["sharded"] = pipe, run.trace(
         on_mesh((n, K // n, S), i32, "shards"), (),
         (on_mesh((n, T, B // n), jnp.float32, "shards"),) * 2,
         on_mesh((n, T, B // n), i32, "shards"),
         on_mesh((T, 1 + 3 * F + S), i32),       # the plan, side by side
     ).lower().compile()
+    return _COMPILED["sharded"]
+
+
+def test_sharded_chained_superscan_compiles_on_the_2x2_mesh(v5e, monkeypatch):
+    _sharded_chain(v5e, monkeypatch)
+
+
+def _scopes(compiled):
+    """[(HLO line, op_name, phase, nested name)] of a compiled program's
+    instructions that carry an `op_name`."""
+    import re
+
+    from flink_tpu.metrics.device_phases import phase_of
+
+    out = []
+    for line in compiled.as_text().splitlines():
+        op_name = re.search(r'op_name="([^"]*)"', line)
+        if " = " in line and op_name:
+            out.append((line, op_name.group(1), *phase_of(op_name.group(1))))
+    return out
+
+
+@pytest.mark.parametrize("program", ["q5", "sharded"])
+def test_compiled_window_programs_carry_their_phases(v5e, monkeypatch,
+                                                     program):
+    """The chip's compiler keeps the scopes `metrics/device_phases.py` reads a
+    capture by: the chained program at NEXMark q5's geometry (a 10 s window
+    hopping by 2 s) names four of PHASES, the sharded program all five, and
+    every `while` and `conditional` inside the scan's body lies under one —
+    a loop under no phase would put its whole time in the table's `other`."""
+    from flink_tpu.metrics.device_phases import EXCHANGE, PHASES
+
+    _pipe, compiled = (_served_chain(v5e, monkeypatch, 2_000)
+                       if program == "q5" else _sharded_chain(v5e, monkeypatch))
+    scopes = _scopes(compiled)
+    found = {phase for _l, _o, phase, _sub in scopes if phase}
+    expect = set(PHASES) if program == "sharded" else set(PHASES) - {EXCHANGE}
+    assert found == expect
+    loops = [(op_name, phase) for line, op_name, phase, _sub in scopes
+             if (" while(" in line or " conditional(" in line)
+             and "/while/body/" in op_name]
+    assert len(loops) >= 6      # ingest's loop(s), four fire slots, the purge
+    assert all(phase for _o, phase in loops), loops
+    assert {sub for _l, _o, phase, sub in scopes if phase == "ingest"} >= {
+        "hist", "fold"}
+
+
+def test_ysb_prologue_shows_one_scope_per_transform(v5e, monkeypatch):
+    """`ysb_catchup`'s chain under the prologue: the view filter, the ad ->
+    campaign join, the key and the key bounds each have a row of their own."""
+    _pipe, compiled = _ysb_chain(v5e, monkeypatch)
+    scopes = _scopes(compiled)
+    subs = {sub for _l, _o, phase, sub in scopes if phase == "prologue" and sub}
+    assert {s for s in subs if s.startswith("t")} == {"t0.filter", "t1.map"}
+    assert subs >= {"key", "bounds"}
+    # the join's gather is the map's
+    assert any("gather" in op_name for _l, op_name, _p, sub in scopes
+               if sub == "t1.map")
 
 
 @pytest.mark.parametrize("routed", [False, True], ids=["static", "table"])
