@@ -14,6 +14,12 @@ from __future__ import annotations
 import functools as _functools
 
 
+#: the counts a step threads through its carry with `phase_counters`: records
+#: ingested, fire slots executed, steps that purged, steps whose live records
+#: lay in one slice
+PHASE_COUNTS = 4
+
+
 def default_ingest() -> str:
     """THE backend-dependent ingest choice, single-sourced: programs built
     fresh per job (the chained single-chip superscan and both sharded
@@ -68,6 +74,17 @@ def make_superscan_step(agg, K, S, NSB, F, R, SPW, chunk, exact,
     core). Identical math either way: both are pure adds into the same
     cells, counts exact in int32.
 
+    The matmul histogram is as wide as the step's records are: a step whose
+    live lanes all lie in the step's lowest slice (`idx % NSB == 0`; nearly
+    every step of an in-order stream, a batch being far shorter than a
+    slice) contracts K segments, slice 0 of the partial, where a step that
+    straddles a slice boundary contracts K * NSB. One `lax.cond` per step
+    picks, on a predicate reduced from the step's own lanes, and yields the
+    step's [NSB, K] partials, which the fold adds into the ring outside it:
+    the ring is never an operand of that conditional (XLA:TPU would give it
+    its default layout there and turn it over in every step, PERF.md
+    section 6, PRs 33 and 36).
+
     'partials' consumes PRE-REDUCED per-step partials instead of record
     lanes — the receive side of the mesh map-side combiner
     (parallel.mesh.local-combine): the idx slot of `args` carries the
@@ -78,12 +95,14 @@ def make_superscan_step(agg, K, S, NSB, F, R, SPW, chunk, exact,
     add/min/max ops the lane scatter applies, so the ring state is exact;
     fire and purge are the identical shared body.
 
-    `phase_counters` (device-plane observability) threads an int32[3]
-    counter through the carry — [records ingested, fire slots executed,
-    steps that purged] — so a dispatch's device time can be attributed to
-    the ingest/fire/purge phases without any extra host sync (the counts
-    ride the same async readback as the fire rows). The carry becomes a
-    5-tuple; callers opt in, so the default executable shape is unchanged.
+    `phase_counters` (device-plane observability) threads an
+    int32[PHASE_COUNTS] counter through the carry — [records ingested, fire
+    slots executed, steps that purged, steps whose live records lay in one
+    slice] — so a dispatch's device time can be attributed to the
+    ingest/fire/purge phases, and the narrow histogram's engagement read,
+    without any extra host sync (the counts ride the same async readback as
+    the fire rows). The carry becomes a 5-tuple; callers opt in, so the
+    default executable shape is unchanged.
 
     A fire reads the ring through `read_window`, whatever the window's
     length: one masked dense pass over the [K, S] ring per field, folded
@@ -139,7 +158,7 @@ def make_superscan_step(agg, K, S, NSB, F, R, SPW, chunk, exact,
                 state = new_state if vfields else state
             return _fire_purge(
                 state, count, outs, count_out, phase_c if phase_counters
-                else None, cpart.sum(),
+                else None, cpart.sum(), ~jnp.any(cpart[:, 1:] != 0),
                 (fire_pos, fire_valid, fire_row, purge_mask))
 
         with jax.named_scope(INGEST):
@@ -155,6 +174,9 @@ def make_superscan_step(agg, K, S, NSB, F, R, SPW, chunk, exact,
             srel = idx % NSB
             col = (smin_pos + srel) % S
             safe_kid = jnp.where(idx >= 0, kid, K)  # OOB rows drop
+            # no live lane above the step's lowest slice (a dead lane's -1
+            # has srel NSB - 1): what the matmul histogram's width hangs on
+            one_slice = ~jnp.any((idx >= 0) & (srel != 0))
             # CPU add-ingest form: XLA lowers a FLAT 1-D index scatter ~2x
             # faster than the 2-D (kid, col) scatter, so adds go through a
             # [K*NSB] staging histogram folded densely into the ring columns —
@@ -162,11 +184,46 @@ def make_superscan_step(agg, K, S, NSB, F, R, SPW, chunk, exact,
             # the batch, so huge-K geometries keep the direct scatter
             flat_adds = ingest != "matmul" and nseg <= 16 * idx.shape[0]
             if ingest == "matmul":
+                def hists(seg, nsegs):
+                    return matmul_hist.count_hist(seg, nsegs, chunk=chunk), {
+                        name: matmul_hist.weighted_hist(
+                            seg, vals, nsegs, chunk=chunk, exact=exact)
+                        for name, _dt, scatter, _ident in vfields
+                        if scatter == "add"}
+
+                def narrow():
+                    # [K] histograms over the key alone (a dead lane's
+                    # -1 // NSB stays out of range): slice 0 of the step's
+                    # partial, the slices above it zero
+                    return jax.tree.map(
+                        lambda h: jnp.pad(h[None], ((0, NSB - 1), (0, 0))),
+                        hists(idx // NSB, K))
+
+                def wide():
+                    return jax.tree.map(
+                        lambda h: h.reshape(K, NSB).T, hists(idx, nseg))
+
+                def fold(ring, part):
+                    # part's slices into their ring columns, the live ones
+                    # only: a loop the ring is carried through, never a
+                    # conditional's operand
+                    def add_column(i, ring):
+                        column = jax.lax.dynamic_slice_in_dim(
+                            ring, cols[i], 1, axis=1)
+                        return jax.lax.dynamic_update_slice_in_dim(
+                            ring, column + part[i][:, None].astype(ring.dtype),
+                            cols[i], axis=1)
+
+                    return jax.lax.fori_loop(
+                        0, jnp.where(one_slice, 1, NSB), add_column, ring)
+
+                # the step's partials as the fold reads them, key-minor
+                # [NSB, K]; the ring stays outside the conditional
                 with jax.named_scope(HIST):
-                    pc = matmul_hist.count_hist(
-                        idx, nseg, chunk=chunk).reshape(K, NSB)
+                    pc, add_parts = (jax.lax.cond(one_slice, narrow, wide)
+                                     if NSB > 1 else wide())
                 with jax.named_scope(FOLD):
-                    count = count.at[:, cols].add(pc)
+                    count = fold(count, pc)
             elif flat_adds:
                 # dead rows carry idx -1, which jax would WRAP to the last
                 # segment (numpy negative indexing; mode="drop" only drops
@@ -184,13 +241,8 @@ def make_superscan_step(agg, K, S, NSB, F, R, SPW, chunk, exact,
             new_state = {}
             for name, dt, scatter, ident in vfields:
                 if scatter == "add" and ingest == "matmul":
-                    with jax.named_scope(HIST):
-                        ph = matmul_hist.weighted_hist(
-                            idx, vals, nseg, chunk=chunk, exact=exact
-                        ).reshape(K, NSB)
                     with jax.named_scope(FOLD):
-                        new_state[name] = state[name].at[:, cols].add(
-                            ph.astype(dt))
+                        new_state[name] = fold(state[name], add_parts[name])
                 elif scatter == "add" and flat_adds:
                     with jax.named_scope(HIST):
                         ph = jnp.zeros((nseg,), dt).at[
@@ -210,10 +262,11 @@ def make_superscan_step(agg, K, S, NSB, F, R, SPW, chunk, exact,
         return _fire_purge(
             state, count, outs, count_out,
             phase_c if phase_counters else None,
-            jnp.sum((idx >= 0).astype(jnp.int32)),
+            jnp.sum((idx >= 0).astype(jnp.int32)), one_slice,
             (fire_pos, fire_valid, fire_row, purge_mask))
 
-    def _fire_purge(state, count, outs, count_out, phase_c, ingested, plan):
+    def _fire_purge(state, count, outs, count_out, phase_c, ingested,
+                    one_slice, plan):
         """Fire + purge, shared verbatim by the lane-scatter and
         pre-reduced ('partials') ingest forms — the combine path must be a
         different INGEST, never a different fire/purge."""
@@ -286,6 +339,7 @@ def make_superscan_step(agg, K, S, NSB, F, R, SPW, chunk, exact,
                 ingested.astype(jnp.int32),
                 jnp.sum(fire_valid).astype(jnp.int32),
                 purged.astype(jnp.int32),
+                one_slice.astype(jnp.int32),
             ])
             return (state, count, outs, count_out, phase_c), None
         return (state, count, outs, count_out), None
@@ -449,6 +503,7 @@ def make_global_scan_step(agg, S, NSB, F, R, SPW, fire_spws=None,
                 jnp.sum((idx >= 0).astype(jnp.int32)),
                 jnp.sum(fire_valid).astype(jnp.int32),
                 purged.astype(jnp.int32),
+                (~jnp.any(srel > 0)).astype(jnp.int32),
             ])
             return (state, count, outs, count_out, phase_c), None
         return (state, count, outs, count_out), None
@@ -480,7 +535,7 @@ def build_global_superscan(agg, S, NSB, F, R, SPW, T, B,
                              purge_mask):
         carry0 = (state, count, outs, count_out)
         if phases:
-            carry0 = carry0 + (jnp.zeros((3,), jnp.int32),)
+            carry0 = carry0 + (jnp.zeros((PHASE_COUNTS,), jnp.int32),)
         carry, _ = jax.lax.scan(
             step, carry0,
             (idx, vals, smin_pos, fire_pos, fire_valid, fire_row,

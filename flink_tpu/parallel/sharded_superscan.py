@@ -76,6 +76,7 @@ from flink_tpu.metrics.device_phases import EXCHANGE, PROLOGUE
 from flink_tpu.metrics.task_io import dispatch_stage
 from flink_tpu.ops.aggregators import VALUE, combine_reduce, decomposable
 from flink_tpu.ops.superscan import (
+    PHASE_COUNTS,
     default_ingest,
     make_segment_partials,
     make_superscan_step,
@@ -532,7 +533,7 @@ class ShardedFusedPipeline:
             count_out0 = jnp.zeros((R, Kl), jnp.int32)
             carry0 = (state, count, outs0, count_out0)
             if phases:
-                carry0 = carry0 + (jnp.zeros((3,), jnp.int32),)
+                carry0 = carry0 + (jnp.zeros((PHASE_COUNTS,), jnp.int32),)
             carry, _ = jax.lax.scan(
                 routed_step,
                 carry0,
@@ -549,7 +550,7 @@ class ShardedFusedPipeline:
                 count_out[None], tuple(outs[nm][None] for nm in names),
             )
             if phases:
-                out = out + (pc[None],)   # [1, 3] per shard
+                out = out + (pc[None],)   # [1, PHASE_COUNTS] per shard
             return out
 
         out_specs = (
@@ -559,7 +560,8 @@ class ShardedFusedPipeline:
             (P(axis, None, None),) * nf,
         )
         if phases:
-            out_specs = out_specs + (P(axis, None),)  # phase counters [n,3]
+            # phase counters [n, PHASE_COUNTS]
+            out_specs = out_specs + (P(axis, None),)
         in_specs = (
             P(axis, None, None),                      # count [n,Kl,S]
             (P(axis, None, None),) * nf,              # field states
@@ -767,7 +769,7 @@ class ShardedFusedPipeline:
             count_out0 = jnp.zeros((R, Kl), jnp.int32)
             inner0 = (state, count, outs0, count_out0)
             if phases:
-                inner0 = inner0 + (jnp.zeros((3,), jnp.int32),)
+                inner0 = inner0 + (jnp.zeros((PHASE_COUNTS,), jnp.int32),)
             kb0 = jnp.asarray([-1, 0], jnp.int32)
             xs = (raw, srel)
             if needs_ts:
@@ -1048,7 +1050,8 @@ class _MeshProgram:
             self.name, run, args, {"T": T, "B": B, "n": p.n, **record_sig})
         p._state = dict(zip(names, states))
         key_bounds = tail.pop(0) if self.chained else None
-        # phase counters [n, 3]: read back per shard, folded at resolve
+        # phase counters [n, PHASE_COUNTS]: read back per shard, folded at
+        # resolve
         return (count_out, dict(zip(names, field_outs)), key_bounds,
                 tail[0] if tail else None)
 

@@ -1107,6 +1107,9 @@ class WindowStepRunner(StepRunner):
                 group.gauge("phasePurgeSteps",
                             lambda: phases()["purgeSteps"],
                             fold="sum", kind="counter")
+                group.gauge("phaseOneSliceSteps",
+                            lambda: phases()["oneSliceSteps"],
+                            fold="sum", kind="counter")
         if self.key_stats is not None:
             self.key_stats.register(group)
         # state-tier gauges (state/tier_manager.py): counters/sizes SUM

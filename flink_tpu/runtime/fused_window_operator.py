@@ -1092,10 +1092,13 @@ class FusedWindowOperator:
     def phase_totals(self) -> Dict[str, int]:
         """Cumulative per-phase superscan step counters (resolved
         dispatches only): records ingested, fire slots executed, steps
-        that purged — where a laggard kernel's device time goes."""
+        that purged — where a laggard kernel's device time goes — and
+        steps whose live records lay in one slice (a dispatch's pad steps
+        among them; on a mesh summed over the shards): the steps the
+        matmul ingest contracts K segments for, not K * NSB."""
         t = self.pipe.phase_totals
         return {"ingestRecords": int(t[0]), "fireSteps": int(t[1]),
-                "purgeSteps": int(t[2])}
+                "purgeSteps": int(t[2]), "oneSliceSteps": int(t[3])}
 
     def key_loads(self):
         """Device-resident per-key record counts for the key-stats fold."""

@@ -251,10 +251,10 @@ class DeferredEmissions:
         # int32: [max_seen, min_seen], then on a mesh (routed, lanes) per shard
         self._key_bounds = key_bounds
         self._key_capacity = key_capacity
-        # int32[3] per-phase step counters of this dispatch, [n, 3] from a
-        # mesh (device-plane observability); folded into the pipeline's
-        # totals at resolve so the readback rides the same async copy as
-        # the fire rows
+        # int32[PHASE_COUNTS] per-phase step counters of this dispatch,
+        # [n, PHASE_COUNTS] from a mesh (device-plane observability); folded
+        # into the pipeline's totals at resolve so the readback rides the
+        # same async copy as the fire rows
         self._phase_counts = phase_counts
         #: bytes resolve() reads back (the stage clock's d2hBytes)
         self.nbytes = sum(
@@ -275,7 +275,8 @@ class DeferredEmissions:
     def resolve(self):
         if self._phase_counts is not None:
             self._pipe.phase_totals += np.asarray(
-                self._phase_counts, dtype=np.int64).reshape(-1, 3).sum(axis=0)
+                self._phase_counts,
+                dtype=np.int64).reshape(-1, PHASE_COUNTS).sum(axis=0)
             self._phase_counts = None
         if self._key_bounds is not None:
             bounds = np.asarray(self._key_bounds)
@@ -711,7 +712,8 @@ class FusedWindowPipeline:
         self.compile_tracker = None
         self.stage_clock = None
         self.phase_counters = False
-        self.phase_totals = np.zeros(3, np.int64)  # [ingest, fire, purge]
+        # [ingest, fire, purge, one-slice steps]
+        self.phase_totals = np.zeros(PHASE_COUNTS, np.int64)
         # latency-mode dispatch shape (scheduler/latency_controller.py),
         # flipped by the operator when execution.latency.target-ms is on:
         # donate_carry donates the [K, S] scan carry to the executable
@@ -1385,7 +1387,7 @@ class FusedWindowPipeline:
             kb0 = jnp.asarray([-1, 0], jnp.int32)
             inner0 = (state, count, outs, count_out)
             if phases:
-                inner0 = inner0 + (jnp.zeros((3,), jnp.int32),)
+                inner0 = inner0 + (jnp.zeros((PHASE_COUNTS,), jnp.int32),)
             (inner, key_bounds), _ = jax.lax.scan(body, (inner0, kb0), xs)
             if phases:
                 state, count, outs, count_out, pc = inner
@@ -1569,7 +1571,10 @@ _SCAN, _CHAINED, _PALLAS = _ScanProgram(False), _ScanProgram(True), _PallasProgr
 #: the per-step ingest/fire/purge body now lives in ops/superscan.py (a
 #: pure device-kernel builder, importable from `parallel/` without a
 #: runtime edge — ARCH001); re-exported here for existing callers
-from flink_tpu.ops.superscan import make_superscan_step  # noqa: E402,F401
+from flink_tpu.ops.superscan import (  # noqa: E402,F401
+    PHASE_COUNTS,
+    make_superscan_step,
+)
 
 
 @functools.lru_cache(maxsize=None)
@@ -1578,7 +1583,7 @@ def _build_superscan(agg, K, S, NSB, F, R, SPW, chunk, exact, T, B,
                      donate: bool = False):
     """Compiled T-step superscan; module-level cache so every pipeline with
     identical geometry (incl. warmup instances) shares one executable.
-    With `phases` the program additionally returns the int32[3] per-phase
+    With `phases` the program additionally returns the int32[4] per-phase
     step counters threaded through the scan carry (device-plane
     observability); the flag is part of the cache key, so gated jobs and
     ungated jobs never share an executable shape. `fire_spws` (shared
@@ -1603,7 +1608,7 @@ def _build_superscan(agg, K, S, NSB, F, R, SPW, chunk, exact, T, B,
                                 smin_pos, fire_pos, fire_valid, fire_row,
                                 purge_mask):
             carry0 = (state, count, outs, count_out,
-                      jnp.zeros((3,), jnp.int32))
+                      jnp.zeros((PHASE_COUNTS,), jnp.int32))
             (state, count, outs, count_out, pc), _ = jax.lax.scan(
                 step, carry0,
                 (idx, vals, smin_pos, fire_pos, fire_valid, fire_row,

@@ -160,7 +160,7 @@ function deviceSection(dev) {
     <td>${fmt(o.compile?.numCompiles)} / ${fmt(o.compile?.numRecompiles)}</td>
     <td>${fmt(o.hbmUtilizationPct, 2)} / ${fmt(o.flopsUtilizationPct, 2)}</td>
     <td>${fmt(o.phases?.ingestRecords)} / ${fmt(o.phases?.fireSteps)}
-        / ${fmt(o.phases?.purgeSteps)}</td>
+        / ${fmt(o.phases?.purgeSteps)} / ${fmt(o.phases?.oneSliceSteps)}</td>
     <td>${fmt(o.keys?.keySkew, 2)}</td>
     <td>${fmt(o.keys?.activeKeys)}</td>
     <td>${esc((o.keys?.hotKeys ?? []).slice(0, 3)
@@ -175,7 +175,7 @@ function deviceSection(dev) {
       ? `${fmt(prof.captures)} &rarr; ${esc(prof.last_capture_dir ?? "-")}`
       : "off",
   }) + (ops.length ? `<table><thead><tr><th>operator</th>
-    <th>compiles/re</th><th>hbm/flops %</th><th>ingest/fire/purge</th>
+    <th>compiles/re</th><th>hbm/flops %</th><th>ingest/fire/purge/one-slice</th>
     <th>key skew</th><th>active keys</th><th>hot keys</th></tr></thead>
     <tbody>${ops.join("")}</tbody></table>` : "")
     + skewTable(dev)

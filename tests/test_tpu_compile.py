@@ -12,6 +12,7 @@ Nothing here executes on a device; results are checked elsewhere
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -354,8 +355,6 @@ def test_sharded_chained_superscan_compiles_on_the_2x2_mesh(v5e, monkeypatch):
 def _scopes(compiled):
     """[(HLO line, op_name, phase, nested name)] of a compiled program's
     instructions that carry an `op_name`."""
-    import re
-
     from flink_tpu.metrics.device_phases import phase_of
 
     out = []
@@ -389,6 +388,117 @@ def test_compiled_window_programs_carry_their_phases(v5e, monkeypatch,
     assert all(phase for _o, phase in loops), loops
     assert {sub for _l, _o, phase, sub in scopes if phase == "ingest"} >= {
         "hist", "fold"}
+
+
+def _computations(hlo):
+    """{computation: its instruction lines} of a compiled program's text."""
+    comps, lines = {}, None
+    for line in hlo.splitlines():
+        header = re.match(r"(?:ENTRY )?%([\w.\-]+) \(.*\{$", line)
+        if header:
+            lines = comps[header.group(1)] = []
+        elif line.startswith("}"):
+            lines = None
+        elif lines is not None and " = " in line:
+            lines.append(line)
+    return comps
+
+
+def _called(line):
+    """The computations an instruction runs (branches, loop body and
+    condition, a fusion's)."""
+    names = re.findall(r"(?:body|condition|calls|to_apply)=%([\w.\-]+)", line)
+    for branches in re.findall(r"branch_computations=\{([^}]*)\}", line):
+        names += re.findall(r"%([\w.\-]+)", branches)
+    return names
+
+
+def _under(comps, roots):
+    """Every instruction line of `roots` and of what they run, nested."""
+    seen, todo, out = set(), list(roots), []
+    while todo:
+        name = todo.pop()
+        if name in seen or name not in comps:
+            continue
+        seen.add(name)
+        for line in comps[name]:
+            out.append(line)
+            todo.extend(_called(line))
+    return out
+
+
+@pytest.mark.parametrize("program", ["chain.1000", "chain.2000", "ysb",
+                                     "sharded"])
+def test_the_ingest_is_as_wide_as_the_step_and_leaves_the_ring_alone(
+        v5e, monkeypatch, program):
+    """What PERF.md section 6 (PR 36) read in the compiled step before a
+    chip was asked for, held for the served chain at both slides, the YSB
+    chain and a shard of the 2x2 mesh: ONE conditional under `ingest/hist`
+    picks the step's histogram, K / 128 rows of 128 where the step's records
+    lie in one slice and K * NSB / 128 where they do not; the `[K * NSB]`
+    reshape and the `[NSB, K]` relayout run in the wide branch alone; the
+    ring is no operand of it (XLA:TPU gives a conditional's operands their
+    default layout: slice-minor, a relayout of the whole ring in every
+    step), is carried key-minor through the scan and through the fold's
+    loop, and nothing in the scan's own body copies or transposes it."""
+    from flink_tpu.metrics.device_phases import INGEST, phase_of
+
+    if program == "sharded":
+        pipe, compiled = _sharded_chain(v5e, monkeypatch)
+        K = pipe.K_local
+    elif program == "ysb":
+        pipe, compiled = _ysb_chain(v5e, monkeypatch)
+        K = pipe.K
+    else:
+        pipe, compiled = _served_chain(v5e, monkeypatch,
+                                       int(program.split(".")[1]))
+        K = pipe.K
+    S, NSB, R = pipe.S, pipe.NSB, EXEC["R"]
+    ring, key_minor = f"s32[{K},{S}]", f"s32[{K},{S}]{{0,1"
+    comps = _computations(compiled.as_text())
+    every = [ln for lines in comps.values() for ln in lines]
+
+    # the scan: the one loop that carries the ring beside the fire buffer
+    (scan,) = [ln for ln in every if " while(" in ln and ring in ln
+               and f"s32[{R},{K}]" in ln]
+    assert key_minor in scan and f"{ring}{{1,0" not in scan
+    body = comps[re.search(r"body=%([\w.\-]+)", scan).group(1)]
+    assert not [ln for ln in body if ring in ln.split(" = ")[1].split("(")[0]
+                and (" copy(" in ln or " transpose(" in ln)]
+
+    # ingest's conditional, in the scan's body, under ingest/hist
+    (cond,) = [ln for ln in body if " conditional(" in ln
+               and "/ingest/hist/cond" in ln]
+    branches = _called(cond)
+    assert len(branches) == 2
+    inside = _under(comps, branches)
+    assert not [ln for ln in inside if ring in ln]       # operands included
+    dots = sorted(ln.split(" = ")[1].split("{")[0] for ln in inside
+                  if " convolution(" in ln)
+    assert dots == sorted([f"s32[{K // 128},128]",
+                           f"s32[{K * NSB // 128},128]"])
+    narrow, wide = sorted(branches, key=lambda b: f"s32[{K * NSB // 128},128]"
+                          in "".join(_under(comps, [b])))
+    reshaped = f"s32[{K},{NSB}]"
+    assert [ln for ln in comps[wide] if reshaped in ln]
+    assert not [ln for ln in _under(comps, [narrow])
+                if reshaped in ln or " copy(" in ln and f"[{NSB},{K}]" in ln]
+    assert [ln for ln in comps[narrow] if " pad(" in ln]
+    # a capture's phase table (`device_phases.phase_table`) gives an op its
+    # own `op_name`'s phase and one whose `op_name` names none (the relayout
+    # copy, the loops' counters) its enclosing op's, here the conditional's:
+    # no instruction in either branch names another phase
+    # (a branch's parameter keeps its producer's name and runs nothing)
+    ours = {cond, *inside}
+    scoped = [phase for line, _o, phase, _sub in _scopes(compiled)
+              if line in ours and " parameter(" not in line]
+    assert len(scoped) > 20 and set(scoped) - {None} == {INGEST}
+    assert phase_of(re.search(r'op_name="([^"]*)"', cond).group(1)) == (
+        INGEST, "hist")
+
+    # the fold: a loop of its own that carries the ring key-minor
+    (fold,) = [ln for ln in body if " while(" in ln and "/ingest/fold/" in ln]
+    assert key_minor in fold
 
 
 def test_ysb_prologue_shows_one_scope_per_transform(v5e, monkeypatch):
