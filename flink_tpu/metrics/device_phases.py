@@ -24,11 +24,18 @@ PROLOGUE, EXCHANGE, INGEST, FIRE, PURGE = PHASES
 KEY, VALUE, BOUNDS = "key", "value", "bounds"
 #: nested in `ingest` (`ops/superscan.make_superscan_step`): the step's
 #: [K, NSB] partial histogram, that partial folded into the ring's columns,
-#: a per-record scatter into the ring
+#: a per-record scatter into the ring: the count's
 HIST, FOLD, SCATTER = "hist", "fold", "scatter"
+#: the same three pieces of work done for the aggregate's VALUE fields (a
+#: sum's weighted histogram and its fold, a min's scatter), each under a
+#: name of its own, so that a table tells what a value column costs from
+#: what counting costs; absent from a count-only program
+_OF_VALUES = ".value"
+HIST_VALUE, FOLD_VALUE, SCATTER_VALUE = (
+    name + _OF_VALUES for name in (HIST, FOLD, SCATTER))
 _NESTED = {
     PROLOGUE: re.compile(r"t\d+\.(map|filter|map_ts)|key|value|bounds"),
-    INGEST: re.compile(r"hist|fold|scatter"),
+    INGEST: re.compile(r"(hist|fold|scatter)(\.value)?"),
 }
 
 
@@ -41,13 +48,21 @@ def phase_of(op_name: str) -> Tuple[Optional[str], Optional[str]]:
     """(phase, nested name) of an op from the `op_name` the compiler keeps
     for it (`jit(run)/while/body/closed_call/ingest/hist/dot_general`): the
     first path component that is one of `PHASES`, and the component after it
-    where that is one of the phase's nested names."""
+    where that is one of the phase's nested names. The VALUE fields' share of
+    a piece whose one op serves both kinds of field (the histogram's
+    conditional: `ingest/hist/cond/branch_1_fun/hist.value/...`) is named
+    deeper, and goes by that name."""
     parts = (op_name or "").split("/")
     for i, part in enumerate(parts):
         if part in PHASES:
             nested = _NESTED.get(part)
             sub = parts[i + 1] if i + 1 < len(parts) else ""
-            return part, sub if nested and nested.fullmatch(sub) else None
+            if not (nested and nested.fullmatch(sub)):
+                return part, None
+            deeper = sub + _OF_VALUES
+            if nested.fullmatch(deeper) and deeper in parts[i + 2:]:
+                sub = deeper
+            return part, sub
     return None, None
 
 

@@ -76,6 +76,30 @@ def count_hist(idx: jnp.ndarray, num_segments: int, *, chunk: int = 8192) -> jnp
     return acc.reshape(-1)[:num_segments]
 
 
+def bf16_terms(vals: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """f32 values as THREE bf16 terms with v == t0 + t1 + t2 bit for bit
+    (8 + 8 + 8 mantissa bits cover f32's 24; the precise contract is
+    weighted_hist's).
+
+    Each term is rounded by `lax.reduce_precision`, never by a convert to
+    bf16 and back: XLA:TPU takes `f32 -> bf16 -> f32` for an identity it may
+    skip (excess precision is allowed by default), the residual `v - t0`
+    then reads 0, and the "exact" sum is the one-term sum. That is what the
+    first chip run of a value column found (PERF.md section 6, PR 37): 99.6 %
+    of 44 M window sums of 14-bit prices wrong on the chip with every CPU
+    test passing. `reduce_precision` exists to be honoured, and the converts
+    left below act on values bf16 holds exactly, so skipping them changes
+    nothing."""
+    def rounded(x):
+        return jax.lax.reduce_precision(x, exponent_bits=8, mantissa_bits=7)
+
+    t0 = rounded(vals)
+    r1 = vals - t0
+    t1 = rounded(r1)
+    r2 = r1 - t1        # what is left has 8 significant bits or fewer
+    return tuple(t.astype(jnp.bfloat16) for t in (t0, t1, r2))
+
+
 def weighted_hist(
     idx: jnp.ndarray,
     vals: jnp.ndarray,
@@ -87,10 +111,11 @@ def weighted_hist(
     """f32[num_segments] per-segment sums of vals; out-of-range ids dropped.
 
     Exactness contract (honest version):
-    - exact=True splits each f32 value into THREE bf16 terms, v == t0+t1+t2
-      bit-exactly for every finite f32 whose twice-reduced residual does not
-      underflow bf16's subnormal floor (all values with |v| >= ~2**-110,
-      and 0). Each bf16 x {0,1} one-hot product is exact, so every record's
+    - exact=True splits each f32 value into THREE bf16 terms (bf16_terms),
+      v == t0+t1+t2 bit-exactly for every finite f32 whose twice-reduced
+      residual does not underflow bf16's subnormal floor (all values with
+      |v| >= ~2**-110, and 0). Each bf16 x {0,1} one-hot product is exact,
+      so every record's
       value enters the f32 accumulator unquantized; the per-segment SUM is
       then an f32 accumulation, equal to a per-record f32 sum up to
       addition order. It is NOT f64 accumulation (the reference's
@@ -106,16 +131,8 @@ def weighted_hist(
     def body(acc, args):
         ii, vv = args
         oh_hi, oh_lo = _one_hots(ii, hi_n, jnp.bfloat16)
-        if exact:
-            t0 = vv.astype(jnp.bfloat16)
-            r1 = vv - t0.astype(jnp.float32)
-            t1 = r1.astype(jnp.bfloat16)
-            r2 = r1 - t1.astype(jnp.float32)
-            t2 = r2.astype(jnp.bfloat16)
-            for t in (t0, t1, t2):
-                acc = acc + _dot(oh_hi * t[:, None], oh_lo, jnp.float32)
-        else:
-            acc = acc + _dot(oh_hi * vv[:, None].astype(jnp.bfloat16), oh_lo, jnp.float32)
+        for t in (bf16_terms(vv) if exact else (vv.astype(jnp.bfloat16),)):
+            acc = acc + _dot(oh_hi * t[:, None], oh_lo, jnp.float32)
         return acc, None
 
     n = idx.shape[0] // chunk

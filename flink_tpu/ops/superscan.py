@@ -119,7 +119,8 @@ def make_superscan_step(agg, K, S, NSB, F, R, SPW, chunk, exact,
     import jax.numpy as jnp
 
     from flink_tpu.metrics.device_phases import (
-        FIRE, FOLD, HIST, INGEST, PURGE, SCATTER)
+        FIRE, FOLD, FOLD_VALUE, HIST, HIST_VALUE, INGEST, PURGE, SCATTER,
+        SCATTER_VALUE)
     from flink_tpu.ops import matmul_hist
     from flink_tpu.ops.aggregators import VALUE
 
@@ -148,13 +149,15 @@ def make_superscan_step(agg, K, S, NSB, F, R, SPW, chunk, exact,
             # idx is the step's [K, NSB] count partial, vals the tuple of
             # per-VALUE-field [K, NSB] partials — one dense column combine
             # per field, same add/min/max semantics as the lane scatter
-            with jax.named_scope(INGEST), jax.named_scope(FOLD):
+            with jax.named_scope(INGEST):
                 cpart = idx
-                count = count.at[:, cols].add(cpart)
+                with jax.named_scope(FOLD):
+                    count = count.at[:, cols].add(cpart)
                 new_state = {}
                 for (name, dt, scatter, _ident), part in zip(vfields, vals):
-                    upd = getattr(state[name].at[:, cols], scatter)
-                    new_state[name] = upd(part.astype(dt))
+                    with jax.named_scope(FOLD_VALUE):
+                        upd = getattr(state[name].at[:, cols], scatter)
+                        new_state[name] = upd(part.astype(dt))
                 state = new_state if vfields else state
             return _fire_purge(
                 state, count, outs, count_out, phase_c if phase_counters
@@ -169,7 +172,9 @@ def make_superscan_step(agg, K, S, NSB, F, R, SPW, chunk, exact,
             # them on the fused path at all). Nested scopes name its pieces
             # for a capture's phase table (metrics/device_phases.py): HIST
             # the step's [K, NSB] partial, FOLD that partial added into the
-            # ring's columns, SCATTER a per-record scatter into the ring
+            # ring's columns, SCATTER a per-record scatter into the ring;
+            # the same pieces for the VALUE fields under names of their own
+            # (HIST_VALUE, FOLD_VALUE, SCATTER_VALUE)
             kid = idx // NSB
             srel = idx % NSB
             col = (smin_pos + srel) % S
@@ -184,24 +189,28 @@ def make_superscan_step(agg, K, S, NSB, F, R, SPW, chunk, exact,
             # the batch, so huge-K geometries keep the direct scatter
             flat_adds = ingest != "matmul" and nseg <= 16 * idx.shape[0]
             if ingest == "matmul":
-                def hists(seg, nsegs):
-                    return matmul_hist.count_hist(seg, nsegs, chunk=chunk), {
-                        name: matmul_hist.weighted_hist(
-                            seg, vals, nsegs, chunk=chunk, exact=exact)
-                        for name, _dt, scatter, _ident in vfields
-                        if scatter == "add"}
+                def hists(seg, nsegs, as_partial):
+                    # the count's histogram, then one per add-combining
+                    # VALUE field, each shaped into the step's [NSB, K]
+                    # partial where it is made
+                    pc = as_partial(
+                        matmul_hist.count_hist(seg, nsegs, chunk=chunk))
+                    with jax.named_scope(HIST_VALUE):
+                        return pc, {
+                            name: as_partial(matmul_hist.weighted_hist(
+                                seg, vals, nsegs, chunk=chunk, exact=exact))
+                            for name, _dt, scatter, _ident in vfields
+                            if scatter == "add"}
 
                 def narrow():
                     # [K] histograms over the key alone (a dead lane's
                     # -1 // NSB stays out of range): slice 0 of the step's
                     # partial, the slices above it zero
-                    return jax.tree.map(
-                        lambda h: jnp.pad(h[None], ((0, NSB - 1), (0, 0))),
-                        hists(idx // NSB, K))
+                    return hists(idx // NSB, K, lambda h: jnp.pad(
+                        h[None], ((0, NSB - 1), (0, 0))))
 
                 def wide():
-                    return jax.tree.map(
-                        lambda h: h.reshape(K, NSB).T, hists(idx, nseg))
+                    return hists(idx, nseg, lambda h: h.reshape(K, NSB).T)
 
                 def fold(ring, part):
                     # part's slices into their ring columns, the live ones
@@ -241,21 +250,21 @@ def make_superscan_step(agg, K, S, NSB, F, R, SPW, chunk, exact,
             new_state = {}
             for name, dt, scatter, ident in vfields:
                 if scatter == "add" and ingest == "matmul":
-                    with jax.named_scope(FOLD):
+                    with jax.named_scope(FOLD_VALUE):
                         new_state[name] = fold(state[name], add_parts[name])
                 elif scatter == "add" and flat_adds:
-                    with jax.named_scope(HIST):
+                    with jax.named_scope(HIST_VALUE):
                         ph = jnp.zeros((nseg,), dt).at[
                             jnp.where(idx >= 0, idx, nseg)].add(
                             vals.astype(dt), mode="drop").reshape(K, NSB)
-                    with jax.named_scope(FOLD):
+                    with jax.named_scope(FOLD_VALUE):
                         new_state[name] = state[name].at[:, cols].add(ph)
                 elif scatter == "add":
-                    with jax.named_scope(SCATTER):
+                    with jax.named_scope(SCATTER_VALUE):
                         new_state[name] = state[name].at[safe_kid, col].add(
                             vals.astype(dt), mode="drop")
                 else:
-                    with jax.named_scope(SCATTER):
+                    with jax.named_scope(SCATTER_VALUE):
                         upd = getattr(state[name].at[safe_kid, col], scatter)
                         new_state[name] = upd(vals.astype(dt), mode="drop")
             state = new_state if vfields else state

@@ -2417,6 +2417,9 @@ class JobRuntime:
             phases = getattr(getattr(r, "op", None), "phase_totals", None)
             if callable(phases):
                 entry["phases"] = phases()
+            rings = getattr(getattr(r, "op", None), "ring_counters", None)
+            if callable(rings):
+                entry.update(rings())
             if ks is not None:
                 entry["keys"] = ks.payload()
             tier_payload = getattr(getattr(r, "op", None), "tier_payload",

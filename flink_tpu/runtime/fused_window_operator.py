@@ -1175,6 +1175,14 @@ class FusedWindowOperator:
         n += int(getattr(getattr(self.pipe, "_count", None), "nbytes", 0) or 0)
         return n
 
+    def ring_counters(self) -> Dict[str, int]:
+        """The device-resident slice rings of this operator, for its block
+        of `metrics["device"]`: `valueFields`, the aggregate's VALUE fields
+        (each a ring of its own beside the count's; 0 for a count), and
+        `ringBytes`, the bytes of all of them (K x S x 4 a ring)."""
+        return {"valueFields": sum(f.source == VALUE for f in self.agg.fields),
+                "ringBytes": self.state_bytes()}
+
     def state_key_count(self) -> int:
         if self.tier is not None:
             return self.tier.vocab.vocab_size

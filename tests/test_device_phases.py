@@ -39,6 +39,19 @@ RUN = "jit(run_fused_chained_superscan)/while/body/closed_call/"
      ("ingest", "hist")),
     (RUN + "ingest/hist/reshape;ingest/hist/reshape", ("ingest", "hist")),
     (RUN + "ingest/fold/scatter-add", ("ingest", "fold")),
+    # the VALUE fields' share: a scope of its own beside the count's, or
+    # named deeper where one op (the histogram's conditional) serves both
+    (RUN + "ingest/fold.value/while/body/add", ("ingest", "fold.value")),
+    (RUN + "ingest/scatter.value/scatter-min", ("ingest", "scatter.value")),
+    (RUN + "ingest/hist/cond/branch_1_fun/hist.value/while/body/dot_general",
+     ("ingest", "hist.value")),
+    (RUN + "ingest/hist/cond/branch_1_fun/while/body/dot_general",
+     ("ingest", "hist")),
+    (RUN + "ingest/hist/cond", ("ingest", "hist")),
+    # no other piece's deeper name, and no other phase's
+    (RUN + "ingest/fold/while/body/hist.value/add", ("ingest", "fold")),
+    (RUN + "prologue/value/select_n", ("prologue", "value")),
+    (RUN + "prologue/key/value.value/convert", ("prologue", "key")),
     (RUN + "prologue/t1.map/gather", ("prologue", "t1.map")),
     (RUN + "prologue/t12.map_ts/add", ("prologue", "t12.map_ts")),
     (RUN + "prologue/bounds/reduce_max", ("prologue", "bounds")),
@@ -76,6 +89,44 @@ def test_the_programs_take_their_scope_names_from_phases():
         assert not [s for s in sites if '"' in s or "'" in s], (rel, sites)
     assert dp.PHASES == ("prologue", "exchange", "ingest", "fire", "purge")
     assert dp.transform_scope(2, "map_ts") == "t2.map_ts"
+
+
+def test_a_value_fields_ops_have_rows_of_their_own_and_the_phases_still_sum():
+    """One execution of a SUM program cut by hand: the conditional under
+    `ingest/hist` holds the count's loop and the weighted histogram's; the
+    latter's ops, and a compiler-made copy inside its loop, go to
+    `ingest/hist.value`, the conditional's own time and the count's loop stay
+    `ingest/hist`, the sum ring's fold is `ingest/fold.value`; the phases and
+    `other` sum to the module's time as they do without a value field."""
+    scopes = {"jit_run_x(1)": {
+        "cond.1": RUN + "ingest/hist/cond",
+        "while.2": RUN + "ingest/hist/cond/branch_1_fun/while",
+        "fusion.3": RUN + "ingest/hist/cond/branch_1_fun/while/body/dot_general",
+        "while.4": RUN + "ingest/hist/cond/branch_1_fun/hist.value/while",
+        "fusion.5": RUN + "ingest/hist/cond/branch_1_fun/hist.value/while/"
+                    "body/dot_general",
+        "while.7": RUN + "ingest/fold/while",
+        "while.8": RUN + "ingest/fold.value/while",
+        "fusion.9": RUN + "prologue/value/select_n"}}
+    ops = [(0, 10, "%fusion.9 = f32[8] fusion()"),
+           (10, 100, "%cond.1 = (s32[4,8], f32[4,8]) conditional()"),
+           (12, 30, "%while.2 = () while()"),
+           (14, 28, "%fusion.3 = s32[8] fusion()"),
+           (30, 95, "%while.4 = () while()"),
+           (32, 80, "%fusion.5 = f32[8] fusion()"),
+           (80, 90, "%copy.6 = f32[8] copy()"),       # no op_name: inherits
+           (100, 110, "%while.7 = () while()"),
+           (110, 125, "%while.8 = () while()")]
+    table = dp._cut([("jit_run_x(1)", 0, 130)], ops, scopes, top=5)
+    (m,) = table.values()
+    assert m["sub"] == pytest.approx({
+        "ingest/fold": 10e-6, "ingest/fold.value": 15e-6,
+        "ingest/hist": (7 + 4 + 14) * 1e-6,
+        "ingest/hist.value": (7 + 48 + 10) * 1e-6,
+        "prologue/value": 10e-6})
+    assert m["phases"] == pytest.approx({"prologue": 10e-6, "ingest": 115e-6})
+    assert sum(m["phases"].values()) + m["other"] == pytest.approx(m["ms"])
+    assert m["other"] == pytest.approx(5e-6)
 
 
 # -- the table over the hand-written capture ---------------------------------

@@ -203,9 +203,15 @@ _COMPILED = {}     # what this file compiled, by name: each program once
 
 
 def _compile_chained(v5e, pipe, run, T, B):
-    """A chained program over two staged f32 fields, for the first chip."""
+    """A chained program over two staged f32 fields, for the first chip; a
+    ring and a fire buffer per VALUE field of the aggregate beside the
+    count's."""
     dev, i32, K = v5e.devices[0], jnp.int32, pipe.K
-    args = ({}, _on(dev, (K, pipe.S), i32), {}, _on(dev, (pipe.R, K), i32),
+    vf = {f.name: jnp.dtype(f.dtype) for f in pipe._value_fields}
+    args = ({n: _on(dev, (K, pipe.S), dt) for n, dt in vf.items()},
+            _on(dev, (K, pipe.S), i32),
+            {n: _on(dev, (pipe.R, K), dt) for n, dt in vf.items()},
+            _on(dev, (pipe.R, K), i32),
             (_on(dev, (T, B), jnp.float32),) * 2, _on(dev, (T, B), i32),
             ) + _plan_specs(dev, T, pipe.F, pipe.S)
     return run.trace(*args).lower().compile()
@@ -238,6 +244,33 @@ def _served_chain(v5e, monkeypatch, slide_ms):
     run = pipe._build_chained_superscan(T, B, layout)
     _COMPILED[name] = pipe, _compile_chained(v5e, pipe, run, T, B)
     return _COMPILED[name]
+
+
+def _sum_chain(v5e, monkeypatch):
+    """`purchases_sum_catchup`'s program (benchmarks/jobs/keyed_sum_traced.py):
+    no filter, a traced key and a traced value function over the 4-field
+    purchase, SUM per key over an 8 s window sliding by 4 s: a count ring and
+    an f32 sum ring, the weighted histogram with its three bf16 terms."""
+    if "sum" in _COMPILED:
+        return _COMPILED["sum"]
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    K, T, B = 1 << 16, 32, 1 << 16
+    pipe = FusedWindowPipeline(
+        SlidingEventTimeWindows.of(8_000, 4_000), "sum", key_capacity=K,
+        fires_per_step=EXEC["F"], out_rows=EXEC["R"], chunk=EXEC["CH"],
+        plan_only=True,
+        prologue=TracedPrologue(
+            transforms=(),
+            key_fn=lambda col: col[:, 0].astype(jnp.int32),
+            value_fn=lambda col: col[:, 1]),
+    )
+    pipe._raw_shape, pipe._raw_dtype = (4,), jnp.float32
+    layout = pipe._layout()
+    # the two fields the chain reads of the purchase's four
+    assert (layout.columns, layout.width) == ((0, 1), 4)
+    run = pipe._build_chained_superscan(T, B, layout)
+    _COMPILED["sum"] = pipe, _compile_chained(v5e, pipe, run, T, B)
+    return _COMPILED["sum"]
 
 
 def test_chained_superscan_compiles_at_served_defaults(v5e, monkeypatch):
@@ -365,18 +398,23 @@ def _scopes(compiled):
     return out
 
 
-@pytest.mark.parametrize("program", ["q5", "sharded"])
+@pytest.mark.parametrize("program", ["q5", "sharded", "sum"])
 def test_compiled_window_programs_carry_their_phases(v5e, monkeypatch,
                                                      program):
     """The chip's compiler keeps the scopes `metrics/device_phases.py` reads a
     capture by: the chained program at NEXMark q5's geometry (a 10 s window
     hopping by 2 s) names four of PHASES, the sharded program all five, and
     every `while` and `conditional` inside the scan's body lies under one —
-    a loop under no phase would put its whole time in the table's `other`."""
+    a loop under no phase would put its whole time in the table's `other`.
+    The SUM program's VALUE field has nested rows of its own under `ingest`
+    (inside the histogram's conditional and beside the count's fold), and a
+    `value` row under `prologue`; a count-only program has none of them."""
     from flink_tpu.metrics.device_phases import EXCHANGE, PHASES
 
-    _pipe, compiled = (_served_chain(v5e, monkeypatch, 2_000)
-                       if program == "q5" else _sharded_chain(v5e, monkeypatch))
+    _pipe, compiled = {
+        "q5": lambda: _served_chain(v5e, monkeypatch, 2_000),
+        "sharded": lambda: _sharded_chain(v5e, monkeypatch),
+        "sum": lambda: _sum_chain(v5e, monkeypatch)}[program]()
     scopes = _scopes(compiled)
     found = {phase for _l, _o, phase, _sub in scopes if phase}
     expect = set(PHASES) if program == "sharded" else set(PHASES) - {EXCHANGE}
@@ -386,8 +424,19 @@ def test_compiled_window_programs_carry_their_phases(v5e, monkeypatch,
              and "/while/body/" in op_name]
     assert len(loops) >= 6      # ingest's loop(s), four fire slots, the purge
     assert all(phase for _o, phase in loops), loops
-    assert {sub for _l, _o, phase, sub in scopes if phase == "ingest"} >= {
-        "hist", "fold"}
+    ingest = {sub for _l, _o, phase, sub in scopes if phase == "ingest"}
+    assert ingest >= {"hist", "fold"}
+    of_values = {"hist.value", "fold.value"}
+    assert ingest & of_values == (of_values if program == "sum" else set())
+    if program == "sum":
+        # the weighted histogram's loops lie inside the conditional, under
+        # the value's name; the sum ring's fold is a loop of its own
+        deep = [op_name for line, op_name, _p, sub in scopes
+                if sub == "hist.value" and " while(" in line]
+        assert len(deep) == 2 and all(
+            "/ingest/hist/cond/branch_" in o for o in deep), deep
+        assert "value" in {sub for _l, _o, phase, sub in scopes
+                           if phase == "prologue"}
 
 
 def _computations(hlo):
@@ -428,7 +477,7 @@ def _under(comps, roots):
 
 
 @pytest.mark.parametrize("program", ["chain.1000", "chain.2000", "ysb",
-                                     "sharded"])
+                                     "sharded", "sum"])
 def test_the_ingest_is_as_wide_as_the_step_and_leaves_the_ring_alone(
         v5e, monkeypatch, program):
     """What PERF.md section 6 (PR 36) read in the compiled step before a
@@ -440,7 +489,10 @@ def test_the_ingest_is_as_wide_as_the_step_and_leaves_the_ring_alone(
     ring is no operand of it (XLA:TPU gives a conditional's operands their
     default layout: slice-minor, a relayout of the whole ring in every
     step), is carried key-minor through the scan and through the fold's
-    loop, and nothing in the scan's own body copies or transposes it."""
+    loop, and nothing in the scan's own body copies or transposes it. The SUM
+    program (`purchases_sum_catchup`) is held to the same for BOTH its rings:
+    the conditional yields an f32 partial beside the i32 one, from three bf16
+    dots a branch, and the sum ring has a fold loop of its own."""
     from flink_tpu.metrics.device_phases import INGEST, phase_of
 
     if program == "sharded":
@@ -449,22 +501,29 @@ def test_the_ingest_is_as_wide_as_the_step_and_leaves_the_ring_alone(
     elif program == "ysb":
         pipe, compiled = _ysb_chain(v5e, monkeypatch)
         K = pipe.K
+    elif program == "sum":
+        pipe, compiled = _sum_chain(v5e, monkeypatch)
+        K = pipe.K
     else:
         pipe, compiled = _served_chain(v5e, monkeypatch,
                                        int(program.split(".")[1]))
         K = pipe.K
     S, NSB, R = pipe.S, pipe.NSB, EXEC["R"]
     ring, key_minor = f"s32[{K},{S}]", f"s32[{K},{S}]{{0,1"
+    # the rings the scan carries: the count's, and one per VALUE field
+    rings = [ring] + ([f"f32[{K},{S}]"] if program == "sum" else [])
     comps = _computations(compiled.as_text())
     every = [ln for lines in comps.values() for ln in lines]
 
     # the scan: the one loop that carries the ring beside the fire buffer
     (scan,) = [ln for ln in every if " while(" in ln and ring in ln
                and f"s32[{R},{K}]" in ln]
-    assert key_minor in scan and f"{ring}{{1,0" not in scan
     body = comps[re.search(r"body=%([\w.\-]+)", scan).group(1)]
-    assert not [ln for ln in body if ring in ln.split(" = ")[1].split("(")[0]
-                and (" copy(" in ln or " transpose(" in ln)]
+    for a_ring in rings:
+        assert f"{a_ring}{{0,1" in scan and f"{a_ring}{{1,0" not in scan
+        assert not [ln for ln in body
+                    if a_ring in ln.split(" = ")[1].split("(")[0]
+                    and (" copy(" in ln or " transpose(" in ln)]
 
     # ingest's conditional, in the scan's body, under ingest/hist
     (cond,) = [ln for ln in body if " conditional(" in ln
@@ -472,11 +531,24 @@ def test_the_ingest_is_as_wide_as_the_step_and_leaves_the_ring_alone(
     branches = _called(cond)
     assert len(branches) == 2
     inside = _under(comps, branches)
-    assert not [ln for ln in inside if ring in ln]       # operands included
+    assert not [ln for ln in inside                      # operands included
+                if any(a_ring in ln for a_ring in rings)]
     dots = sorted(ln.split(" = ")[1].split("{")[0] for ln in inside
                   if " convolution(" in ln)
+    # a VALUE field that adds: three bf16 terms a value, a dot each, in
+    # either branch (`matmul_hist.weighted_hist`, `exact_sums=True`)
+    terms = 3 if program == "sum" else 0
     assert dots == sorted([f"s32[{K // 128},128]",
-                           f"s32[{K * NSB // 128},128]"])
+                           f"s32[{K * NSB // 128},128]"]
+                          + terms * [f"f32[{K // 128},128]",
+                                     f"f32[{K * NSB // 128},128]"])
+    # the terms are rounded by `reduce-precision`, two a branch, which the
+    # chip honours: rounded by a convert to bf16 and back, which XLA:TPU
+    # may skip, the chip summed ONE term and 99.6 % of 44 M window sums were
+    # wrong (`matmul_hist.bf16_terms`; PERF.md section 6, PR 37)
+    assert len([ln for ln in inside if " reduce-precision(" in ln
+                and "exponent_bits=8, mantissa_bits=7" in ln]) == (
+                    4 if program == "sum" else 0)
     narrow, wide = sorted(branches, key=lambda b: f"s32[{K * NSB // 128},128]"
                           in "".join(_under(comps, [b])))
     reshaped = f"s32[{K},{NSB}]"
@@ -499,6 +571,10 @@ def test_the_ingest_is_as_wide_as_the_step_and_leaves_the_ring_alone(
     # the fold: a loop of its own that carries the ring key-minor
     (fold,) = [ln for ln in body if " while(" in ln and "/ingest/fold/" in ln]
     assert key_minor in fold
+    if program == "sum":
+        (fold,) = [ln for ln in body
+                   if " while(" in ln and "/ingest/fold.value/" in ln]
+        assert f"f32[{K},{S}]{{0,1" in fold
 
 
 def test_ysb_prologue_shows_one_scope_per_transform(v5e, monkeypatch):
