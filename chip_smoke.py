@@ -9,6 +9,10 @@ with a plain numpy reference (per-slice `bincount` over the same
   leg 1  traced chain: from_source -> filter -> key_by -> 10 s / 1 s sliding
          count, 2^24 events over 65 536 keys (fills the default key
          capacity), the chained XLA superscan;
+  leg 1b a 16 384-row int32 table whose values span int32's whole range,
+         read by `jnp.take` in a traced map over 2^22 lanes: the prologue
+         does the gather as a one-hot contraction over four byte planes
+         (ops/table_lookup.py), compared with numpy's take;
   leg 2  host-keyed count (8192 keys) and sum (4096 keys), 2^22 events
          each: the Pallas kernel as the operator selects it, Mosaic-compiled;
   leg 3  leg 1 sharded over a 4-device mesh — only where 4 devices are
@@ -266,6 +270,58 @@ def leg1(seed, sizes):
     return facts, got, stream
 
 
+def leg_lookup(seed, sizes):
+    """The traced prologue's table lookup at its largest: 16 384 rows, four
+    byte planes, int32's two extremes among the values, indices in range,
+    negative (wrapped by `jnp.take`), >= N and < -N (read as its fill).
+    Only a chip run shows a precision trap (PERF.md section 6, PR 37)."""
+    import jax
+    import jax.numpy as jnp
+
+    from flink_tpu.runtime.fused_window_pipeline import TracedPrologue
+
+    rng = np.random.default_rng(seed)
+    N, lanes, B = 1 << 14, sizes["lookup_lanes"], sizes["lookup_batch"]
+    i32 = np.iinfo(np.int32)
+    table = rng.integers(i32.min, i32.max, N, endpoint=True,
+                         dtype=np.int64).astype(np.int32)
+    table[:2] = i32.min, i32.max
+    idx = rng.integers(-N - 4096, N + 4096, lanes).astype(np.int32)
+    dev_table = jnp.asarray(table)
+    pro = TracedPrologue(
+        transforms=(("map", lambda col: jnp.take(dev_table, col[:, 0])[:, None]),),
+        key_fn=lambda col: col[:, 0])
+    kb = jnp.asarray([-1, 0], jnp.int32)
+
+    def step(_, raw):
+        srel = jnp.zeros(raw.shape[:1], jnp.int32)
+        return None, pro.apply(raw, srel, None, kb, K=1, NSB=1,
+                               needs_vals=False)[1]
+
+    run = jax.jit(lambda xs: jax.lax.scan(step, None, xs)[1])
+    xs = idx.reshape(-1, B, 1)
+    compiled = run.lower(xs).compile()
+    lowered, kept = pro.gathers()
+    if (lowered, kept) != (1, ()):
+        raise AssertionError(f"leg 1b: gathers lowered / kept {lowered} / {kept}")
+    if " gather(" in compiled.as_text():
+        raise AssertionError("leg 1b: the compiled program still gathers")
+    t0 = time.perf_counter()
+    got = np.asarray(compiled(xs)).reshape(-1)
+    wall = time.perf_counter() - t0
+    wrapped = np.where(idx < 0, idx + N, idx)
+    inside = (wrapped >= 0) & (wrapped < N)
+    expect = np.where(inside, np.take(table, np.clip(wrapped, 0, N - 1)),
+                      i32.min)
+    wrong = int(np.count_nonzero(got != expect))
+    if wrong:
+        raise AssertionError(f"leg 1b: {wrong} of {lanes} lookups differ "
+                             "from numpy's take")
+    return {"leg": "leg1b-table-lookup", "rows": N, "lanes": lanes,
+            "byte_planes": 4, "outside": int((~inside).sum()),
+            "wall_s": round(wall, 3)}
+
+
 def leg2(seed, sizes, on_chip):
     from flink_tpu.config import Configuration
 
@@ -382,9 +438,11 @@ def leg3(leg1_got, leg1_stream):
 
 
 CHIP_SIZES = dict(events1=1 << 24, keys1=1 << 16, events2=1 << 22,
-                  keys2_count=8192, keys2_sum=4096, span_ms=40_000)
+                  keys2_count=8192, keys2_sum=4096, span_ms=40_000,
+                  lookup_lanes=1 << 22, lookup_batch=1 << 16)
 REHEARSAL_SIZES = dict(events1=1 << 22, keys1=1 << 16, events2=1 << 22,
-                       keys2_count=2048, keys2_sum=2048, span_ms=14_000)
+                       keys2_count=2048, keys2_sum=2048, span_ms=14_000,
+                       lookup_lanes=1 << 16, lookup_batch=1 << 12)
 
 
 def main(argv=None) -> int:
@@ -443,6 +501,8 @@ def main(argv=None) -> int:
     try:
         facts1, got1, stream1 = leg1(args.seed, sizes)
         print("leg 1 ok:", json.dumps(facts1))
+        leg = "leg 1b"
+        print("leg 1b ok:", json.dumps(leg_lookup(args.seed, sizes)))
         leg = "leg 2"
         for facts in leg2(args.seed, sizes, on_chip):
             print("leg 2 ok:", json.dumps(facts))

@@ -2417,9 +2417,10 @@ class JobRuntime:
             phases = getattr(getattr(r, "op", None), "phase_totals", None)
             if callable(phases):
                 entry["phases"] = phases()
-            rings = getattr(getattr(r, "op", None), "ring_counters", None)
-            if callable(rings):
-                entry.update(rings())
+            for counters in ("ring_counters", "prologue_counters"):
+                read = getattr(getattr(r, "op", None), counters, None)
+                if callable(read):
+                    entry.update(read())
             if ks is not None:
                 entry["keys"] = ks.payload()
             tier_payload = getattr(getattr(r, "op", None), "tier_payload",

@@ -1183,6 +1183,18 @@ class FusedWindowOperator:
         return {"valueFields": sum(f.source == VALUE for f in self.agg.fields),
                 "ringBytes": self.state_bytes()}
 
+    def prologue_counters(self) -> Dict[str, int]:
+        """The traced prologue's gathers, for this operator's block of
+        `metrics["device"]`: `prologueGathersLowered`, those done as a
+        one-hot contraction over a small constant table
+        (`ops/table_lookup.py`), and `prologueGathersKept`, those left a
+        gather — as its program was traced (0 / 0 without a prologue or
+        before the first trace)."""
+        lowered, kept = (self.prologue.gathers() if self.prologue is not None
+                         else (0, ()))
+        return {"prologueGathersLowered": lowered,
+                "prologueGathersKept": len(kept)}
+
     def state_key_count(self) -> int:
         if self.tier is not None:
             return self.tier.vocab.vocab_size

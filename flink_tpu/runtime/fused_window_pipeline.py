@@ -33,6 +33,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import weakref
 from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
@@ -117,6 +118,13 @@ class TracedPrologue:
         return _column_layout(self, int(raw_shape[0]),
                               np.dtype(raw_dtype).name, bool(needs_vals))
 
+    def gathers(self) -> Tuple[int, Tuple[str, ...]]:
+        """(gathers `apply` lowered to a table lookup, why each other one was
+        kept) when it was last traced, by this prologue or an equal one (the
+        programs are cached by prologue): the prologue's
+        `prologueGathersLowered` / `prologueGathersKept`; (0, ()) before."""
+        return _TRACED_GATHERS.get(self, (0, ()))
+
     def apply(self, raw, srel, ts, key_bounds, *, K: int, NSB: int,
               needs_vals: bool, layout: Optional[ColumnLayout] = None):
         """One scan step of the chain, traced: record lanes -> (live, keys,
@@ -132,6 +140,8 @@ class TracedPrologue:
         import jax
         import jax.numpy as jnp
 
+        from flink_tpu.ops import table_lookup
+
         if layout is None:
             col = raw
         else:
@@ -140,25 +150,35 @@ class TracedPrologue:
             col = jnp.stack([staged.get(c, unread)
                              for c in range(layout.width)], axis=1)
         mask = srel >= 0
+        # every callable goes through `table_lookup.call`: a gather from a
+        # small constant integer table becomes a one-hot contraction, a
+        # callable without one is called as it is; `gathers` reads its choices
+        lowerings = []
+
+        def call(fn, *args):
+            out, low = table_lookup.call(fn, *args)
+            lowerings.append(low)
+            return out
+
         # one nested scope per element of the chain: a capture's phase table
         # (metrics/device_phases.py) then has a row per transform
         for i, (kind, fn) in enumerate(self.transforms):
             with jax.named_scope(device_phases.transform_scope(i, kind)):
                 if kind == "map":
-                    col = fn(col)
+                    col = call(fn, col)
                 elif kind == "map_ts":
-                    col = fn(col, ts)
+                    col = call(fn, col, ts)
                 else:  # filter
-                    mask = mask & jnp.asarray(fn(col)).astype(bool)
+                    mask = mask & jnp.asarray(call(fn, col)).astype(bool)
         with jax.named_scope(device_phases.KEY):
-            keys = jnp.asarray(self.key_fn(col)).astype(jnp.int32)
+            keys = jnp.asarray(call(self.key_fn, col)).astype(jnp.int32)
             live = mask & (keys >= 0) & (keys < K)
             idx = jnp.where(live, keys * NSB + srel, jnp.int32(-1))
             idx = idx.astype(jnp.int32)
         if needs_vals:
             with jax.named_scope(device_phases.VALUE):
-                vcol = (self.value_fn(col) if self.value_fn is not None
-                        else col)
+                vcol = (call(self.value_fn, col)
+                        if self.value_fn is not None else col)
                 # dead/pad rows hold uninitialized staging bytes that can
                 # decode as NaN/inf; zero them BEFORE ingest or any shuffle —
                 # the matmul histogram multiplies the zero one-hot by the raw
@@ -169,6 +189,9 @@ class TracedPrologue:
                     live, jnp.asarray(vcol).astype(jnp.float32), 0.0)
         else:
             vals = jnp.zeros((1,), jnp.float32)
+        _TRACED_GATHERS[self] = (sum(low.lowered for low in lowerings),
+                                 tuple(why for low in lowerings
+                                       for why in low.kept))
         # key range observed over every SURVIVING record (pre range clamp):
         # an out-of-range key is a hard error at resolve, never a silent
         # drop or a silent alias of another key's (or shard's) row
@@ -181,6 +204,11 @@ class TracedPrologue:
             ])
         return live, keys, idx, vals, key_bounds
 
+
+#: `TracedPrologue.gathers`: each prologue's lowered / kept gathers from its
+#: latest trace; weak, so an entry lives as long as an equal prologue does
+#: (a cached program's key holds one)
+_TRACED_GATHERS: "weakref.WeakKeyDictionary" = weakref.WeakKeyDictionary()
 
 #: rows of the record the column analysis traces the chain on (column
 #: functions are per-record: the host chain runs them on any batch length)

@@ -339,6 +339,31 @@ def test_ysb_chain_compiles_per_column_and_builds_no_record(v5e, monkeypatch):
         + 3 * T * B * 4 + (1 << 20)
 
 
+@pytest.mark.parametrize("chain", ["ysb", "keys64k"])
+def test_the_joins_gather_is_a_contraction_and_nothing_else_moves(
+        v5e, monkeypatch, chain):
+    """`ysb_catchup`'s join, `jnp.take` over the 1 000-row campaign table, is
+    not a gather in the compiled program: a dot (a `convolution` on the
+    chip) under `t1.map/lookup` (PERF.md section 6, PR 38: the gather cost
+    11.2 ms a dispatch). A count chain with no gather, `keys64k_catchup`'s
+    geometry, compiles with no `lookup` scope at all."""
+    if chain == "ysb":
+        pipe, compiled = _ysb_chain(v5e, monkeypatch)
+        assert pipe.prologue.gathers() == (1, ())
+    else:
+        pipe, compiled = _served_chain(v5e, monkeypatch, 10_000)
+        assert pipe.prologue.gathers() == (0, ())
+    hlo = compiled.as_text()
+    assert " gather(" not in hlo
+    lookups = [line for line, op_name, _p, sub in _scopes(compiled)
+               if "/lookup/" in op_name]
+    if chain == "keys64k":
+        assert not lookups
+        return
+    assert any(" convolution(" in line and "/t1.map/lookup/" in line
+               for line in lookups), lookups
+
+
 def _sharded_chain(v5e, monkeypatch):
     """chip_smoke.py leg 3's program: the served job sharded over the four
     chips of one host, the keyBy exchange as an in-scan all-to-all."""
@@ -585,8 +610,8 @@ def test_ysb_prologue_shows_one_scope_per_transform(v5e, monkeypatch):
     subs = {sub for _l, _o, phase, sub in scopes if phase == "prologue" and sub}
     assert {s for s in subs if s.startswith("t")} == {"t0.filter", "t1.map"}
     assert subs >= {"key", "bounds"}
-    # the join's gather is the map's
-    assert any("gather" in op_name for _l, op_name, _p, sub in scopes
+    # the join is the map's: its lookup, nested under it
+    assert any("/t1.map/lookup/" in op_name for _l, op_name, _p, sub in scopes
                if sub == "t1.map")
 
 
