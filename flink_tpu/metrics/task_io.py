@@ -128,7 +128,8 @@ class TaskIOMetrics:
 STAGES = (
     "source.poll", "source.watermark", "chain.host", "keys.lookup",
     "normalize", "stage.fill", "stage.shard", "stage.put", "dispatch",
-    "resolve", "emit", "drain", "fire.reduce", "sink.write", "keys.stats",
+    "resolve", "emit", "drain", "fire.reduce", "table.output", "sink.write",
+    "keys.stats",
 )
 SPAN_PREFIX = "flink_tpu."
 

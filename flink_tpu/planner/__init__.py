@@ -32,6 +32,8 @@ from flink_tpu.planner.logical import (  # noqa: F401 — public surface
     LogicalPlan,
     TableInfo,
     Unsupported,
+    ViewInfo,
+    WindowMaximaJoin,
     build_logical_plan,
 )
 from flink_tpu.planner.lowering import LoweredQuery, lower
@@ -63,6 +65,12 @@ class SqlPlanReport:
         if self.fused and self.plan is not None:
             return self.plan.describe()
         return f"interpreted[{self.reason}]: {self.detail}"
+
+    def summary(self) -> Dict[str, Optional[str]]:
+        """Plain data for a job's metrics: the path, the fallback's reason
+        and detail (None on the fused path), the plan as text."""
+        return {"path": self.path, "reason": self.reason,
+                "detail": self.detail, "plan": self.describe()}
 
 
 def plan_query(
@@ -96,10 +104,12 @@ def plan_query(
                         path="interpreted", reason="unknown-table",
                         detail=f"no source for {name!r}")
         else:
-            src = sources.get(q.table)
+            # a view's statement reads the table under it
+            name = plan.scan.table.name
+            src = sources.get(name)
             if src is None:
                 return SqlPlanReport(
                     path="interpreted", reason="unknown-table",
-                    detail=f"no source for {q.table!r}")
+                    detail=f"no source for {name!r}")
             lowered = lower(plan, src)
     return SqlPlanReport(path="fused", plan=plan, lowered=lowered)

@@ -28,7 +28,13 @@ import dataclasses
 import math
 from typing import Any, Dict, List, Optional, Tuple
 
-from flink_tpu.table.sql import BoolExpr, Operand, Query, SelectItem
+from flink_tpu.table.sql import (
+    BoolExpr,
+    Operand,
+    Query,
+    SelectItem,
+    predicate_columns,
+)
 
 #: fallback catalog: reason code -> what keeps the statement on the
 #: interpreted path (docs/sql.md renders this table; the gateway reports
@@ -85,6 +91,16 @@ FALLBACK_CATALOG: Dict[str, str] = {
     "rowtime-in-expression": "the rowtime column rides the batch "
                              "timestamps; predicates/aggregates over it "
                              "have no value-column device form",
+    "view": "the view is not a projection of a table's columns under an "
+            "optional WHERE, so it has no inlined form",
+    "derived-table": "a derived table (a query in FROM) fuses only inside "
+                     "the per-window maxima join; elsewhere it runs on the "
+                     "host",
+    "window-maxima": "a join of derived tables fuses only as a windowed "
+                     "aggregate joined on its window bounds with the "
+                     "per-window MAX of the same aggregate over the same "
+                     "input under `>=` (keep each window's maxima, ties "
+                     "included); any other such join runs on the host",
 }
 
 
@@ -122,6 +138,19 @@ class TableInfo:
 
     def is_numeric(self, field: str) -> bool:
         return self.type_of(field) in ("int", "float")
+
+
+@dataclasses.dataclass(frozen=True)
+class ViewInfo:
+    """Catalog entry of a view the planner inlines: `SELECT <columns> FROM
+    <table> [WHERE <where>]`, the columns by name as the table has them.
+    `table` None: a view of any other form, which the planner refuses."""
+
+    name: str
+    table: Optional[str]
+    columns: Tuple[str, ...]
+    where: Any = None             # predicate AST over the table's columns
+    where_text: Optional[str] = None
 
 
 @dataclasses.dataclass
@@ -204,9 +233,16 @@ class Output:
     having_text: Optional[str] = None
     order_by: List[Tuple[str, bool]] = dataclasses.field(default_factory=list)
     limit: Optional[int] = None
+    # rules.rewrite_window_maxima: keep each window's rows whose aggregate is
+    # the window's maximum, every tied key; `roles` names what each output
+    # column holds ('key' | 'agg' | 'start' | 'end')
+    maxima: bool = False
+    roles: Optional[List[Tuple[str, str]]] = None
 
     def describe(self) -> str:
         extra = []
+        if self.maxima:
+            extra.append("keep=window maxima, ties kept")
         if self.having_text:
             extra.append(f"having={self.having_text}")
         if self.order_by:
@@ -280,6 +316,50 @@ class JoinLogicalPlan:
         return "\n".join(lines)
 
 
+@dataclasses.dataclass
+class WindowMaximaJoin:
+    """`( A ) AS a JOIN ( SELECT MAX(c.x) ... FROM ( C ) AS c GROUP BY ...
+    ) AS b ON ...` as built: A and C planned as windowed aggregates, the MAX
+    query and the condition as parsed. rules.rewrite_window_maxima proves
+    the shape (A and C structurally equal, B the per-window MAX of C's
+    aggregate, the condition the window bounds and `a.agg >= b.max`) and
+    turns it into A's plan with a maxima output, or refuses it."""
+
+    per_key: LogicalPlan
+    per_key_alias: str
+    per_window: LogicalPlan
+    max_query: Query
+    max_alias: str
+    query: Query
+
+    def describe(self) -> str:
+        return f"WindowMaximaJoin[{self.query.derived_join.on_text}]"
+
+
+def resolve_source(name: str, catalog: Dict[str, Any]
+                   ) -> Tuple[TableInfo, Any, Optional[str], Tuple[str, ...]]:
+    """(table, the WHERE its views put on it or None, that WHERE's text,
+    the columns visible under `name`) of a table or view name."""
+    entry = catalog.get(name)
+    if entry is None:
+        raise Unsupported("unknown-table", f"table {name!r}")
+    if isinstance(entry, TableInfo):
+        return entry, None, None, entry.fields
+    if entry.table is None:
+        raise Unsupported("view", f"view {name!r}")
+    table, pred, text, visible = resolve_source(entry.table, catalog)
+    hidden = [c for c in entry.columns if c not in visible]
+    if hidden:
+        raise Unsupported("view", f"view {name!r} selects {hidden}, which "
+                                  f"{entry.table!r} does not have")
+    if entry.where is not None:
+        pred = (entry.where if pred is None
+                else BoolExpr("and", pred, entry.where))
+        text = entry.where_text if text is None \
+            else f"{text} AND {entry.where_text}"
+    return table, pred, text, tuple(entry.columns)
+
+
 def render_predicate(node) -> str:
     """Stable text form of a predicate AST (parenthesized OR under AND)."""
     if isinstance(node, BoolExpr):
@@ -310,9 +390,16 @@ def build_logical_plan(
         raise Unsupported("union")
     if q.join is not None:
         return _build_join_plan(q, catalog)
-    table = catalog.get(q.table)
-    if table is None:
-        raise Unsupported("unknown-table", f"table {q.table!r}")
+    if q.derived_join is not None:
+        return _build_maxima_join(q, catalog)
+    if q.subquery is not None:
+        raise Unsupported("derived-table", f"FROM ( ... ) AS {q.table}")
+    return _window_agg_plan(q, catalog)
+
+
+def _window_agg_plan(q: Query, catalog: Dict[str, Any]) -> LogicalPlan:
+    """A windowed GROUP BY aggregate over a table or a view of one."""
+    table, view_pred, view_text, visible = resolve_source(q.table, catalog)
 
     aggs = [i for i in q.select if i.kind == "agg"]
     if any(i.kind == "ml_predict" for i in q.select):
@@ -353,8 +440,21 @@ def build_logical_plan(
         arg=None if agg_item.name == "*" else agg_item.name,
         output=agg_item.output_name,
     )
-    flt = (Filter(q.where_ast, q.where_text or "")
-           if q.where_ast is not None else None)
+    if visible is not table.fields:
+        # a view: the statement sees only the view's columns
+        used = list(q.group_by) + [q.window.time_col]
+        used += [agg.arg] if agg.arg is not None else []
+        if q.where_ast is not None:
+            used += predicate_columns(q.where_ast)
+        hidden = sorted({c for c in used if c not in visible})
+        if hidden:
+            raise Unsupported("unknown-column",
+                              f"view {q.table!r} has no column {hidden}")
+    pred, text = q.where_ast, q.where_text
+    if view_pred is not None:
+        pred = view_pred if pred is None else BoolExpr("and", view_pred, pred)
+        text = view_text if text is None else f"{view_text} AND {text}"
+    flt = Filter(pred, text or "") if pred is not None else None
     out = Output(
         columns=[i.output_name for i in q.select],
         having_text=q.having_text,
@@ -369,6 +469,28 @@ def build_logical_plan(
         output=out,
         query=q,
     )
+
+
+def _build_maxima_join(q: Query, catalog: Dict[str, Any]
+                       ) -> WindowMaximaJoin:
+    """The join of two derived tables, as far as its sides go: one a window
+    TVF aggregate (A), the other an aggregate over a derived window TVF
+    aggregate (C). Which condition and which MAX make it a maxima join is
+    rules.rewrite_window_maxima's to prove."""
+    dj = q.derived_join
+    sides = [(dj.left_alias, dj.left), (dj.right_alias, dj.right)]
+    if dj.left.subquery is not None and dj.right.subquery is None:
+        sides.reverse()
+    (a_alias, a), (b_alias, b) = sides
+    if not a.tvf or b.subquery is None or not b.subquery.tvf:
+        raise Unsupported(
+            "window-maxima",
+            f"{dj.left_alias} JOIN {dj.right_alias}: neither side is a "
+            "window TVF aggregate beside an aggregate over one")
+    return WindowMaximaJoin(
+        per_key=_window_agg_plan(a, catalog), per_key_alias=a_alias,
+        per_window=_window_agg_plan(b.subquery, catalog),
+        max_query=b, max_alias=b_alias, query=q)
 
 
 def _build_join_plan(q: Query, catalog: Dict[str, TableInfo]
@@ -397,6 +519,9 @@ def _build_join_plan(q: Query, catalog: Dict[str, TableInfo]
     right = catalog.get(j.table2)
     if right is None:
         raise Unsupported("unknown-table", f"table {j.table2!r}")
+    for side in (left, right):
+        if isinstance(side, ViewInfo):
+            raise Unsupported("view", f"join over view {side.name!r}")
     if q.group_by or any(i.kind in ("agg", "ml_predict") for i in q.select):
         raise Unsupported("join", "aggregate/GROUP BY over a join")
     window = NormalizedWindow(

@@ -49,6 +49,7 @@ from flink_tpu.runtime.fire_block import (
     downstream_batch,
     fires_of,
     reduce_block,
+    window_maxima,
 )
 from flink_tpu.runtime.oracle_window_operator import OracleWindowOperator
 from flink_tpu.runtime.tpu_window_operator import TpuWindowOperator
@@ -432,10 +433,51 @@ class ChainRunner(StepRunner):
         if len(ts) and self.downstream:
             self.downstream.on_batch(vals, ts)
 
-    def _apply(self, values: np.ndarray, timestamps: np.ndarray):
+    def on_fires_n(self, ordinal: int, drained: Sequence, bare: bool,
+                   clock: Optional[StageClock]) -> bool:
+        """A chain whose first transform keeps each window's maxima
+        (`window_maxima_rows`: the SQL plan of planner/rules
+        rewrite_window_maxima) takes a fused window's fires as columns: the
+        rows of a window that tie at its maximum are picked by whole-column
+        calls (stage `fire.reduce`, a window at a time), rows are built of
+        those alone (stage `table.output`, a kept block at a time), both on
+        the emitting operator's clock with the firing dispatch's `seq=`; the
+        rest of the chain takes them as a batch. Anything else goes the row
+        way, where the transform's own function does the same: False."""
+        rows_of = (self.transforms[0].config.get("window_maxima_rows")
+                   if self.transforms else None)
+        if rows_of is None or bare or type(drained[0]) is not FireBlock:
+            return False
+        windows: Dict[int, List[FireBlock]] = {}
+        for b in drained:
+            windows.setdefault(b.ts, []).append(b)
+        kept = []
+        for blocks in windows.values():
+            with stage(clock, "fire.reduce", blocks[0].seq):
+                window = window_maxima(blocks)
+            if window is None:
+                return False
+            kept.extend(window)
+        if clock is not None:
+            clock.fire_rows_reduced += sum(map(len, drained))
+            clock.fire_rows_kept += sum(map(len, kept))
+        vals: List = []
+        for b in kept:
+            with stage(clock, "table.output", b.seq):
+                vals.extend(rows_of(b))
+        ts = np.repeat(np.fromiter((b.ts for b in kept), dtype=np.int64,
+                                   count=len(kept)),
+                       [len(b) for b in kept])
+        vals, ts = self._apply(obj_array(vals), ts, first=1)
+        if len(ts) and self.downstream:
+            self.downstream.on_batch(vals, ts)
+        return True
+
+    def _apply(self, values: np.ndarray, timestamps: np.ndarray,
+               first: int = 0):
         vals = values
         ts = np.asarray(timestamps, dtype=np.int64)
-        for t in self.transforms:
+        for t in self.transforms[first:]:
             if len(ts) == 0:
                 break
             fn = t.config["fn"]
@@ -485,7 +527,9 @@ class ChainRunner(StepRunner):
                     )
             elif t.kind == "flat_map":
                 if vec:
-                    out, src_idx = fn(vals)
+                    out, src_idx = (fn(vals, ts)
+                                    if t.config.get("with_timestamps")
+                                    else fn(vals))
                     vals = self._to_column(out, columnar=True)
                     ts = ts[np.asarray(src_idx, dtype=np.int64)]
                 else:
@@ -2713,5 +2757,17 @@ class LocalPipelineExecutor:
             # programs were compiled and dispatched (/jobs/:id/device shape)
             metrics={"records_in": runtime.records_in,
                      "mesh_devices": runtime.mesh_devices(),
-                     "device": runtime.device_snapshot()},
+                     "device": runtime.device_snapshot(),
+                     **sql_plans(graph)},
         )
+
+
+def sql_plans(graph: StepGraph) -> Dict[str, List[Dict[str, Any]]]:
+    """`{"sql": [...]}`: the plan report of each SQL statement in the job
+    (`SqlPlanReport.summary()`, stamped on the statement's result by the
+    table layer: path, fallback reason and detail, plan), or {} where the
+    job ran no SQL."""
+    plans = [t.config["sql_plan"]
+             for s in graph.steps for t in (*s.chain, s.terminal)
+             if t is not None and "sql_plan" in t.config]
+    return {"sql": plans} if plans else {}

@@ -7,8 +7,9 @@ any row does. `FireBlock` carries it in that form; rows are built from a
 block at most once, by whole-column calls (`tolist` + `zip`), by whoever
 needs rows: `rows_of` for `drain_output()`'s callers, `downstream_batch`
 for the runner's hand-over; a null-key window behind the operator takes
-one row of a block without building the others (`reduce_block`). The other
-window operators (oracle,
+one row of a block without building the others (`reduce_block`), a SQL plan
+that keeps each window's maxima the tied rows alone (`window_maxima`). The
+other window operators (oracle,
 TpuWindowOperator, session, global) drain `(key, window, result, ts)` rows;
 `downstream_batch` and `fires_of` take those too.
 """
@@ -16,7 +17,7 @@ TpuWindowOperator, session, global) drain `(key, window, result, ts)` rows;
 from __future__ import annotations
 
 from itertools import chain, groupby, repeat
-from typing import Any, Iterator, List, Optional, Sequence, Tuple
+from typing import Any, Dict, Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -94,6 +95,34 @@ def reduce_block(block: FireBlock, agg) -> Optional[Tuple[Any, Any]]:
         return None
     i = agg.pick(column)
     return _scalar(block.keys[i]), _scalar(block.results[i])
+
+
+def window_maxima(blocks: Sequence[FireBlock]) -> Optional[List[FireBlock]]:
+    """Of each window's fires (the blocks that share a timestamp), the rows
+    whose result is the window's maximum, every tied key, in ascending key
+    order, as one block a window; by whole-column calls (one `max` and one
+    equality mask a block). None where only the rows can say (no key
+    column, a column that is no plain numeric ndarray, a NaN): the caller
+    then takes `downstream_batch`."""
+    windows: Dict[int, List[FireBlock]] = {}
+    for b in blocks:
+        if b.keys is None or not all(
+                isinstance(c, np.ndarray) and c.dtype.kind in "iuf"
+                for c in (b.keys, b.results)) \
+                or (b.results.dtype.kind == "f" and np.isnan(b.results).any()):
+            return None
+        if len(b):
+            windows.setdefault(b.ts, []).append(b)
+    out = []
+    for ts, group in windows.items():
+        top = max(b.results.max() for b in group)
+        keep = [b.results == top for b in group]
+        keys = np.concatenate([b.keys[m] for b, m in zip(group, keep)])
+        order = np.argsort(keys, kind="stable")
+        results = np.concatenate([b.results[m] for b, m in zip(group, keep)])
+        out.append(FireBlock(group[0].window, keys[order], results[order], ts,
+                             group[0].seq))
+    return out
 
 
 def _scalar(v):

@@ -5,7 +5,11 @@ aggregate functions, translated onto the DataStream plan (and therefore onto
 the device window operator — the same sliced-window execution the reference
 SQL runtime uses via tvf/slicing)."""
 
-from flink_tpu.table.table_env import TableEnvironment, TableSchema
+from flink_tpu.table.table_env import (
+    StreamTableEnvironment,
+    TableEnvironment,
+    TableSchema,
+)
 from flink_tpu.table.sql import SqlParseError, parse_query
 from flink_tpu.table.changelog import (
     DELETE,

@@ -17,6 +17,20 @@ group windows. Supported grammar (case-insensitive keywords):
   [ORDER BY <col> [ASC|DESC] [, ...]] -- per window (streaming top-N)
   [LIMIT <n>]
   [UNION ALL <query>]                 -- concatenate result streams
+  [;]
+
+  <table>  := <name> [AS <alias>]
+            | ( <query> ) [AS] <alias>          -- derived table
+            | TABLE(<tvf>)                      -- window table-valued function
+  <tvf>    := TUMBLE(TABLE <name>, DESCRIPTOR(<time_col>), INTERVAL ...)
+            | HOP(TABLE <name>, DESCRIPTOR(<time_col>), INTERVAL <slide>,
+                  INTERVAL <size>)
+              -- the query's GROUP BY then names window_start and
+              -- window_end beside its key columns
+  ( <query> ) AS a JOIN ( <query> ) AS b ON <cond>
+              -- a join of two derived tables: <cond> is comparisons of
+              -- a.<col> with b.<col> joined by AND (planner/rules.py
+              -- rewrite_window_maxima says which such joins fuse)
 
   <item>   := <col> | <agg>( <col> | * ) [AS <alias>]
             | WINDOW_START [AS alias] | WINDOW_END [AS alias]
@@ -39,7 +53,7 @@ import re
 from typing import Any, Callable, List, Optional, Tuple
 
 _TOKEN_RE = re.compile(
-    r"\s*(?:(?P<num>-?\d+\.\d+|-?\d+)|(?P<str>'[^']*')|(?P<op><=|>=|!=|<>|=|<|>|\(|\)|,|\*)"
+    r"\s*(?:(?P<num>-?\d+\.\d+|-?\d+)|(?P<str>'[^']*')|(?P<op><=|>=|!=|<>|=|<|>|\(|\)|,|\*|;)"
     r"|(?P<word>[A-Za-z_][A-Za-z_0-9.]*))"
 )
 
@@ -218,6 +232,17 @@ def predicate_columns(node) -> List[str]:
     return out
 
 
+def conjuncts(node) -> Optional[List[Comparison]]:
+    """The comparisons of a predicate AST made of AND alone, in order; None
+    where it holds an OR."""
+    if not isinstance(node, BoolExpr):
+        return [node]
+    if node.op != "and":
+        return None
+    left, right = conjuncts(node.left), conjuncts(node.right)
+    return None if left is None or right is None else left + right
+
+
 @dataclasses.dataclass
 class JoinSpec:
     """Equi-join. With a trailing WINDOW clause: windowed join (the
@@ -235,6 +260,20 @@ class JoinSpec:
     join_type: str = "inner"   # 'inner' | 'left' | 'right' (regular only)
                                # | 'full' (parses; refused with the typed
                                # catalogued reason 'join-full-outer')
+
+
+@dataclasses.dataclass
+class DerivedJoin:
+    """`( <query> ) AS left_alias JOIN ( <query> ) AS right_alias ON <on>`:
+    an inner join of two derived tables. `on` is the condition's AST over
+    alias-qualified columns; `on_text` its source text."""
+
+    left_alias: str
+    left: "Query"
+    right_alias: str
+    right: "Query"
+    on: Any
+    on_text: str
 
 
 @dataclasses.dataclass
@@ -257,6 +296,29 @@ class Query:
     # path's compiled view of the same trees
     where_ast: Any = None
     having_ast: Any = None
+    # FROM ( <query> ) AS <table>: `table` is the alias, this the query
+    subquery: Optional["Query"] = None
+    # FROM ( ... ) AS a JOIN ( ... ) AS b ON ...: `table` is a's alias
+    derived_join: Optional[DerivedJoin] = None
+    # the window came from a window TVF (FROM TABLE(HOP(TABLE t, ...))):
+    # window_start / window_end were named in GROUP BY and taken out of it
+    tvf: bool = False
+
+
+@dataclasses.dataclass
+class _Relation:
+    """One FROM item: a table (or view) name, a derived table, or a window
+    TVF over a table."""
+
+    name: str
+    alias: str
+    subquery: Optional[Query] = None
+    window: Optional[WindowSpec] = None
+
+
+#: words that end a FROM item, so never read as an alias without AS
+_CLAUSE_WORDS = {"JOIN", "LEFT", "RIGHT", "FULL", "INNER", "ON", "WHERE",
+                 "GROUP", "HAVING", "ORDER", "LIMIT", "UNION", "WINDOW"}
 
 
 class _Parser:
@@ -265,6 +327,7 @@ class _Parser:
         self.positions = positions
         self.sql = sql
         self.i = 0
+        self.depth = 0          # derived tables open around the cursor
 
     def pos(self) -> int:
         """Character offset of the current token (end of input when past)."""
@@ -304,12 +367,9 @@ class _Parser:
             self.next()
             select.append(self.select_item())
         self.expect("FROM")
-        table = self.next()
+        rel = self.relation()
+        table, alias1 = rel.name, rel.alias
         join = None
-        alias1 = table
-        if self.peek_upper() == "AS":
-            self.next()
-            alias1 = self.next()
         join_type = "inner"
         has_join = self.peek_upper() == "JOIN"
         if self.peek_upper() in ("LEFT", "RIGHT", "FULL", "INNER"):
@@ -321,11 +381,13 @@ class _Parser:
         elif has_join:
             self.next()
         if has_join:
-            table2 = self.next()
-            alias2 = table2
-            if self.peek_upper() == "AS":
-                self.next()
-                alias2 = self.next()
+            at = self.pos()
+            rel2 = self.relation()
+            if rel.subquery is not None or rel2.subquery is not None:
+                return self.derived_join(select, rel, rel2, join_type, at)
+            if rel.window is not None or rel2.window is not None:
+                raise self.error("a window TVF cannot be a join input", at=at)
+            table2, alias2 = rel2.name, rel2.alias
             if alias2 == alias1:
                 raise ValueError(
                     f"join sides must have distinct aliases, both are "
@@ -362,6 +424,19 @@ class _Parser:
                     self.next()
                     continue
                 break
+        if rel.window is not None:
+            # GROUP BY <keys>, window_start, window_end: the TVF's window
+            if window is not None:
+                raise self.error("a window TVF query groups by window_start "
+                                 "and window_end, not by a window")
+            bounds = [g for g in group_by
+                      if g.lower() in ("window_start", "window_end")]
+            if sorted(b.lower() for b in bounds) != ["window_end",
+                                                     "window_start"]:
+                raise self.error("a window TVF query must GROUP BY "
+                                 "window_start and window_end")
+            group_by = [g for g in group_by if g not in bounds]
+            window = rel.window
         having = having_text = having_ast = None
         if self.peek_upper() == "HAVING":
             if not group_by and window is None:
@@ -396,7 +471,7 @@ class _Parser:
             if limit < 0:
                 raise self.error(
                     f"LIMIT must be non-negative, got {limit}", at=at)
-        if join is None and alias1 != table:
+        if join is None and alias1 != table and rel.subquery is None:
             raise ValueError(
                 "table aliases are only meaningful on join queries; "
                 f"drop 'AS {alias1}' or add a JOIN"
@@ -421,7 +496,7 @@ class _Parser:
                     "UNION ALL with a join as the LEFT branch is not "
                     "supported; put the join on the right branch"
                 )
-            if self.peek() is not None:
+            if not self.at_end():
                 raise ValueError(f"trailing tokens: {self.tokens[self.i:]}")
             if having is not None or order_by or limit is not None:
                 raise ValueError(
@@ -436,12 +511,100 @@ class _Parser:
             self.next()
             self.expect("ALL")
             union_all = self.query()       # right-recursive: a UNION chain
-        elif self.peek() is not None:
+        elif not self.at_end():
             raise ValueError(f"trailing tokens: {self.tokens[self.i:]}")
         return Query(select, table, where, where_text, group_by, window,
                      having=having, having_text=having_text,
                      order_by=order_by, limit=limit, union_all=union_all,
-                     where_ast=where_ast, having_ast=having_ast)
+                     where_ast=where_ast, having_ast=having_ast,
+                     subquery=rel.subquery, tvf=rel.window is not None)
+
+    def at_end(self) -> bool:
+        """End of this query: the end of input, or the `)` that closes a
+        derived table."""
+        return self.peek() is None or (self.depth > 0 and self.peek() == ")")
+
+    def relation(self) -> _Relation:
+        """One FROM item: `<name> [AS <alias>]`, `( <query> ) [AS] <alias>`
+        or `TABLE(HOP|TUMBLE(TABLE <name>, DESCRIPTOR(<col>), ...))`."""
+        if self.peek() == "(":
+            self.next()
+            self.depth += 1
+            sub = self.query()
+            self.depth -= 1
+            self.expect(")")
+            if self.peek_upper() == "AS":
+                self.next()
+            at = self.pos()
+            alias = self.peek()
+            if alias is None or not re.match(r"[A-Za-z_]", alias) \
+                    or alias.upper() in _CLAUSE_WORDS:
+                raise self.error("a derived table needs an alias", at=at)
+            self.next()
+            return _Relation(alias, alias, subquery=sub)
+        if self.peek_upper() == "TABLE":
+            return self.window_tvf()
+        name = self.next()
+        alias = name
+        if self.peek_upper() == "AS":
+            self.next()
+            alias = self.next()
+        return _Relation(name, alias)
+
+    def window_tvf(self) -> _Relation:
+        """`TABLE(HOP(TABLE t, DESCRIPTOR(c), INTERVAL <slide>, INTERVAL
+        <size>))` or the TUMBLE form with one interval."""
+        self.expect("TABLE")
+        self.expect("(")
+        at = self.pos()
+        kind = self.next().upper()
+        if kind not in ("TUMBLE", "HOP"):
+            raise self.error(f"unsupported window TVF {kind!r} (TUMBLE or "
+                             "HOP)", at=at)
+        self.expect("(")
+        self.expect("TABLE")
+        name = self.next()
+        self.expect(",")
+        self.expect("DESCRIPTOR")
+        self.expect("(")
+        time_col = self.next()
+        self.expect(")")
+        self.expect(",")
+        first = self.interval()
+        if kind == "HOP":
+            self.expect(",")
+            # HOP(data, timecol, slide, size): the reference TVF's order
+            window = WindowSpec("hop", time_col, size_ms=self.interval(),
+                                slide_ms=first)
+        else:
+            window = WindowSpec("tumble", time_col, size_ms=first)
+        self.expect(")")
+        self.expect(")")
+        return _Relation(name, name, window=window)
+
+    def derived_join(self, select: List[SelectItem], left: _Relation,
+                     right: _Relation, join_type: str, at: int) -> Query:
+        """The rest of `( ... ) AS a JOIN ( ... ) AS b ON <cond>`."""
+        if left.subquery is None or right.subquery is None:
+            raise self.error("a derived table joins only another derived "
+                             "table", at=at)
+        if join_type != "inner":
+            raise self.error("only INNER joins of derived tables are "
+                             "supported", at=at)
+        if left.alias == right.alias:
+            raise self.error(f"join sides must have distinct aliases, both "
+                             f"are {left.alias!r}", at=at)
+        self.expect("ON")
+        start = self.i
+        on = self.or_expr()
+        on_text = " ".join(self.tokens[start:self.i])
+        if not self.at_end():
+            raise self.error("nothing may follow the ON condition of a join "
+                             "of derived tables")
+        return Query(select, left.alias, None, None, [], None,
+                     derived_join=DerivedJoin(left.alias, left.subquery,
+                                              right.alias, right.subquery,
+                                              on, on_text))
 
     def select_item(self) -> SelectItem:
         t = self.next()
@@ -565,6 +728,8 @@ def parse_query(sql: str) -> Query:
     IndexError/ValueError escaping the recursive descent is a crash, not a
     diagnostic, so any stray one is wrapped at the current token here."""
     tokens, positions = _tokenize(sql)
+    if tokens and tokens[-1] == ";":      # one statement's terminator
+        tokens, positions = tokens[:-1], positions[:-1]
     parser = _Parser(tokens, positions, sql)
     try:
         return parser.query()

@@ -14,7 +14,11 @@ use a composite oracle AggregateFunction.
 from __future__ import annotations
 
 import dataclasses
+import math
+from itertools import repeat
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
+
+import numpy as np
 
 from flink_tpu.api.datastream import DataStream, StreamExecutionEnvironment
 from flink_tpu.api.functions import AggregateFunction
@@ -25,13 +29,19 @@ from flink_tpu.api.windowing.assigners import (
 )
 from flink_tpu.connectors.sink import CollectSink, Sink
 from flink_tpu.core.watermarks import WatermarkStrategy
+from flink_tpu.graph.transformation import Transformation
 from flink_tpu.table.sql import (
     AGG_FUNCS,
     DEVICE_AGG_OF as _DEVICE_AGG,   # single-sourced with planner/rules
+    BoolExpr,
     Query,
     SelectItem,
+    WindowSpec,
+    compile_predicate,
+    conjuncts,
     parse_query,
 )
+from flink_tpu.utils.arrays import obj_array
 
 
 @dataclasses.dataclass
@@ -127,10 +137,16 @@ class TableEnvironment:
     def __init__(self, env: Optional[StreamExecutionEnvironment] = None):
         self.env = env or StreamExecutionEnvironment.get_execution_environment()
         self._tables: Dict[str, _Table] = {}
+        # views by name: the parsed SELECT each stands for
+        self._views: Dict[str, Query] = {}
         self._models: Dict[str, Any] = {}
         # planning outcome of the last sql_query()/execute_sql* call — the
         # gateway reports it per statement (executionPath + fallbackReason)
         self.last_plan_report = None
+
+    @classmethod
+    def create(cls, env: StreamExecutionEnvironment) -> "TableEnvironment":
+        return cls(env)
 
     # -- registration -----------------------------------------------------
     def register_model(self, name: str, provider) -> None:
@@ -146,6 +162,16 @@ class TableEnvironment:
         planner fuses whole (docs/sql.md); the interpreted path reads them
         through a per-record row view."""
         self._tables[name] = _Table(stream, schema, columnar=columnar)
+
+    def create_temporary_view(self, name: str, sql: str) -> None:
+        """Register `sql` (a SELECT) under `name`: statements read the view
+        as a table. A projection of a table's columns under an optional
+        WHERE is inlined by the planner (its filter then runs wherever the
+        statement's does, in the fused program's traced prologue among
+        others); any other view runs on the interpreted path."""
+        if name in self._tables:
+            raise ValueError(f"{name!r} is a registered table")
+        self._views[name] = parse_query(sql)
 
     def from_rows(self, name: str, rows: Sequence[dict], schema: TableSchema) -> None:
         """Register an in-memory table (fromValues analogue)."""
@@ -175,7 +201,10 @@ class TableEnvironment:
         # gateway stamps this onto the operation as executionPath)
         self.last_plan_report = None
         q = parse_query(sql)
-        return self._plan_and_translate(q)
+        out = self._plan_and_translate(q)
+        # the job's own account of how SQL ran (result.metrics["sql"])
+        out.transform.config["sql_plan"] = self.last_plan_report.summary()
+        return out
 
     def explain_sql(self, sql: str):
         """Plan-only view of a statement: the SqlPlanReport the planner
@@ -190,9 +219,9 @@ class TableEnvironment:
         return plan_query(q, self._catalog())
 
     def _catalog(self):
-        from flink_tpu.planner import TableInfo
+        from flink_tpu.planner import TableInfo, ViewInfo
 
-        return {
+        catalog = {
             name: TableInfo(
                 name=name,
                 fields=tuple(t.schema.fields),
@@ -203,6 +232,18 @@ class TableEnvironment:
             )
             for name, t in self._tables.items()
         }
+        for name, v in self._views.items():
+            plain = (v.join is None and v.derived_join is None
+                     and v.subquery is None and v.union_all is None
+                     and v.window is None and not v.group_by
+                     and not v.order_by and v.limit is None
+                     and all(i.kind == "column" and i.output_name == i.name
+                             for i in v.select))
+            catalog[name] = ViewInfo(
+                name=name, table=v.table if plain else None,
+                columns=tuple(i.name for i in v.select) if plain else (),
+                where=v.where_ast, where_text=v.where_text)
+        return catalog
 
     def _plan_and_translate(self, q: Query) -> DataStream:
         """Route through the SQL planner (flink_tpu/planner) behind
@@ -237,8 +278,48 @@ class TableEnvironment:
             return self._translate(q)
         low = report.lowered
         result = DataStream(self.env, low.terminal)
+        if report.plan.output.maxima:
+            return self._window_maxima_stage(result, report.plan)
         return self._windowed_output_stage(
             result, q, [low.group_col], extract=None)
+
+    def _window_maxima_stage(self, result: DataStream, plan) -> DataStream:
+        """Output stage of a plan that keeps each window's maxima
+        (planner/rules.rewrite_window_maxima): of every window, one row per
+        key whose aggregate equals the window's maximum, every tied key, in
+        ascending key order. Behind the fused window the keys are picked on
+        the fire's columns and rows built for them alone
+        (`window_maxima_rows`, runtime ChainRunner.on_fires_n); `maxima`
+        does the same for `(key, result)` rows where no block comes."""
+        size = plan.window_agg.window.size_ms
+        expr = {"key": "k", "agg": "r", "start": f"ts + 1 - {size}",
+                "end": "ts + 1"}
+        src = "lambda k, r, ts: {%s}" % ", ".join(
+            f"{name!r}: {expr[role]}" for name, role in plan.output.roles)
+        to_row = eval(src, {"__builtins__": {}})  # noqa: S307
+        to_row.__sql_codegen__ = src
+
+        def rows_of(block):
+            return list(map(to_row, block.keys.tolist(),
+                            block.results.tolist(), repeat(int(block.ts))))
+
+        def maxima(vals, ts):
+            windows: Dict[int, List[int]] = {}
+            for i, t in enumerate(ts.tolist()):
+                windows.setdefault(t, []).append(i)
+            rows, idx = [], []
+            for t, at in windows.items():
+                top = max(vals[i][1] for i in at)
+                for k, i in sorted((vals[i][0], i) for i in at
+                                   if vals[i][1] == top):
+                    rows.append(to_row(k, vals[i][1], t))
+                    idx.append(i)
+            return obj_array(rows), np.asarray(idx, dtype=np.int64)
+
+        return DataStream(self.env, Transformation(
+            "flat_map", "sql_window_maxima", [result.transform],
+            {"fn": maxima, "vectorized": True, "with_timestamps": True,
+             "window_maxima_rows": rows_of}))
 
     def _translate(self, q: Query) -> DataStream:
         if q.union_all is not None:
@@ -255,13 +336,14 @@ class TableEnvironment:
                 )
             left = dataclasses.replace(q, union_all=None)
             return self._translate(left).union(self._translate(q.union_all))
-        if q.table not in self._tables:
-            raise KeyError(f"unknown table {q.table!r}; registered: {list(self._tables)}")
-        table = self._tables[q.table]
-        stream = self._row_stream(table)
-
+        if q.derived_join is not None:
+            return self._derived_join_query(q)
         if q.join is not None:
             return self._join_query(q)
+        table = self._source(q.table, q.subquery)
+        stream = self._row_stream(table)
+        if q.subquery is not None:
+            q = self._over_derived(q)
 
         if q.where is not None:
             pred = q.where
@@ -366,6 +448,34 @@ class TableEnvironment:
                 f"BY/aggregates; table {q.table!r} declares "
                 f"{table.schema.fields}")
         return self._grouped_window_query(q, stream)
+
+    def _source(self, name: str, subquery: Optional[Query] = None) -> _Table:
+        """The table a statement reads by `name`: a registered table, a view
+        (its SELECT translated: dict rows of its columns), or the derived
+        table `subquery` the statement names `name`. A translated table
+        keeps the field types of the table's columns it passes on, and its
+        rowtime where it passes that on."""
+        query = subquery if subquery is not None else self._views.get(name)
+        if query is None:
+            if name not in self._tables:
+                raise KeyError(f"unknown table {name!r}; registered: "
+                               f"{list(self._tables) + list(self._views)}")
+            return self._tables[name]
+        fields = [i.output_name for i in query.select]
+        base = (None if query.derived_join is not None
+                or query.subquery is not None
+                else self._source(query.table).schema)
+        types = None
+        if base is not None and base.field_types is not None and all(
+                i.kind == "column" and i.name in base.fields
+                for i in query.select):
+            types = [base.field_types[base.fields.index(i.name)]
+                     for i in query.select]
+        rowtime = next((i.output_name for i in query.select
+                        if base is not None and i.kind == "column"
+                        and i.name == base.rowtime), None)
+        return _Table(self._translate(query),
+                      TableSchema(fields, rowtime=rowtime, field_types=types))
 
     def _row_stream(self, table: _Table) -> DataStream:
         """Dict-row view of a table for the interpreted path. Row-mode
@@ -597,9 +707,6 @@ class TableEnvironment:
                 "FULL OUTER JOIN is not supported: neither the host "
                 "StreamingJoinRunner nor the device join ring implements "
                 "two-sided padding retraction")
-        if j.table2 not in self._tables:
-            raise KeyError(
-                f"unknown table {j.table2!r}; registered: {list(self._tables)}")
         if q.group_by:
             raise ValueError("join queries aggregate via a follow-up query; "
                              "GROUP BY on a join is not supported yet")
@@ -610,12 +717,12 @@ class TableEnvironment:
         if j.window is not None and j.window.kind == "session":
             raise ValueError("session windows are not supported for joins")
 
-        s1 = self._row_stream(self._tables[q.table])
-        s2 = self._row_stream(self._tables[j.table2])
+        t1, t2 = self._source(q.table), self._source(j.table2)
+        s1, s2 = self._row_stream(t1), self._row_stream(t2)
         lcol = j.left_col.split(".", 1)[1]
         rcol = j.right_col.split(".", 1)[1]
-        cols1 = set(self._tables[q.table].schema.fields)
-        cols2 = set(self._tables[j.table2].schema.fields)
+        cols1 = set(t1.schema.fields)
+        cols2 = set(t2.schema.fields)
         a1, a2 = j.alias1, j.alias2
 
         def merge(l, r):
@@ -684,6 +791,130 @@ class TableEnvironment:
 
         return joined.map(project, name="sql_join_output")
 
+    # -- derived tables (the interpreted path) ------------------------------
+    @staticmethod
+    def _window_bounds(q: Query) -> Optional[Tuple[WindowSpec, Dict[str, str]]]:
+        """(window, {output column: 'start' | 'end'}) of a query whose rows
+        are per-window results (each stamped its window's end - 1): a
+        windowed aggregate, or a per-window aggregate over one (GROUP BY a
+        window bound of its derived table, nothing but bounds); None
+        otherwise."""
+        if q.window is not None and q.window.kind != "session":
+            return q.window, {i.output_name: i.kind[len("window_"):]
+                              for i in q.select
+                              if i.kind in ("window_start", "window_end")}
+        inner = (TableEnvironment._window_bounds(q.subquery)
+                 if q.subquery is not None else None)
+        if inner is None or q.window is not None:
+            return None
+        window, bounds = inner
+        grouped = [bounds.get(_unqualified(g, q.table)) for g in q.group_by]
+        if not grouped or None in grouped:
+            return None
+        return window, {i.output_name: bounds[_unqualified(i.name, q.table)]
+                        for i in q.select if i.kind == "column"
+                        and _unqualified(i.name, q.table) in bounds}
+
+    def _over_derived(self, q: Query) -> Query:
+        """An aggregate over a windowed derived table, grouped by its window
+        bounds: the same statement with the table's alias taken off its
+        column names and, as its window, a tumbling one as long as the
+        derived table's slide, which holds exactly one window's rows (they
+        all carry that window's end - 1). Anything else over a derived
+        table is refused."""
+        if self._window_bounds(q) is None or q.where is not None:
+            raise NotImplementedError(
+                "a query over a derived table must be an aggregate, with no "
+                "WHERE, grouped by the window bounds of a windowed derived "
+                "table")
+        window, _bounds = self._window_bounds(q.subquery)
+        plain = {i.output_name for i in q.subquery.select}
+
+        def col(name):
+            return _unqualified(name, q.table) if name != "*" else name
+
+        select = [dataclasses.replace(i, name=col(i.name)) for i in q.select]
+        for i in select:
+            if i.kind in ("column", "agg") and i.name not in plain | {"*"}:
+                raise ValueError(f"derived table {q.table!r} has no column "
+                                 f"{i.name!r}")
+        return dataclasses.replace(
+            q, select=select, group_by=[col(g) for g in q.group_by],
+            window=WindowSpec("tumble", "", window.slide_ms or window.size_ms),
+            subquery=None)
+
+    def _derived_join_query(self, q: Query) -> DataStream:
+        """`( A ) AS a JOIN ( B ) AS b ON ...` where both sides give
+        per-window rows and the condition equates the windows' ends (or
+        their starts, the windows being as long): a row carries its
+        window's end - 1, so rows that join carry one timestamp. A windowed
+        join keyed on the `=` pairs of the condition over tumbling windows
+        aligned with both sides' slides, the rest of the condition a filter
+        on the joined rows, then the projection."""
+        dj = q.derived_join
+        sides = {dj.left_alias: self._window_bounds(dj.left),
+                 dj.right_alias: self._window_bounds(dj.right)}
+        (la, lw), (ra, rw) = sides.items()
+        if lw is None or rw is None:
+            raise NotImplementedError(
+                "a join of derived tables runs where both give per-window "
+                "rows")
+        keys, rest = [], []
+        terms = conjuncts(dj.on)
+        if terms is None:
+            raise NotImplementedError("OR in the condition of a join of "
+                                      "derived tables")
+        for cmp in terms:
+            ends = [_side_column(op, sides) for op in (cmp.left, cmp.right)]
+            if cmp.op == "=" and None not in ends and ends[0][0] != ends[1][0]:
+                by_alias = dict(ends)
+                keys.append((by_alias[la], by_alias[ra]))
+            else:
+                rest.append(cmp)
+        same = lw[0].size_ms == rw[0].size_ms
+        if not any((lw[1].get(lc), rw[1].get(rc)) == ("end", "end")
+                   or same and (lw[1].get(lc), rw[1].get(rc)) == ("start",
+                                                                  "start")
+                   for lc, rc in keys):
+            raise NotImplementedError(
+                "a join of derived tables must equate the window ends of "
+                "both sides (or the starts of windows as long)")
+        lcols = tuple(lc for lc, _rc in keys)
+        rcols = tuple(rc for _lc, rc in keys)
+        lschema = set(i.output_name for i in dj.left.select)
+        rschema = set(i.output_name for i in dj.right.select)
+
+        def merge(l, r):
+            row = {f"{la}.{k}": v for k, v in l.items()}
+            row.update({f"{ra}.{k}": v for k, v in r.items()})
+            row.update({k: v for k, v in l.items() if k not in rschema})
+            row.update({k: v for k, v in r.items() if k not in lschema})
+            return row
+
+        slide = math.gcd(*(w.slide_ms or w.size_ms for w, _b in (lw, rw)))
+        joined = (
+            self._translate(dj.left)
+            .join(self._translate(dj.right))
+            .where(lambda row, c=lcols: tuple(row[x] for x in c))
+            .equal_to(lambda row, c=rcols: tuple(row[x] for x in c))
+            .window(TumblingEventTimeWindows.of(slide))
+            .apply(merge, name=f"sql_join[{dj.on_text}]"))
+        if rest:
+            pred = rest[0]
+            for cmp in rest[1:]:
+                pred = BoolExpr("and", pred, cmp)
+            joined = joined.filter(compile_predicate(pred),
+                                   name="sql_join_condition")
+        cols = [i for i in q.select if i.kind == "column"]
+        if len(cols) != len(q.select):
+            raise NotImplementedError(
+                "a join of derived tables selects columns only")
+
+        def project(row, _cols=cols):
+            return {i.output_name: row[i.name] for i in _cols}
+
+        return joined.map(project, name="sql_join_output")
+
     def execute_sql_to_list(self, sql: str) -> List[dict]:
         """Convenience: run the query to completion, return rows. A
         changelog result (continuous aggregate / regular join) is
@@ -715,3 +946,22 @@ class TableEnvironment:
         if w.kind == "session":
             return EventTimeSessionWindows.with_gap(w.size_ms)
         raise ValueError(w.kind)
+
+
+#: the reference's name for the table environment of a streaming job
+#: (`StreamTableEnvironment.create(env)`): tables over the environment's
+#: DataStreams, `sql_query` results as DataStreams, run by its `execute()`
+StreamTableEnvironment = TableEnvironment
+
+
+def _unqualified(name: str, alias: str) -> str:
+    """`alias.col` -> `col`; any other name as it is."""
+    return name[len(alias) + 1:] if name.startswith(alias + ".") else name
+
+
+def _side_column(op, sides) -> Optional[Tuple[str, str]]:
+    """(alias, column) of an `alias.column` operand of a join condition."""
+    if op.kind != "column":
+        return None
+    alias, _dot, col = op.value.partition(".")
+    return (alias, col) if alias in sides and col else None
