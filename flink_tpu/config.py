@@ -778,7 +778,9 @@ class ObservabilityOptions:
         "dispatches, and "
         "stepsPlannedScalar / stepsPlannedMasked: data steps whose slice "
         "plan came from the batch's two timestamp extremes, or per record "
-        "under a late mask). flink_tpu.emit appends one block of columns "
+        "under a late mask; stagingSetsAllocated / stagingSetsReused: "
+        "dispatches whose host staging arrays were taken fresh, or reused "
+        "from the pipeline's pool). flink_tpu.emit appends one block of columns "
         "per fire (fireBlocks; rowsEmitted / fireBlocks = rows per fire), "
         "flink_tpu.drain builds the downstream batch from the blocks. "
         "deviceDispatchMs / deviceTimeMsTotal / deviceDispatches are derived "

@@ -246,6 +246,10 @@ class StageClock:
         # step's two timestamp extremes, or per record under a late mask
         self.steps_planned_scalar = 0
         self.steps_planned_masked = 0
+        # host staging sets (lane arrays) a dispatch took: fresh, or from
+        # the pipeline's pool (`_StagingPool`)
+        self.staging_sets_allocated = 0
+        self.staging_sets_reused = 0
         self.seq = 0            # the dispatch being staged (`dispatches` so far)
         self.total_s = 0.0
         self.dispatches = 0     # outer sections entered
@@ -272,14 +276,21 @@ class StageClock:
     def section(self) -> "_Section":
         return StageClock._Section(self)
 
-    def staged(self, arrays, events: int = 0, columns=None) -> None:
+    def staged(self, arrays, events: int = 0, columns=None,
+               reused=None) -> None:
         """Host arrays handed to `jax.device_put` in a stage.put, and the
         events they carry; `columns` = (fields staged, fields of the
-        record) where the dispatch ships a traced chain's record."""
+        record) where the dispatch ships a traced chain's record; `reused`
+        = whether its staging set came from the pool (None: no set)."""
         self.h2d_bytes += sum(a.nbytes for a in arrays if a is not None)
         self.events_staged += events
         if columns is not None:
             self.columns_staged, self.record_columns = columns
+        if reused is not None:
+            if reused:
+                self.staging_sets_reused += 1
+            else:
+                self.staging_sets_allocated += 1
 
     def planned(self, masked: bool) -> None:
         """One data step's slice plan reached staging (StepPlan.masked)."""
@@ -301,7 +312,9 @@ class StageClock:
                 "fireRowsReduced": self.fire_rows_reduced,
                 "fireRowsKept": self.fire_rows_kept, "dispatches": self.seq,
                 "stepsPlannedScalar": self.steps_planned_scalar,
-                "stepsPlannedMasked": self.steps_planned_masked}
+                "stepsPlannedMasked": self.steps_planned_masked,
+                "stagingSetsAllocated": self.staging_sets_allocated,
+                "stagingSetsReused": self.staging_sets_reused}
 
     def register(self, group) -> None:
         group.gauge("deviceTimeMsTotal", lambda: self.total_s * 1000.0,

@@ -266,8 +266,20 @@ _CHAINED_CACHE: Dict[tuple, Any] = {}
 _CHAINED_CACHE_MAX = 128
 
 
+def _give_back(handle) -> None:
+    """Return the staging set a resolved dispatch read to its pool."""
+    lease, handle.lease = handle.lease, None
+    if lease is not None:
+        lease.release()
+
+
 class DeferredEmissions:
     """Handle for fires of one dispatch; the device->host copy runs async."""
+
+    #: the staging set the dispatch was staged from (`_StagingPool`), given
+    #: back at resolve: the fire rows are read, so the program that read
+    #: the set has run and every transfer out of it has completed
+    lease = None
 
     def __init__(self, pipe: "FusedWindowPipeline", fires, count_out, outs,
                  key_bounds=None, key_capacity: Optional[int] = None,
@@ -324,6 +336,7 @@ class DeferredEmissions:
             self._key_bounds = None     # checked and folded once
         count_np = np.asarray(self._count_out)
         outs_np = {k: np.asarray(v) for k, v in self._outs.items()}
+        _give_back(self)
         return [
             (
                 self._pipe._window_of_fire(pf),
@@ -341,7 +354,10 @@ class _StreamedEmissions:
     group's scan was enqueued — fires from early step groups become
     host-visible while later groups are still computing. resolve()
     concatenates the per-group resolutions in group order, reproducing
-    the whole-span handle's emission order and payloads exactly."""
+    the whole-span handle's emission order and payloads exactly. The
+    staging set goes back once every group has resolved."""
+
+    lease = None
 
     def __init__(self, parts: List[DeferredEmissions]):
         self._parts = parts
@@ -351,6 +367,7 @@ class _StreamedEmissions:
         out = []
         for p in self._parts:
             out.extend(p.resolve())
+        _give_back(self)
         return out
 
 
@@ -473,13 +490,17 @@ class Staged(NamedTuple):
       array[, ts]).
     plan: smin_pos, fire_pos, fire_valid, fire_row, purge_mask; on a mesh
       the five side by side in one [T, 1 + 3F + S] array (`_place`).
-    layout: how a rank-1 record was split into fields (None: it was not)."""
+    layout: how a rank-1 record was split into fields (None: it was not).
+    lease: the host staging set the lanes came from (`_StagingPool`;
+      None: the payload stages none), handed on to the dispatch's
+      emissions, which give it back at resolve."""
 
     payload: Any
     xs: tuple
     plan: tuple
     fires: list
     layout: Optional[ColumnLayout] = None
+    lease: Any = None
 
     @property
     def T(self) -> int:
@@ -509,6 +530,98 @@ class Staged(NamedTuple):
             fires=[pf for pf in self.fires if lo <= pf.step < hi])
 
 
+class _StagingLease:
+    """One dispatch's hold on a staging set: its host lane arrays."""
+
+    __slots__ = ("pool", "geometry", "arrays", "reused")
+
+    def __init__(self, pool, geometry, arrays, reused: bool):
+        self.pool = pool            # None: the set is not given back
+        self.geometry = geometry
+        self.arrays = arrays
+        self.reused = reused
+
+    def release(self) -> None:
+        pool, self.pool = self.pool, None
+        if pool is not None:
+            pool._give_back(self)
+
+
+class _StagingPool:
+    """The host lane arrays of a pipeline's dispatches, reused across them.
+
+    A set (srel or idx, the staged fields, ts or vals) is taken in `_fill`
+    and given back when the dispatch that staged it resolves: its program
+    has run by then, so every transfer out of the set is complete. A set
+    taken from the pool skips the page faults and zeroing of fresh host
+    memory; its lanes hold an earlier dispatch's data, which the payload's
+    `pad` marks dead as it marks `np.empty`'s garbage. The pool holds no
+    more sets than were out at once, so its depth follows the in-flight
+    ring, and it keeps the sets of its newest geometries only: a ragged
+    tail or the end-of-input flush does not pile up full-size sets.
+
+    A `device_put` that aliased a set's memory (a CPU backend's zero copy)
+    would let the next fill change the device array: each new set is
+    probed at its first placement, and a geometry that aliased takes a
+    fresh set every dispatch, never given back."""
+
+    #: how many geometries keep their free sets: the newest ones taken
+    KEEP = 2
+
+    def __init__(self):
+        self._free: Dict[tuple, List[tuple]] = {}   # oldest geometry first
+        self._aliased: set = set()
+
+    def take(self, geometry) -> _StagingLease:
+        """A set of `geometry` ((shape, dtype, fill) per array; fill None:
+        `np.empty`): a free one, else a fresh one."""
+        if geometry in self._aliased:
+            return _StagingLease(None, geometry, _fresh(geometry), False)
+        free = self._free.pop(geometry, [])
+        self._free[geometry] = free
+        while len(self._free) > self.KEEP:
+            del self._free[next(iter(self._free))]
+        if free:
+            return _StagingLease(self, geometry, free.pop(), True)
+        return _StagingLease(self, geometry, _fresh(geometry), False)
+
+    def placed(self, lease: _StagingLease, device_arrays) -> None:
+        """Probe a new set of the pool against the device arrays made from
+        it (a reused set was probed when it was new)."""
+        if lease.reused or lease.pool is None:
+            return
+        if _aliases(lease.arrays, device_arrays):
+            self._aliased.add(lease.geometry)
+            self._free.pop(lease.geometry, None)
+            lease.pool = None
+
+    def _give_back(self, lease: _StagingLease) -> None:
+        free = self._free.get(lease.geometry)
+        if free is not None:
+            free.append(lease.arrays)
+
+
+def _fresh(geometry) -> tuple:
+    return tuple(np.empty(shape, dtype) if fill is None
+                 else np.full(shape, fill, dtype)
+                 for shape, dtype, fill in geometry)
+
+
+def _aliases(host_arrays, device_arrays) -> bool:
+    """Whether a buffer of `device_arrays` lies in the memory of one of
+    `host_arrays`; a buffer whose address cannot be read counts as one."""
+    spans = [(a.ctypes.data, a.ctypes.data + a.nbytes) for a in host_arrays]
+    for x in device_arrays:
+        try:
+            ptrs = [s.data.unsafe_buffer_pointer()
+                    for s in x.addressable_shards]
+        except (AttributeError, RuntimeError):   # unknown: keep fresh sets
+            return True
+        if any(lo <= ptr < hi for ptr in ptrs for lo, hi in spans):
+            return True
+    return False
+
+
 class _KeyIdPayload:
     """Steps carry dense key ids and, where the aggregate reads them,
     values. Staged as idx = kid * NSB + srel per lane and vals; the width
@@ -517,15 +630,24 @@ class _KeyIdPayload:
     record = False
 
     def alloc(self, p: "FusedWindowPipeline", steps):
-        """(host arrays, how many of them carry one entry per lane, the
-        record's layout)."""
+        """(the staging set's lease, how many of its arrays carry one entry
+        per lane, the record's layout)."""
         T = len(steps)
         B = -(-_longest(steps) // p.chunk) * p.chunk
-        idx_h = np.empty((T, B), dtype=np.int32)
-        # value-less aggregates (count) carry a [T,1] placeholder instead of
-        # shipping a dead [T,B] f32 column to the device
-        vals_h = np.zeros((T, B if p._needs_vals else 1), dtype=np.float32)
-        return (idx_h, vals_h), 2 if p._needs_vals else 1, None
+        # value-less aggregates (count) carry a zero [T,1] placeholder
+        # instead of shipping a dead [T,B] f32 column to the device; with
+        # values every lane is written or padded
+        vals = ((T, B), np.float32, None) if p._needs_vals else \
+            ((T, 1), np.float32, 0)
+        lease = p._staging.take((((T, B), np.int32, None), vals))
+        return lease, 2 if p._needs_vals else 1, None
+
+    def pad(self, xs_h, lanes: int, t: int, live: int) -> None:
+        """Mark step t's lanes from `live` on dead: idx -1, value 0 (the
+        matmul histogram multiplies a dead lane's value by a zero one-hot)."""
+        xs_h[0][t, live:] = -1
+        if lanes > 1:
+            xs_h[1][t, live:] = 0
 
     def write(self, p, xs_h, layout, t: int, n: int, step,
               plan: StepPlan) -> int:
@@ -548,8 +670,8 @@ class _KeyIdPayload:
         # (pad-row semantics)
         if int(kid.min()) < 0:
             row[kid < 0] = -1
-        if vals is not None and p._needs_vals:
-            vals_h[t, :n] = (vals if keep is None
+        if p._needs_vals:
+            vals_h[t, :n] = (0.0 if vals is None else vals if keep is None
                              else np.where(keep, vals, 0.0))
         return n
 
@@ -563,7 +685,7 @@ class _BoundsPayload(_KeyIdPayload):
     stages idx (and vals) on the device itself; dispatched as key ids."""
 
     def alloc(self, p, steps):
-        return (), 0, None
+        return None, 0, None
 
     def write(self, p, xs_h, layout, t, n, step, plan) -> int:
         return 0
@@ -611,25 +733,30 @@ class _RecordPayload:
                     "chain executable is shaped on a fixed column layout"
                 )
 
-        # np.empty, not zeros: pad rows are srel -1 — every traced consumer
-        # masks on that before touching raw/ts, so the 16MB+ staging memset
-        # per dispatch would be pure waste. Buffers are allocated in jax's
-        # CANONICAL dtype (x64-off: float64→float32, int64→int32): device_put
-        # of a non-canonical array re-casts the whole buffer host-side every
-        # dispatch — a full extra copy, and the garbage pad bytes overflow
-        # the narrowing float cast (RuntimeWarning). Real rows cast at fill.
+        # np.empty or a pooled set, not zeros: pad rows are srel -1 — every
+        # traced consumer masks on that before touching raw/ts, so the
+        # 16MB+ staging memset per dispatch would be pure waste. Buffers are
+        # allocated in jax's CANONICAL dtype (x64-off: float64→float32,
+        # int64→int32): device_put of a non-canonical array re-casts the
+        # whole buffer host-side every dispatch — a full extra copy, and the
+        # garbage pad bytes overflow the narrowing float cast
+        # (RuntimeWarning). Real rows cast at fill.
         layout = p._layout()
-        xs_h = (np.empty((T, B), dtype=np.int32),)      # srel
+        geometry = (((T, B), np.int32, None),)      # srel
         if layout is None:
-            xs_h += (np.empty((T, B) + p._raw_shape,
-                              dtype=_jdt.canonicalize_dtype(p._raw_dtype)),)
+            geometry += (((T, B) + p._raw_shape,
+                          _jdt.canonicalize_dtype(p._raw_dtype), None),)
         else:
-            xs_h += tuple(np.empty((T, B), dtype=layout.dtype)
-                          for _c in layout.columns)
+            geometry += tuple(((T, B), np.dtype(layout.dtype), None)
+                              for _c in layout.columns)
         if p.prologue.needs_ts:
-            xs_h += (np.empty((T, B),
-                              dtype=_jdt.canonicalize_dtype(np.int64)),)
-        return xs_h, len(xs_h), layout
+            geometry += (((T, B), _jdt.canonicalize_dtype(np.int64), None),)
+        return p._staging.take(geometry), len(geometry), layout
+
+    def pad(self, xs_h, lanes: int, t: int, live: int) -> None:
+        """Mark step t's lanes from `live` on dead: srel -1, the rest left
+        as they are (every traced consumer masks on srel)."""
+        xs_h[0][t, live:] = -1
 
     def write(self, p, xs_h, layout, t: int, n: int, step,
               plan: StepPlan) -> int:
@@ -793,8 +920,10 @@ class FusedWindowPipeline:
         self.max_seen_slice: Optional[int] = None
         self.num_late_records_dropped = 0
 
-        # staging (below): what a step of this job carries
+        # staging (below): what a step of this job carries, and the host
+        # arrays it is staged in
         self._payload = _KEY_IDS if prologue is None else _RECORD
+        self._staging = _StagingPool()
         # weakref to the mesh pipeline that plans through this one
         self._mesh = None
 
@@ -1216,25 +1345,30 @@ class FusedWindowPipeline:
                 payload = _KEY_IDS
         clock = self.stage_clock
         with dispatch_stage(clock, "stage.fill"):
-            xs_h, lanes, layout, plan_np, fires = self._fill(
+            xs_h, lanes, layout, plan_np, fires, lease = self._fill(
                 payload, steps, watermarks)
             xs_h, plan_h, shardings = self.deployment._place(
                 payload, xs_h, lanes, plan_np)
         with dispatch_stage(clock, "stage.put"):
             xs, plan = jax.device_put((xs_h, plan_h), shardings)
+            if lease is not None:
+                self._staging.placed(lease, xs)
             if clock is not None:
                 clock.staged(xs_h + plan_np, sum(len(s[2]) for s in steps),
-                             payload.columns(self, layout))
-        return Staged(payload, xs, plan, fires, layout)
+                             payload.columns(self, layout),
+                             None if lease is None else lease.reused)
+        return Staged(payload, xs, plan, fires, layout, lease)
 
     def _fill(self, payload, steps, watermarks):
         """THE staging loop: each step's plan taken (or made), its lanes
         written by the payload, the tail of its row marked dead, the
         fire / purge plan advanced by the plan cursor. Returns (the
         payload's host arrays, how many of them carry one entry per lane,
-        the record's ColumnLayout, the five plan arrays, fires)."""
+        the record's ColumnLayout, the five plan arrays, fires, the lease
+        of the staging set the arrays are: None without one)."""
         T = len(steps)
-        xs_h, lanes, layout = payload.alloc(self, steps)
+        lease, lanes, layout = payload.alloc(self, steps)
+        xs_h = () if lease is None else lease.arrays
         smin_pos = np.zeros(T, dtype=np.int32)
         fire_pos = np.zeros((T, self.F), dtype=np.int32)
         fire_valid = np.zeros((T, self.F), dtype=np.int32)
@@ -1251,15 +1385,17 @@ class FusedWindowPipeline:
                 plan = self._take_plan(plan, step[2], cur, t, smin_pos)
                 live = payload.write(self, xs_h, layout, t, n, step, plan)
             if lanes:
-                # np.empty staging: only the pad tails (whole rows of empty
-                # or all-late steps) are set, to the -1 every consumer
-                # masks on before touching the other arrays
-                xs_h[0][t, live:] = -1
+                # np.empty or pooled staging: only the pad tails (whole rows
+                # of empty or all-late steps) are set, to the -1 every
+                # consumer masks on before touching the other arrays (and a
+                # key id's value to 0)
+                payload.pad(xs_h, lanes, t, live)
             cur.advance(t, watermarks[t], fire_pos, fire_valid, fire_row,
                         purge_mask, fires)
         cur.commit()
         return (xs_h, lanes, layout,
-                (smin_pos, fire_pos, fire_valid, fire_row, purge_mask), fires)
+                (smin_pos, fire_pos, fire_valid, fire_row, purge_mask), fires,
+                lease)
 
     def _place(self, payload, xs_h, lanes, plan_np):
         """Placement: the payload's host arrays and the plan as they are
@@ -1323,6 +1459,7 @@ class FusedWindowPipeline:
                 self, group.fires, count_out, outs, key_bounds=key_bounds,
                 key_capacity=self.K, phase_counts=pc))
         deferred = parts[0] if len(parts) == 1 else _StreamedEmissions(parts)
+        deferred.lease = staged.lease
         return deferred if defer else deferred.resolve()
 
     def plan_superbatch(self, slice_bounds, watermarks):
@@ -2076,6 +2213,7 @@ class FusedGlobalWindowPipeline:
             self._planner, fires, count_out, outs,
             phase_counts=(pc if self.phase_counters and not use_pallas
                           else None))
+        deferred.lease = staged.lease
         return deferred if defer else deferred.resolve()
 
     def snapshot(self) -> dict:

@@ -181,7 +181,7 @@ def test_every_program_is_staged_by_one_loop_and_fires_at_parity(program):
     fired, wm_before = [], MIN_WATERMARK
     for steps, wms in groups:
         staged = pipe.stage(_steps_for(program, steps), wms)
-        xs_h, lanes, layout, plan_np, fires = fills.pop()
+        xs_h, lanes, layout, plan_np, fires, _lease = fills.pop()
         assert not fills and lanes == len(xs_h) == len(staged.xs)
         B = xs_h[0].shape[1]
         assert B == CHUNK and xs_h[0].dtype == np.int32

@@ -186,12 +186,13 @@ def _drive(flavour: str, stream: str, seed: int):
     fill = planner._fill
 
     def record_fill(payload, steps, wms):
-        xs_h, lanes, layout, plan_np, fires = fill(payload, steps, wms)
+        filled = fill(payload, steps, wms)
+        xs_h, lanes, _layout, plan_np, fires, _lease = filled
         live = xs_h[0] >= 0         # srel_h / idx_h
         staged.append(("raw" if payload.record else "keyed", xs_h[0].copy(),
                        [a.copy() for a in plan_np], fires_of(fires),
                        [a[live] for a in xs_h[1:lanes]]))
-        return xs_h, lanes, layout, plan_np, fires
+        return filled
 
     planner._fill = record_fill
     push = op._push_steps
@@ -374,7 +375,7 @@ def test_a_bare_step_is_planned_at_staging_and_a_wrong_plan_is_caught():
     pipe.attach_stage_clock(clock)
     rec = np.zeros((3, 3), np.float32)
     ts = np.array([5100, 5900, 6100], np.int64)
-    (srel_h, *_raw), _lanes, _layout, plan_np, _fires = pipe._fill(
+    (srel_h, *_raw), _lanes, _layout, plan_np, _fires, _lease = pipe._fill(
         pipe._payload, [(rec, None, ts), (rec[:0], None, ts[:0])],
         [5999, 5999])
     np.testing.assert_array_equal(srel_h[0, :3], [0, 0, 1])
