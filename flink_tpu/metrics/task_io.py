@@ -250,6 +250,10 @@ class StageClock:
         # the pipeline's pool (`_StagingPool`)
         self.staging_sets_allocated = 0
         self.staging_sets_reused = 0
+        # steps with records whose lanes were written by the one native
+        # call of their dispatch, or by numpy (`_fill`)
+        self.steps_staged_native = 0
+        self.steps_staged_numpy = 0
         self.seq = 0            # the dispatch being staged (`dispatches` so far)
         self.total_s = 0.0
         self.dispatches = 0     # outer sections entered
@@ -292,6 +296,12 @@ class StageClock:
             else:
                 self.staging_sets_allocated += 1
 
+    def lanes_written(self, native: int, numpy: int) -> None:
+        """A stage.fill's steps with records, by who wrote their lanes: the
+        dispatch's one native call, or numpy."""
+        self.steps_staged_native += native
+        self.steps_staged_numpy += numpy
+
     def planned(self, masked: bool) -> None:
         """One data step's slice plan reached staging (StepPlan.masked)."""
         if masked:
@@ -314,7 +324,9 @@ class StageClock:
                 "stepsPlannedScalar": self.steps_planned_scalar,
                 "stepsPlannedMasked": self.steps_planned_masked,
                 "stagingSetsAllocated": self.staging_sets_allocated,
-                "stagingSetsReused": self.staging_sets_reused}
+                "stagingSetsReused": self.staging_sets_reused,
+                "stepsStagedNative": self.steps_staged_native,
+                "stepsStagedNumpy": self.steps_staged_numpy}
 
     def register(self, group) -> None:
         group.gauge("deviceTimeMsTotal", lambda: self.total_s * 1000.0,

@@ -33,6 +33,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import os
 import weakref
 from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
@@ -43,6 +44,7 @@ from flink_tpu.core.time import MIN_WATERMARK, TimeWindow
 from flink_tpu.metrics import device_phases
 from flink_tpu.metrics.task_io import dispatch_stage
 from flink_tpu.ops.aggregators import DeviceAggregator, ONE, VALUE, resolve
+from flink_tpu.utils import native_bridge
 from flink_tpu.utils.arrays import canonical_column
 
 
@@ -642,6 +644,11 @@ class _KeyIdPayload:
         lease = p._staging.take((((T, B), np.int32, None), vals))
         return lease, 2 if p._needs_vals else 1, None
 
+    def native(self, p, xs_h, layout) -> Optional["_NativeLanes"]:
+        """The dispatch's native lane writer (None: `write` + `pad` stage
+        every step)."""
+        return None
+
     def pad(self, xs_h, lanes: int, t: int, live: int) -> None:
         """Mark step t's lanes from `live` on dead: idx -1, value 0 (the
         matmul histogram multiplies a dead lane's value by a zero one-hot)."""
@@ -753,6 +760,17 @@ class _RecordPayload:
             geometry += (((T, B), _jdt.canonicalize_dtype(np.int64), None),)
         return p._staging.take(geometry), len(geometry), layout
 
+    def native(self, p, xs_h, layout) -> Optional["_NativeLanes"]:
+        """The dispatch's native lane writer where the record is staged as
+        fields, no timestamps are staged and the native library is loaded
+        (else None)."""
+        if layout is None or p.prologue.needs_ts:
+            return None
+        lib = native_bridge.get_lib()
+        if lib is None or np.dtype(layout.dtype).itemsize not in (1, 2, 4, 8):
+            return None
+        return _NativeLanes(lib, xs_h, layout)
+
     def pad(self, xs_h, lanes: int, t: int, live: int) -> None:
         """Mark step t's lanes from `live` on dead: srel -1, the rest left
         as they are (every traced consumer masks on srel)."""
@@ -800,6 +818,92 @@ class _RecordPayload:
             return len(layout.columns), layout.width
         width = int(np.prod(p._raw_shape))
         return width, width
+
+
+#: native threads that write one dispatch's record lanes, the job's thread
+#: one of them; no more than the cores this process may run on. Four: on a
+#: TPU v5e host `ysb_catchup`'s fill reads 5.4-5.8 ms a dispatch with one
+#: writer, 3.6-3.7 with two, 2.6-2.7 with four (the record rows it reads
+#: come cold from memory; PERF.md, Findings)
+_LANE_WRITERS = min(4, len(os.sched_getaffinity(0)))
+
+
+class _NativeLanes:
+    """The record steps of one dispatch whose lanes ONE native call writes
+    (`stage_record_lanes`, native/flink_tpu_native.cpp), made through
+    ctypes, so with the GIL released: srel, its dead tail and each staged
+    field, every record row read once, no Python per step. It takes a step
+    where that call writes what `_RecordPayload.write` and `pad` write: a
+    step without records (its dead row), or one whose record is an ndarray
+    already in the staged dtype (`canonical_column` has nothing to cast or
+    check) with unit-strided fields, its plan's srel a scalar or an int32
+    array (a masked plan's -1 for a late record is copied as `write`
+    copies it). `_fill` stages every other step with numpy, as before."""
+
+    __slots__ = ("lib", "xs_h", "dtype", "cols", "rows", "live", "src",
+                 "stride", "srel", "srel_src", "keep")
+
+    def __init__(self, lib, xs_h, layout: ColumnLayout):
+        self.lib, self.xs_h = lib, xs_h
+        self.dtype = np.dtype(layout.dtype)
+        self.cols = layout.columns
+        self.rows: List[int] = []
+        self.live: List[int] = []
+        self.src: List[int] = []
+        self.stride: List[int] = []
+        self.srel: List[int] = []
+        self.srel_src: List[int] = []
+        self.keep: List[np.ndarray] = []   # what `src` / `srel_src` point into
+
+    def take(self, t: int, n: int, step, plan: Optional[StepPlan]) -> bool:
+        """Queue step t for the native call, if it writes the step."""
+        src = stride = srel = srel_src = 0
+        if n:
+            rec = step[0]
+            if (not isinstance(rec, np.ndarray) or rec.dtype != self.dtype or rec.ndim != 2
+                    or rec.shape[0] != n
+                    or rec.strides[1] != self.dtype.itemsize):
+                return False
+            if isinstance(plan.srel, np.ndarray):
+                srel_arr = plan.srel
+                if (srel_arr.dtype != np.int32 or srel_arr.shape != (n,)
+                        or not srel_arr.flags.c_contiguous):
+                    return False
+                self.keep.append(srel_arr)
+                srel_src = srel_arr.ctypes.data
+            elif isinstance(plan.srel, (int, np.integer)):
+                srel = int(plan.srel)
+            else:
+                return False
+            self.keep.append(rec)
+            src, stride = rec.ctypes.data, rec.strides[0]
+        self.rows.append(t)
+        self.live.append(n)
+        self.src.append(src)
+        self.stride.append(stride)
+        self.srel.append(srel)
+        self.srel_src.append(srel_src)
+        return True
+
+    def write(self) -> int:
+        """Write every step taken; returns how many of them carry records."""
+        if not self.rows:
+            return 0
+        fields = self.xs_h[1:1 + len(self.cols)]
+        args = (np.array(self.rows, np.int64), np.array(self.live, np.int64),
+                np.array(self.src, np.uint64),
+                np.array(self.stride, np.int64),
+                np.array(self.srel, np.int32),
+                np.array(self.srel_src, np.uint64))
+        offsets = np.array(self.cols, np.int64) * self.dtype.itemsize
+        dst = np.array([a.ctypes.data for a in fields], np.uint64)
+        srel_h = self.xs_h[0]
+        self.lib.stage_record_lanes(
+            len(self.rows), *(a.ctypes.data for a in args),
+            srel_h.shape[1], self.dtype.itemsize, len(self.cols),
+            offsets.ctypes.data, srel_h.ctypes.data, dst.ctypes.data,
+            _LANE_WRITERS)
+        return sum(1 for n in self.live if n)
 
 
 _KEY_IDS, _BOUNDS, _RECORD = _KeyIdPayload(), _BoundsPayload(), _RecordPayload()
@@ -1362,10 +1466,12 @@ class FusedWindowPipeline:
     def _fill(self, payload, steps, watermarks):
         """THE staging loop: each step's plan taken (or made), its lanes
         written by the payload, the tail of its row marked dead, the
-        fire / purge plan advanced by the plan cursor. Returns (the
-        payload's host arrays, how many of them carry one entry per lane,
-        the record's ColumnLayout, the five plan arrays, fires, the lease
-        of the staging set the arrays are: None without one)."""
+        fire / purge plan advanced by the plan cursor. The record steps
+        the payload's native writer takes are written after the loop, all
+        by one native call (`_NativeLanes`). Returns (the payload's host
+        arrays, how many of them carry one entry per lane, the record's
+        ColumnLayout, the five plan arrays, fires, the lease of the staging
+        set the arrays are: None without one)."""
         T = len(steps)
         lease, lanes, layout = payload.alloc(self, steps)
         xs_h = () if lease is None else lease.arrays
@@ -1377,22 +1483,30 @@ class FusedWindowPipeline:
         fires: List[_PlannedFire] = []
 
         cur = self._cursor()
+        native = payload.native(self, xs_h, layout)
+        numpy_steps = 0
         for t, step in enumerate(steps):
             plan = step[3] if len(step) > 3 else None
             n = len(step[2])
-            live = 0
             if n or plan is not None:
                 plan = self._take_plan(plan, step[2], cur, t, smin_pos)
-                live = payload.write(self, xs_h, layout, t, n, step, plan)
-            if lanes:
-                # np.empty or pooled staging: only the pad tails (whole rows
-                # of empty or all-late steps) are set, to the -1 every
-                # consumer masks on before touching the other arrays (and a
-                # key id's value to 0)
-                payload.pad(xs_h, lanes, t, live)
+            if native is None or not native.take(t, n, step, plan):
+                live = 0
+                if n or plan is not None:
+                    live = payload.write(self, xs_h, layout, t, n, step, plan)
+                if lanes:
+                    # np.empty or pooled staging: only the pad tails (whole
+                    # rows of empty or all-late steps) are set, to the -1
+                    # every consumer masks on before touching the other
+                    # arrays (and a key id's value to 0)
+                    payload.pad(xs_h, lanes, t, live)
+                    numpy_steps += n > 0
             cur.advance(t, watermarks[t], fire_pos, fire_valid, fire_row,
                         purge_mask, fires)
         cur.commit()
+        native_steps = 0 if native is None else native.write()
+        if self.stage_clock is not None:
+            self.stage_clock.lanes_written(native_steps, numpy_steps)
         return (xs_h, lanes, layout,
                 (smin_pos, fire_pos, fire_valid, fire_row, purge_mask), fires,
                 lease)

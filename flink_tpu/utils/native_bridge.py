@@ -40,7 +40,8 @@ def _build() -> Optional[str]:
     tmp = f"{_LIB}.{os.getpid()}.tmp"
     try:
         subprocess.run(
-            ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-o", tmp, *_SRCS],
+            ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-pthread",
+             "-o", tmp, *_SRCS],
             check=True,
             capture_output=True,
             timeout=120,
@@ -88,8 +89,8 @@ def get_lib() -> Optional[ctypes.CDLL]:
                 _load_error = str(e)
                 warnings.warn(
                     f"native library unavailable ({_load_error}); the host "
-                    "key dictionary, CSV codec, segment ring and spill "
-                    "store run their numpy/Python forms",
+                    "key dictionary, CSV codec, segment ring, spill store "
+                    "and record lane staging run their numpy/Python forms",
                     RuntimeWarning,
                 )
     return _lib
@@ -154,6 +155,12 @@ def _declare(lib: ctypes.CDLL) -> None:
     lib.ss_set_next_run_id.argtypes = [c.c_void_p, c.c_int64]
     lib.ss_purge_below.restype = c.c_int64
     lib.ss_purge_below.argtypes = [c.c_void_p, c.c_uint64]
+    lib.stage_record_lanes.restype = None
+    lib.stage_record_lanes.argtypes = [
+        c.c_int64, c.c_void_p, c.c_void_p, c.c_void_p, c.c_void_p,
+        c.c_void_p, c.c_void_p, c.c_int64, c.c_int64, c.c_int64, c.c_void_p,
+        c.c_void_p, c.c_void_p, c.c_int64,
+    ]
 
 
 class NativeKeyDict:

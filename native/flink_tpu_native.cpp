@@ -17,8 +17,13 @@
 //                    between a producer (network/file thread) and the step
 //                    loop; "no free segment" is the backpressure signal
 //                    (LocalBufferPool exhaustion analogue).
+//   4. record lanes — one dispatch's record steps written into its staging
+//                    arrays (srel and the staged fields) in one call, each
+//                    record row read once.
 //
 // C ABI only (ctypes binding in flink_tpu/utils/native_bridge.py).
+
+#include <pthread.h>
 
 #include <cstdint>
 #include <cstdlib>
@@ -261,5 +266,119 @@ int64_t ring_poll(SegmentRing* r, char* out, int64_t out_cap) {
 
 int64_t ring_available(SegmentRing* r) { return r->tail - r->head; }
 int64_t ring_free_segments(SegmentRing* r) { return r->num_segments - (r->tail - r->head); }
+
+}  // extern "C"
+
+// ===========================================================================
+// 4. Record lanes: a dispatch's record steps into its staging arrays
+// ===========================================================================
+
+namespace {
+
+struct LaneJob {
+  const int64_t* rows;        // staging row of each step
+  const int64_t* live;        // records of each step (lanes [0, live) live)
+  const uint64_t* src;        // address of each step's first record row
+  const int64_t* stride;      // bytes from one record row to the next
+  const int32_t* srel_value;  // each step's srel where it has no array
+  const uint64_t* srel_src;   // each step's int32 srel array, or 0
+  int64_t lanes;              // B: lanes of a staging row
+  int64_t ncols;
+  const int64_t* col_offset;  // byte offset of each staged field in a row
+  int32_t* srel_dst;          // [T, B]
+  const uint64_t* field_dst;  // [ncols] addresses of [T, B] arrays
+};
+
+// rows are cut in blocks that stay in L1 while each field is gathered
+// from them, so a record row comes from memory once whatever ncols is
+const int64_t kBlockRows = 1024;
+
+template <typename V>
+void write_steps(const LaneJob& job, int64_t lo, int64_t hi) {
+  for (int64_t i = lo; i < hi; i++) {
+    const int64_t n = job.live[i];
+    const int64_t base = job.rows[i] * job.lanes;
+    int32_t* srel = job.srel_dst + base;
+    if (job.srel_src[i] != 0) {
+      memcpy(srel, (const void*)job.srel_src[i], n * sizeof(int32_t));
+    } else {
+      const int32_t v = job.srel_value[i];
+      for (int64_t r = 0; r < n; r++) srel[r] = v;
+    }
+    for (int64_t r = n; r < job.lanes; r++) srel[r] = -1;  // the dead tail
+    const char* rec = (const char*)job.src[i];
+    const int64_t stride = job.stride[i];
+    for (int64_t b = 0; b < n; b += kBlockRows) {
+      const int64_t e = b + kBlockRows < n ? b + kBlockRows : n;
+      for (int64_t c = 0; c < job.ncols; c++) {
+        V* dst = (V*)job.field_dst[c] + base;
+        const char* from = rec + job.col_offset[c];
+        for (int64_t r = b; r < e; r++) {
+          V v;
+          memcpy(&v, from + r * stride, sizeof(V));
+          dst[r] = v;
+        }
+      }
+    }
+  }
+}
+
+struct LaneRange {
+  const LaneJob* job;
+  int64_t itemsize, lo, hi;
+};
+
+void* write_range(void* arg) {
+  const LaneRange& r = *(const LaneRange*)arg;
+  switch (r.itemsize) {
+    case 1: write_steps<uint8_t>(*r.job, r.lo, r.hi); break;
+    case 2: write_steps<uint16_t>(*r.job, r.lo, r.hi); break;
+    case 4: write_steps<uint32_t>(*r.job, r.lo, r.hi); break;
+    case 8: write_steps<uint64_t>(*r.job, r.lo, r.hi); break;
+  }
+  return nullptr;
+}
+
+}  // namespace
+
+extern "C" {
+
+// Writes `steps` record steps into a staging set: for step i, row rows[i]
+// of srel gets the step's srel (its array, else its value) in lanes
+// [0, live[i]) and -1 in [live[i], lanes); row rows[i] of each staged field
+// c gets field c of the step's records in lanes [0, live[i]) (a record row
+// is `stride[i]` bytes, field c at byte `col_offset[c]`, all of `itemsize`
+// bytes, 1, 2, 4 or 8), the lanes after them left as they were. Steps are
+// split in contiguous ranges over `writers` threads, the calling thread one
+// of them; a thread that cannot be started leaves its range to the caller.
+void stage_record_lanes(int64_t steps, const int64_t* rows,
+                           const int64_t* live, const uint64_t* src,
+                           const int64_t* stride, const int32_t* srel_value,
+                           const uint64_t* srel_src, int64_t lanes,
+                           int64_t itemsize, int64_t ncols,
+                           const int64_t* col_offset, int32_t* srel_dst,
+                           const uint64_t* field_dst, int64_t writers) {
+  const LaneJob job{rows, live, src, stride, srel_value, srel_src,
+                    lanes, ncols, col_offset, srel_dst, field_dst};
+  if (writers > steps) writers = steps;
+  if (writers < 1) writers = 1;
+  std::vector<LaneRange> ranges(writers);
+  std::vector<pthread_t> threads(writers);
+  std::vector<char> started(writers, 0);
+  for (int64_t w = 0; w < writers; w++)
+    ranges[w] = {&job, itemsize, steps * w / writers,
+                 steps * (w + 1) / writers};
+  for (int64_t w = 1; w < writers; w++)
+    started[w] = pthread_create(&threads[w], nullptr, write_range,
+                                &ranges[w]) == 0;
+  write_range(&ranges[0]);
+  for (int64_t w = 1; w < writers; w++) {
+    if (started[w]) {
+      pthread_join(threads[w], nullptr);
+    } else {
+      write_range(&ranges[w]);
+    }
+  }
+}
 
 }  // extern "C"

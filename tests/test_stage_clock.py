@@ -119,7 +119,9 @@ def test_link_counters_and_seq():
                             "dispatches": 1, "stepsPlannedScalar": 0,
                             "stepsPlannedMasked": 0,
                             "stagingSetsAllocated": 0,
-                            "stagingSetsReused": 0}
+                            "stagingSetsReused": 0,
+                            "stepsStagedNative": 0,
+                            "stepsStagedNumpy": 0}
     # a traced chain's dispatch says how much of the record it shipped;
     # the gauge is the newest dispatch's, not a sum
     clock.staged((np.zeros(2, np.int32),), events=2, columns=(2, 7))
